@@ -276,8 +276,12 @@ class ArtifactStore:
         with fingerprint `fp`. Returns bytes written."""
         from jax.experimental import serialize_executable as _se
         serialized, in_tree, out_tree = _se.serialize(compiled)
+        # jax 0.9 loads onto ALL of the backend's devices unless told
+        # which ones the program was compiled for
+        device_ids = [d.id for d in
+                      compiled.runtime_executable().local_devices()]
         payload = pickle.dumps(
-            {"fingerprint": fp, "name": str(name),
+            {"fingerprint": fp, "name": str(name), "devices": device_ids,
              "payload": (serialized, in_tree, out_tree)},
             protocol=pickle.HIGHEST_PROTOCOL)
         os.makedirs(self.root, exist_ok=True)
@@ -324,9 +328,12 @@ class ArtifactStore:
             if payload.get("fingerprint") != fp:
                 return self._fallback(name, "fingerprint")
             serialized, in_tree, out_tree = payload["payload"]
+            import jax
             from jax.experimental import serialize_executable as _se
-            loaded = _se.deserialize_and_load(serialized, in_tree,
-                                              out_tree)
+            by_id = {d.id: d for d in jax.devices()}
+            loaded = _se.deserialize_and_load(
+                serialized, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in payload["devices"]])
             # LRU recency for gc: reads bump the blob's mtime
             try:
                 os.utime(blob, None)

@@ -16,12 +16,14 @@ Default ON. Resolution order for the cache directory:
 2. ``MXTPU_COMPILE_CACHE`` — a path, or ``0`` to disable;
 3. ``MXTPU_XLA_CACHE`` — bench.py's pre-existing spelling, same
    semantics (the two tools share one artifact universe);
-4. the default ``$TMPDIR/mxtpu_xla_cache_<uid>`` — created 0700 and
-   refused unless we own it exclusively (a world-writable /tmp dir a
-   stranger pre-created could feed us planted executables — the same
-   refusal bench.py's `_enable_compile_cache` applies to the same
-   default path; bench keeps its stdlib copy for its plain mode, so
-   a change to either must update both).
+4. the default ``<repo>/.jax_cache`` — one fixed directory beside the
+   package (computed from this file's own path, never from a temporary
+   name, pid, uid or time: the path is part of jax's cache key, so a
+   directory that moves never hits). bench.py and tests/conftest.py
+   ask `resolve_cache_dir` instead of spelling it again.
+
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it into
+its own config; `enable_cache` then sets no directory in code.
 
 Size bound: ``MXTPU_COMPILE_CACHE_MAX_BYTES`` (default 1 GiB) is handed
 to jax's own LRU eviction; `gc_cache_dir` is the offline mirror
@@ -38,7 +40,6 @@ time, ``compile.cache.evictions`` counts `gc_cache_dir` removals.
 from __future__ import annotations
 
 import os
-import tempfile
 import threading
 
 from ..base import getenv
@@ -65,26 +66,11 @@ _DISABLED = ("", "0", "false", "False")
 
 
 def default_cache_dir():
-    """The shared uid-scoped default (bench.py's spelling, on purpose:
-    bench children and framework processes reuse each other's
-    compiles)."""
-    return os.path.join(tempfile.gettempdir(),
-                        "mxtpu_xla_cache_%d" % os.getuid())
-
-
-def _own_private_dir(path):
-    """Create-or-verify `path` as a 0700 directory we own. Returns
-    False (refuse) on a symlink, foreign owner, or group/other write
-    bits — only applied to the implicit default; an explicit path is
-    the operator's own responsibility."""
-    try:
-        os.makedirs(path, mode=0o700, exist_ok=True)
-        if os.path.islink(path):
-            return False
-        st = os.lstat(path)
-        return st.st_uid == os.getuid() and not (st.st_mode & 0o022)
-    except OSError:
-        return False
+    """The one fixed default: ``.jax_cache`` in the checkout that holds
+    this package (bench children, tests and framework processes reuse
+    each other's compiles)."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".jax_cache")
 
 
 def resolve_cache_dir(environ=None):
@@ -98,8 +84,7 @@ def resolve_cache_dir(environ=None):
         val = env.get(var)
         if val is not None:
             return None if val in _DISABLED else val
-    path = default_cache_dir()
-    return path if _own_private_dir(path) else None
+    return default_cache_dir()
 
 
 def _on_cache_event(name, **kwargs):
@@ -121,35 +106,27 @@ def _install_multidevice_guard():
     serving and the test suite, so the guard turns cache READS into
     misses when `num_replicas * num_partitions > 1` on the cpu
     platform (writes stay: the risk is executing a deserialized
-    executable, not writing one; jax's LRU bounds the space). Returns
-    False when the (private) hook point is missing — the caller then
-    refuses to enable the cache at all: a cache that may segfault the
-    process is worse than no cache."""
-    try:
-        from jax._src import compiler as _jc
+    executable, not writing one; jax's LRU bounds the space). The hook
+    is jax 0.9's private `compiler._cache_read`; if it moves, the
+    AttributeError is the error to see."""
+    from jax._src import compiler as _jc
 
-        def _spans_devices(compile_options, backend):
-            try:
-                if backend.platform != "cpu":
-                    return False
-                ebo = compile_options.executable_build_options
-                return (ebo.num_replicas * ebo.num_partitions) > 1
-            except AttributeError:
-                return True    # unknown shape: stay out of the cache
+    def _spans_devices(compile_options, backend):
+        if backend.platform != "cpu":
+            return False
+        ebo = compile_options.executable_build_options
+        return (ebo.num_replicas * ebo.num_partitions) > 1
 
-        orig_read = _jc._cache_read
+    orig_read = _jc._cache_read
 
-        def guarded_read(module_name, cache_key, compile_options,
-                         backend):
-            if _spans_devices(compile_options, backend):
-                return None, None
-            return orig_read(module_name, cache_key, compile_options,
-                             backend)
+    def guarded_read(module_name, cache_key, compile_options, backend,
+                     executable_devices):
+        if _spans_devices(compile_options, backend):
+            return None, None
+        return orig_read(module_name, cache_key, compile_options,
+                         backend, executable_devices)
 
-        _jc._cache_read = guarded_read
-        return True
-    except Exception:   # noqa: BLE001 — private API moved: fail safe
-        return False
+    _jc._cache_read = guarded_read
 
 
 def enable_cache(path=None):
@@ -173,30 +150,17 @@ def enable_cache(path=None):
             _state["enabled"], _state["dir"] = False, None
             return None
         import jax
-        # the guard installs BEFORE any config points at the cache:
-        # on failure (private hook moved in a future jax) nothing was
-        # activated, so "refuses to enable" is actually true — an
-        # operator-forced JAX_COMPILATION_CACHE_DIR is explicitly
-        # unset again, because an unguarded cache can segfault the
-        # process (worse than the compile time it would save)
         if not _state["guarded"]:
-            if not _install_multidevice_guard():
-                try:
-                    if jax.config.jax_compilation_cache_dir:
-                        jax.config.update("jax_compilation_cache_dir",
-                                          None)
-                except Exception:
-                    pass
-                _state["enabled"], _state["dir"] = False, None
-                return None
+            _install_multidevice_guard()
             _state["guarded"] = True
         try:
-            if not jax.config.jax_compilation_cache_dir:
-                jax.config.update("jax_compilation_cache_dir", target)
-            else:
-                # an earlier config (conftest, operator) won the dir;
-                # report and meter THAT one rather than fighting it
+            if jax.config.jax_compilation_cache_dir:
+                # JAX_COMPILATION_CACHE_DIR (or an earlier config) already
+                # placed the cache: use, report and meter THAT directory
+                # and set no other in code
                 target = jax.config.jax_compilation_cache_dir
+            else:
+                jax.config.update("jax_compilation_cache_dir", target)
             jax.config.update("jax_persistent_cache_min_compile_time_secs",
                               getenv("MXTPU_COMPILE_CACHE_MIN_S", 0.0))
             # cache even one-liner programs: entry-size floors exist for

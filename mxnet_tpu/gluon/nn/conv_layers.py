@@ -5,7 +5,7 @@ Conv1D-3DTranspose, MaxPool/AvgPool 1-3D, GlobalMaxPool/GlobalAvgPool 1-3D,
 ReflectionPad2D).
 
 TPU notes: convs lower onto the MXU via XLA's conv_general_dilated; NCHW
-layouts are kept at the API for reference parity (XLA relayouts
+layouts are kept at the API for reference parity (XLA changes layouts
 internally). Pooling lowers to lax.reduce_window.
 """
 from __future__ import annotations

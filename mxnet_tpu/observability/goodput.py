@@ -14,9 +14,11 @@ reads the per-step delta and derives
 
 streamed as the ``step_flops`` / ``mfu`` record fields and the
 ``goodput.mfu`` gauge. Peak FLOPs comes from ``MXTPU_PEAK_FLOPS`` when
-the operator knows the chip, else a per-platform default — on the CPU
-backend the default is deliberately modest so CI MFU reads a small
-nonzero number instead of 0.0 or noise.
+the operator knows the chip, else the table of published peaks keyed by
+the device's ``device_kind``; a kind that is not in the table has no
+peak and its MFU is None ("not measured"), never a default. The CPU
+entry is deliberately modest so CI MFU reads a small nonzero number
+instead of 0.0 or noise.
 
 Compute/comm/host decomposition needs no new measurement: the step
 record already carries allreduce/fused-update/data-wait seconds;
@@ -43,10 +45,11 @@ MFU = gauge("goodput.mfu",
             "last derived per-step model FLOPs utilization "
             "(label source)")
 
-#: fallback peak-FLOPs table per jax platform when MXTPU_PEAK_FLOPS is
-#: unset: TPU v4 bf16 / A100 bf16 / a deliberately modest CPU figure
-#: (≈ a few AVX cores) so CPU-CI MFU is a meaningful nonzero signal
-_PLATFORM_PEAK = {"tpu": 1.97e14, "gpu": 3.12e14, "cpu": 5.0e10}
+#: peak FLOP/s by `jax.devices()[0].device_kind` when MXTPU_PEAK_FLOPS
+#: is unset: one TPU v5e chip in bf16 (Google Cloud documentation,
+#: "TPU v5e": 197 TFLOP/s) / a deliberately modest CPU figure (≈ a few
+#: AVX cores) so CPU-CI MFU is a meaningful nonzero signal
+_KIND_PEAK = {"TPU v5 lite": 1.97e14, "cpu": 5.0e10}
 
 _lock = threading.Lock()
 _costs = {}   # program name -> {"flops": f, "bytes": b, "source": s}
@@ -61,25 +64,18 @@ def enabled():
 
 def peak_flops():
     """Peak device FLOP/s for the MFU denominator: MXTPU_PEAK_FLOPS
-    wins, else the per-platform default. Cached per env value."""
+    wins, else the published peak of the first device's kind, else
+    None (a device with no published peak here is "not measured").
+    Cached per env value."""
     env = os.environ.get("MXTPU_PEAK_FLOPS")
-    if _peak_cache["key"] == env and _peak_cache["value"] is not None:
+    if _peak_cache["key"] == (env,):
         return _peak_cache["value"]
-    value = None
     if env:
-        try:
-            value = float(env)
-        except ValueError:
-            value = None
-    if value is None:
-        platform = "cpu"
-        try:
-            import jax
-            platform = jax.default_backend()
-        except Exception:   # noqa: BLE001 — no backend yet
-            pass
-        value = _PLATFORM_PEAK.get(platform, _PLATFORM_PEAK["cpu"])
-    _peak_cache["key"], _peak_cache["value"] = env, value
+        value = float(env)
+    else:
+        import jax
+        value = _KIND_PEAK.get(jax.devices()[0].device_kind)
+    _peak_cache["key"], _peak_cache["value"] = (env,), value
     return value
 
 
@@ -171,14 +167,16 @@ def note_flops(flops, n_dispatches=1):
 
 
 def mfu_value(step_flops, step_time, source=None):
-    """step_flops over the step's peak-FLOP envelope, clamped to [0, 1];
-    also sets the goodput.mfu gauge. Returns None on degenerate input."""
+    """step_flops over the step's peak-FLOP envelope (not clamped: a
+    reading above 1 says the cost model or the peak is wrong, and has
+    to be seen); also sets the goodput.mfu gauge. Returns None on
+    degenerate input or where the device's peak is not known."""
     if not step_flops or not step_time or step_time <= 0:
         return None
     peak = peak_flops()
     if not peak or peak <= 0:
         return None
-    mfu = min(1.0, float(step_flops) / (float(step_time) * peak))
+    mfu = float(step_flops) / (float(step_time) * peak)
     if source is not None:
         MFU.set(mfu, source=source)
     else:

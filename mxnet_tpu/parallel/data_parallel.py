@@ -452,7 +452,7 @@ class ShardedTrainer:
         """K steps fused into ONE XLA program: `lax.scan` over the step
         body, reusing the staged batch each iteration (the reference's
         `--benchmark 1` synthetic-data mode). One dispatch per K steps —
-        on high-latency links (dev tunnels, multi-host controllers) the
+        on high-latency links (multi-host controllers) the
         per-call round trip amortizes away; on any TPU it removes K-1
         host dispatches."""
         from ..compile.cache import enable_cache

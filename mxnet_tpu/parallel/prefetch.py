@@ -67,7 +67,7 @@ class DevicePrefetcher:
                 if self._stop.is_set():
                     return
             self._q.put(_END)
-        except BaseException as e:  # noqa: BLE001 — relayed to consumer
+        except BaseException as e:  # noqa: BLE001 — handed on to consumer
             self._q.put(e)
 
     def __iter__(self):

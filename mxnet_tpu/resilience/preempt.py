@@ -59,9 +59,9 @@ def _handler(signum, frame):
         # a SECOND signal while the first is still pending means the
         # loop is not reaching a step boundary (wedged mid-step):
         # escalate immediately with the clean unwind that SIGINT-first
-        # reaping ladders (bench.fence_child, probe_loop) rely on —
+        # reaping ladders (bench.fence_child) rely on —
         # absorbing it would force them all the way to SIGKILL, which
-        # wedges device leases (PERF.md §9)
+        # leaves a device lease behind
         raise KeyboardInterrupt(
             "second %s while a preemption request was already pending"
             % signal.Signals(signum).name)

@@ -1,8 +1,7 @@
 """Deadline-bounded device init and hung-collective monitoring.
 
-The two hang modes the bench history records (BENCH_r03–r05, PERF.md
-§8) are (1) PJRT backend init blocking forever behind a wedged lease
-holder, and (2) a cross-process collective blocking forever because a
+The two hang modes a run on the device can meet are (1) PJRT backend
+init blocking forever behind a hung lease holder, and (2) a cross-process collective blocking forever because a
 peer died mid-run. `HealthWatchdog` bounds both:
 
 * `init_devices()` wraps `base.probe_devices` (the daemon-thread

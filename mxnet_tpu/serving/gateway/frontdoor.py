@@ -850,7 +850,7 @@ class Gateway:
                 payload["trace_id"] = trace_id
             handler._send_json(200, payload)
             return
-        # streaming: submit, then relay tokens as they land on the
+        # streaming: submit, then forward tokens as they land on the
         # handle (the scheduler appends between decode steps) — one
         # chunked JSON line per token, a final {"done": ...} line
         try:

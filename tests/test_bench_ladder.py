@@ -134,50 +134,22 @@ def test_compile_cache_disabled_by_zero(bench, monkeypatch):
     assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
 
 
-def test_compile_cache_default_dir_created_private(bench, monkeypatch):
-    # exercise the ownership guard on the real uid-derived default
+def test_compile_cache_default_is_the_frameworks(bench, monkeypatch):
+    # one spelling of the default: bench asks the framework's resolver,
+    # which answers the fixed directory inside the checkout
+    from mxnet_tpu.compile.cache import default_cache_dir
     monkeypatch.delenv("MXTPU_XLA_CACHE", raising=False)
+    monkeypatch.delenv("MXTPU_COMPILE_CACHE", raising=False)
     _guard_cache_env(monkeypatch)
     bench._enable_compile_cache()
-    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if d is not None:  # guard may refuse a pre-existing foreign dir
-        assert not os.path.islink(d)
-        st = os.lstat(d)
-        assert st.st_uid == os.getuid()
-        assert not (st.st_mode & 0o022)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] \
+        == default_cache_dir() == os.path.join(root, ".jax_cache")
 
 
-def _guard_fallback_env(monkeypatch):
-    """_fallback_to_cpu mutates os.environ directly; pre-register every
-    var it touches so monkeypatch rolls the mutations back."""
-    for var in ("JAX_PLATFORMS", "MXTPU_BENCH_PLATFORM",
-                "MXTPU_BENCH_BATCH", "MXTPU_BENCH_IMG",
-                "MXTPU_BENCH_STEPS", "MXTPU_BENCH_UNROLL",
-                "MXTPU_BENCH_SCORE", "MXTPU_BENCH_EXTRAS"):
-        monkeypatch.setenv(var, "sentinel")
-        monkeypatch.delenv(var)
-
-
-def test_cpu_fallback_pins_platform_and_shrinks(bench, monkeypatch):
-    _guard_fallback_env(monkeypatch)
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")  # the wedged pin
-    monkeypatch.setattr(bench, "_apply_platform_override",
-                        lambda: None)  # keep jax out of this test
-    bench._fallback_to_cpu()
-    assert os.environ["MXTPU_BENCH_PLATFORM"] == "cpu"
-    assert os.environ["JAX_PLATFORMS"] == ""
-    # workload shrank to the CI-smoke sizes (CPU-feasible, measured)
-    assert (bench.BATCH, bench.IMG, bench.STEPS, bench.UNROLL) \
-        == (8, 32, 2, 1)
-    assert os.environ["MXTPU_BENCH_SCORE"] == "0"
-    assert os.environ["MXTPU_BENCH_EXTRAS"] == "0"
-
-
-def test_cpu_fallback_respects_explicit_sizes(bench, monkeypatch):
-    _guard_fallback_env(monkeypatch)
-    monkeypatch.setenv("MXTPU_BENCH_BATCH", "4")
-    monkeypatch.setenv("MXTPU_BENCH_STEPS", "2")
-    monkeypatch.setattr(bench, "_apply_platform_override",
-                        lambda: None)
-    bench._fallback_to_cpu()
-    assert (bench.BATCH, bench.STEPS) == (4, 2)
+def test_no_cpu_fallback_left(bench):
+    # with no chip bench.py exits non-zero; the CPU is measured only
+    # under the explicit MXTPU_BENCH_PLATFORM=cpu pin
+    assert not hasattr(bench, "_fallback_to_cpu")
+    src = open(bench.__file__).read()
+    assert "MXTPU_BENCH_CPU_FALLBACK" not in src

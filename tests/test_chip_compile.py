@@ -1,0 +1,160 @@
+"""Compiles against a DESCRIBED chip (v5e:2x2), without the chip.
+
+The TPU's compiler is installed beside the CPU backend and compiles for
+a topology that is described and not attached: it raises here what it
+would raise on the chip (tiling, fast-memory limits, a program that does
+not fit the device's memory). Nothing runs, so nothing here says a result
+or a time is right — `chip_smoke.py` on the chip does that.
+
+This is the ONE file for such compiles: only one process may load the
+TPU's library, so the topology is described inside a module-scoped
+fixture (never at import, in a `skipif` or in `parametrize`), and the
+compiles happen in the test's own process.
+
+`pallas_kernels._interpret()` reads the default backend, which is the
+CPU here; each test patches it to False for the compile.
+"""
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops import pallas_kernels as pk
+
+HBM_BYTES = 16 * 2 ** 30        # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no compiler for it here
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def for_the_chip(monkeypatch):
+    """Real (not interpreted) kernels, and no persistent cache around the
+    compile: an entry written for a described device cannot be read back
+    without the chip and only makes the next compile warn."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "full"])
+def test_flash_attention_gpt2_small(one_chip, for_the_chip, causal):
+    # one GPT-2-small attention call: B=8, H=12, T=1024, D=64, bf16
+    qkv = ((8, 12, 1024, 64), jnp.bfloat16)
+    _compile(lambda q, k, v: pk.flash_attention(q, k, v, causal),
+             one_chip, qkv, qkv, qkv)
+
+
+def test_layer_norm_8192x768(one_chip, for_the_chip):
+    _compile(pk.pallas_layer_norm, one_chip,
+             ((8192, 768), jnp.bfloat16), ((768,), jnp.float32),
+             ((768,), jnp.float32))
+
+
+def test_fused_sgd_momentum_mfu_probe_shape(one_chip, for_the_chip):
+    # tools/mfu_probe.py's update: 199680x128 fp32 (25.6M weights)
+    wgm = ((199680, 128), jnp.float32)
+    _compile(lambda w, g, m: pk.fused_sgd_momentum(w, g, m, lr=0.1),
+             one_chip, wgm, wgm, wgm)
+
+
+def test_conv1x1_bn_stats_resnet_stage1(one_chip, for_the_chip):
+    # tools/mfu_probe.py's 1x1 conv: 128*56*56 rows, 64 -> 256 channels
+    _compile(pk.conv1x1_bn_stats, one_chip,
+             ((128 * 56 * 56, 64), jnp.bfloat16),
+             ((64, 256), jnp.bfloat16))
+
+
+@pytest.fixture(scope="module")
+def resnet50_trainer():
+    """The trainer `chip_smoke.py` builds, made on the CPU at a tiny image
+    size (the parameters do not depend on it); only its pure step body
+    goes to the described devices."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import gluon
+    from mxnet_tpu.gluon.model_zoo import vision
+    from mxnet_tpu.parallel import ShardedTrainer, make_mesh
+
+    net = vision.resnet50_v1(classes=1000, layout="NHWC")
+    net.initialize()
+    # shapes from inference, not from an eager forward: that would be a
+    # program for every layer
+    net.infer_shape(mx.nd.zeros((1, 32, 32, 3)))
+    for p in net.collect_params().values():
+        p._finish_deferred_init()
+    return ShardedTrainer(net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+                          {"learning_rate": 0.01, "momentum": 0.9},
+                          mesh=make_mesh({"dp": 1}, jax.devices()[:1]),
+                          compute_dtype="bfloat16")
+
+
+@pytest.mark.parametrize("n_chips", [1, 4], ids=["dp1", "dp4"])
+def test_resnet50_b128_train_step_fits(topo, for_the_chip,
+                                       resnet50_trainer, n_chips):
+    """The steps `chip_smoke.py` runs — ResNet-50 v1 NHWC 224x224, global
+    batch 128, bf16 compute, fp32 masters, SGD momentum, on a dp mesh of
+    one chip or of all four — compiled for the described chips from
+    shapes alone, must fit 16 GB a chip."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    st = resnet50_trainer
+    mesh = Mesh(np.array(topo.devices[:n_chips]), ("dp",))
+    replicated = NamedSharding(mesh, PartitionSpec())
+    by_dp = NamedSharding(mesh, PartitionSpec("dp"))
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=replicated), tree)
+
+    inputs = {"data": jax.ShapeDtypeStruct((128, 224, 224, 3),
+                                           jnp.float32, sharding=by_dp),
+              "label": jax.ShapeDtypeStruct((128,), jnp.float32,
+                                            sharding=by_dp)}
+    step = jax.jit(st._make_step_body(), donate_argnums=(0, 1, 2))
+    compiled = step.lower(described(st._params), described(st._aux),
+                          described(st._opt_state), inputs,
+                          None).compile()
+    ma = compiled.memory_analysis()      # bytes on each device
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 0 < need < HBM_BYTES, (need, ma)
+    n_params = sum(int(np.prod(v.shape)) for v in st._params.values())
+    assert 25.0e6 < n_params < 26.0e6, n_params     # the full width
+    # the gradients of a batch split over four chips are summed by a
+    # collective the compiler put in; one chip needs none
+    assert ("all-reduce" in compiled.as_text()) == (n_chips == 4)
+    print("resnet50 b128 step on %d described chip(s): %.2f GiB a chip"
+          % (n_chips, need / 2 ** 30))

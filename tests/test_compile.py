@@ -62,10 +62,43 @@ class TestCacheDir:
             {"JAX_COMPILATION_CACHE_DIR": "/operator",
              "MXTPU_COMPILE_CACHE": "0"}) == "/operator"
 
-    def test_default_is_uid_scoped(self):
-        d = cache_mod.resolve_cache_dir({})
-        if d is not None:        # None only if the default dir refused
-            assert str(os.getuid()) in d
+    def test_default_is_fixed_dir_in_checkout(self):
+        # one fixed directory beside the package: the path is part of
+        # jax's cache key, so it never comes from tempfile/pid/uid/time
+        assert cache_mod.resolve_cache_dir({}) \
+            == cache_mod.default_cache_dir() \
+            == os.path.join(ROOT, ".jax_cache")
+
+    def test_default_is_same_in_two_processes(self):
+        # run from another directory with no cache variable set: a
+        # second process resolves the very same directory
+        env = {k: v for k, v in os.environ.items()
+               if k not in ("JAX_COMPILATION_CACHE_DIR",
+                            "MXTPU_COMPILE_CACHE", "MXTPU_XLA_CACHE")}
+        env["PYTHONPATH"] = ROOT
+        code = ("from mxnet_tpu.compile.cache import resolve_cache_dir;"
+                "print(resolve_cache_dir())")
+        outs = [subprocess.run([sys.executable, "-c", code], env=env,
+                               cwd=cwd, capture_output=True, text=True,
+                               timeout=120, check=True).stdout.split()[-1]
+                for cwd in ("/", ROOT)]
+        assert outs[0] == outs[1] == os.path.join(ROOT, ".jax_cache")
+
+    def test_jax_env_dir_is_left_alone(self, tmp_path):
+        # with JAX_COMPILATION_CACHE_DIR set, jax has read it into its
+        # own config; enable_cache sets no other directory in code
+        env = dict(os.environ, PYTHONPATH=ROOT, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "ops"),
+                   MXTPU_COMPILE_CACHE=str(tmp_path / "other"))
+        code = ("import jax, mxnet_tpu;"
+                "from mxnet_tpu.compile import cache;"
+                "print(cache.enable_cache());"
+                "print(jax.config.jax_compilation_cache_dir)")
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120,
+                             check=True).stdout.split()
+        assert out[-2:] == [str(tmp_path / "ops")] * 2
+        assert not (tmp_path / "other").exists()
 
     def test_gc_scrubs_empty_and_evicts_lru(self, tmp_path):
         old = tmp_path / "old.bin"
@@ -108,7 +141,37 @@ class TestCacheDir:
 
         # spanning: forced miss, underlying cache never touched
         assert jc._cache_read("m", "key-that-does-not-exist",
-                              Opts(8), Backend()) == (None, None)
+                              Opts(8), Backend(), None) == (None, None)
+
+    def test_guarded_read_forwards_all_five_arguments(self, monkeypatch):
+        """jax 0.9.0 calls `_cache_read(module_name, cache_key,
+        compile_options, backend, executable_devices)`; a single-device
+        read reaches jax's own function with all five, in order."""
+        from jax._src import compiler as jc
+        seen = []
+
+        def orig(*args):
+            seen.append(args)
+            return "executable", 1.5
+
+        monkeypatch.setattr(jc, "_cache_read", orig)
+        cache_mod._install_multidevice_guard()   # wraps `orig`
+        assert jc._cache_read.__name__ == "guarded_read"
+
+        class EBO:
+            num_replicas = 1
+            num_partitions = 1
+
+        class Opts:
+            executable_build_options = EBO
+
+        class Backend:
+            platform = "cpu"
+
+        opts, backend, devs = Opts(), Backend(), ["d0"]
+        assert jc._cache_read("mod", "key", opts, backend, devs) \
+            == ("executable", 1.5)
+        assert seen == [("mod", "key", opts, backend, devs)]
 
     def test_gc_dry_run_touches_nothing(self, tmp_path):
         f = tmp_path / "a.bin"

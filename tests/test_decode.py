@@ -97,6 +97,7 @@ def test_gpt_eager_step_api():
 # DecodeEngine: token identity + the exactly-two-programs invariant
 # ---------------------------------------------------------------------------
 
+@pytest.mark.slow      # ~200 s alone on the CPU: eager greedy oracle
 def test_engine_prefill_step_token_identity():
     blk = make_block()
     eng = DecodeEngine(blk, max_slots=1, name="ti")
@@ -137,6 +138,9 @@ def test_exactly_two_decode_programs():
     assert counter.get(engine="two", kind="prefill") == progs["prefill"]
 
 
+# 131-862 s in tier-1 runs (ninety eager reference forwards);
+# test_staggered_joins_stay_token_identical keeps the oracle in tier-1
+@pytest.mark.slow
 def test_continuous_batching_token_identity_with_joins():
     """More sequences than slots, random lengths: late sequences join
     mid-batch into freed slots, and every one of them still decodes

@@ -279,13 +279,39 @@ def test_goodput_cost_table_and_charges():
     assert goodput.cost("p1")["flops"] == 5.0e9
 
 
-def test_mfu_value_clamped_and_gauged(monkeypatch):
+def test_mfu_value_unclamped_and_gauged(monkeypatch):
     monkeypatch.setenv("MXTPU_PEAK_FLOPS", "1e10")
     assert goodput.mfu_value(1e9, 1.0, source="t") == \
         pytest.approx(0.1)
-    assert goodput.mfu_value(1e12, 0.001, source="t") == 1.0
+    # an impossible reading is shown, not clamped to 1.0
+    assert goodput.mfu_value(1e12, 0.001, source="t") == \
+        pytest.approx(1e5)
     g = obs.REGISTRY.get("goodput.mfu")
     assert g is not None
+
+
+def test_peak_flops_keyed_by_device_kind(monkeypatch):
+    import jax
+    monkeypatch.delenv("MXTPU_PEAK_FLOPS", raising=False)
+    goodput._reset_for_tests()
+    assert jax.devices()[0].device_kind == "cpu"
+    assert goodput.peak_flops() == goodput._KIND_PEAK["cpu"]
+    assert goodput._KIND_PEAK["TPU v5 lite"] == 1.97e14
+
+
+def test_peak_flops_none_for_unknown_device_kind(monkeypatch):
+    monkeypatch.delenv("MXTPU_PEAK_FLOPS", raising=False)
+    monkeypatch.setattr(goodput, "_KIND_PEAK", {"TPU v99": 1.0e15})
+    goodput._reset_for_tests()
+    try:
+        assert goodput.peak_flops() is None
+        # "not measured", and no gauge is set from a guess
+        assert goodput.mfu_value(1e9, 1.0, source="t") is None
+        # the operator's figure still wins
+        monkeypatch.setenv("MXTPU_PEAK_FLOPS", "2e10")
+        assert goodput.peak_flops() == 2e10
+    finally:
+        goodput._reset_for_tests()
 
 
 def test_step_record_carries_nonzero_mfu(tmp_path, monkeypatch):
@@ -300,7 +326,7 @@ def test_step_record_carries_nonzero_mfu(tmp_path, monkeypatch):
     rec = timer.end_step(batch_size=2)
     close_stream()
     assert rec["step_flops"] == 5.0e8
-    assert 0.0 < rec["mfu"] <= 1.0
+    assert rec["mfu"] > 0.0     # a ratio, no longer clamped to 1
     streamed = [json.loads(l) for l in
                 out.read_text().splitlines()][-1]
     assert streamed["mfu"] == rec["mfu"]

@@ -178,6 +178,18 @@ def test_context_placement():
     assert c.context.device_id in (0, 1)  # single-device fallback allowed
 
 
+@pytest.mark.parametrize("make", [mx.tpu, mx.gpu])
+def test_accelerator_context_raises_off_the_accelerator(make):
+    """No fallback that hides the device: on the CPU backend a context
+    that names an accelerator raises, naming the backend it found."""
+    import jax
+    assert jax.default_backend() == "cpu"
+    with pytest.raises(mx.MXNetError, match="backend is 'cpu'"):
+        make(0).jax_device
+    with pytest.raises(mx.MXNetError, match="backend is 'cpu'"):
+        nd.ones((2,), ctx=make(0))
+
+
 def test_serialization(tmp_path):
     fname = str(tmp_path / "arrs.npz")
     data = {"w": nd.array(np.random.rand(3, 3)), "b": nd.ones((3,))}
@@ -259,11 +271,9 @@ def test_gather_scatter():
 
 def test_strict_fence(monkeypatch):
     """wait_to_read/wait_to_write/waitall share ONE fence (_fence), and
-    strict mode device_gets a dependent slice — the only reliable fence
-    on remote/tunneled backends where block_until_ready can return
-    before remote execution completes (docs/faq/env_var.md,
-    MXTPU_STRICT_FENCE; reference WaitToRead semantics,
-    include/mxnet/ndarray.h:315)."""
+    the strict mode one can ask for device_gets a dependent slice on top
+    of block_until_ready (docs/faq/env_var.md, MXTPU_STRICT_FENCE;
+    reference WaitToRead semantics, include/mxnet/ndarray.h:315)."""
     import jax
     from mxnet_tpu.ndarray import ndarray as nd_mod
 

@@ -588,8 +588,12 @@ def _run_case(name, spec):
         f()          # smoke only: no differentiable inputs by contract
         return
 
+    # one program for f and one for its gradient instead of one tiny
+    # program per primitive: the compiles are what this gate costs
+    grad = jax.jit(jax.grad(f, argnums=tuple(range(len(wrt)))))
+    f = jax.jit(f)
     diffs = [arrays[j] for j in wrt]
-    grads = jax.grad(f, argnums=tuple(range(len(wrt))))(*diffs)
+    grads = grad(*diffs)
     eps = spec["eps"]
     for k, j in enumerate(wrt):
         base = np.asarray(arrays[j], "float64")

@@ -44,8 +44,7 @@ def main():
     else:
         print("devices      : UNAVAILABLE (%s)" % err)
         print("  recovery   : python tools/kill_stale.py --kill  "
-              "(reaps init-hung holders; relay-side lease wedges "
-              "clear with time — retry with backoff)")
+              "(reaps init-hung holders of the device)")
         try:
             from tools.kill_stale import find_candidates
             for c in find_candidates():
