@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import MXNetError
+from .observability.trace import trace_span
 
 _state = threading.local()
 
@@ -269,7 +270,9 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
     from .ndarray.ndarray import NDArray
     if isinstance(heads, NDArray):
         heads = [heads]
-    _walk(heads, _normalize_head_grads(heads, head_grads), retain_graph)
+    with trace_span("frontend.backward"):
+        _walk(heads, _normalize_head_grads(heads, head_grads),
+              retain_graph)
 
 
 def _build_head_fn(heads, variables):

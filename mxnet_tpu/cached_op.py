@@ -12,6 +12,8 @@ trading FLOPs for memory exactly like MXNET_BACKWARD_DO_MIRROR).
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 
@@ -19,6 +21,7 @@ from .base import MXNetError, getenv
 from .graph import build_graph_fn, collect_vars
 from .ndarray import NDArray
 from .observability import registry as _obs
+from .observability.trace import trace_span
 from . import autograd
 from . import random as _random
 
@@ -54,6 +57,9 @@ class CachedOp:
         self._fwd_jits = {}
         self._bwd_jits = {}
         self._stub = _GraphOpStub("cached_op_%s" % (sym.name or "graph"))
+        # the compiled programs' names: `jit_cachedop_fwd_<symbol>` in a
+        # device trace and in the program table (compile/programs.py)
+        self._program = re.sub(r"[^A-Za-z0-9_]", "_", sym.name or "graph")
 
     @property
     def input_names(self):
@@ -73,6 +79,7 @@ class CachedOp:
             from .compile.cache import enable_cache
             enable_cache()   # flag check after the first build
             fn, _, _, needs_rng = build_graph_fn(self._symbol._entries, mode)
+            fn.__name__ = "cachedop_fwd_" + self._program
             self._fwd_jits[mode] = (jax.jit(fn), needs_rng)
         return self._fwd_jits[mode]
 
@@ -89,6 +96,8 @@ class CachedOp:
                 _, vjp_fn = jax.vjp(f, args)
                 return vjp_fn(list(cots))[0]
 
+            bwd.__name__ = "cachedop_bwd_" + self._program
+
             # MXTPU_DONATE_CACHEDOP=1: donate the output cotangents —
             # the one backward input that is step-local (weights/aux
             # must outlive the call). Opt-in: a cotangent can alias a
@@ -101,6 +110,10 @@ class CachedOp:
         return self._bwd_jits[mode]
 
     def __call__(self, *inputs):
+        with trace_span("frontend.forward"):
+            return self._call(inputs)
+
+    def _call(self, inputs):
         if len(inputs) != len(self._input_names):
             raise MXNetError(
                 "CachedOp: expected %d inputs (%s), got %d"

@@ -138,6 +138,10 @@ def enable_cache(path=None):
     with _lock:
         if _state["enabled"] is not None and path is None:
             return _state["dir"]
+        # the program table rides the same private compile path and is
+        # wanted with or without a cache directory (compile/programs.py)
+        from . import programs
+        programs.install()
         target = path if path is not None else resolve_cache_dir()
         if target is None:
             _state["enabled"], _state["dir"] = False, None

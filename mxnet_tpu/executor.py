@@ -167,7 +167,9 @@ class Executor:
         self._needs_rng = needs_rng
         grad_names = tuple(self._grad_names)
 
+        # the programs' names in a device trace and the program table
         if not fused:
+            fn.__name__ = "executor_" + mode
             jitted = jax.jit(fn)
         else:
             def fwdbwd(args, aux, key, ograds):
@@ -184,6 +186,7 @@ class Executor:
                 grads = vjp_fn(list(ograds))[0]
                 return outs, auxup, grads
 
+            fwdbwd.__name__ = "executor_fwdbwd"
             jitted = jax.jit(fwdbwd)
         self._jits[key] = jitted
         return jitted

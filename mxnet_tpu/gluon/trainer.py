@@ -20,7 +20,7 @@ Fused one-program step (docs/performance.md "Fused train step &
 ZeRO-1", default on): `step()` runs gradient exchange + optimizer
 update as ONE donated jit program (parallel/fused_step.py) — no
 host-visible buffers or Python between the phases, recorded as a
-single "step" phase in telemetry. ``MXTPU_FUSED_STEP=0``, unsupported
+single "step.launch" phase in telemetry. ``MXTPU_FUSED_STEP=0``, unsupported
 optimizers, compression, or update-on-kvstore fall back to the staged
 bucketed path below (the bit-parity oracle); `allreduce_grads()` /
 `update()` always take the staged halves, unchanged.
@@ -30,6 +30,7 @@ from __future__ import annotations
 from .. import optimizer as opt
 from ..kvstore import create as _create_kvstore
 from ..observability.telemetry import StepTimer
+from ..observability.trace import trace_span
 from ..parallel import fused_step as _fstep
 from ..resilience import numerics as _numerics
 from ..resilience.atomic import atomic_write
@@ -195,27 +196,36 @@ class Trainer:
         (reference: trainer.py:241). With ``MXTPU_FUSED_STEP`` (default
         on) both phases run as ONE donated jit program — the gradient
         exchange and the fused update share an XLA computation, so the
-        telemetry record carries a single "step" phase and
-        `train.step.dispatches` reads exactly 1."""
-        # step boundary: params/opt-state are consistent here, so a
-        # pending SIGTERM checkpoints and stops BEFORE new work starts
-        # (resilience/preempt.py)
-        at_step_boundary()
-        self._ensure_ready()
+        telemetry record carries a single "step.launch" phase and
+        `train.step.dispatches` reads exactly 1. The spans are every
+        trainer's (docs/observability.md "Step spans"): `step.prepare`
+        to just before the compiled program is called, `step.launch`
+        around the call (`allreduce` and `optimizer` on the staged
+        path), `step.finish` from there to the return."""
         tel = self._telemetry
         tel.begin_step()
-        self._optimizer.rescale_grad = self._rescale(batch_size)
-        if not self._fused_step(ignore_stale_grad, tel):
+        with trace_span("step.prepare"):
+            # step boundary: params/opt-state are consistent here, so a
+            # pending SIGTERM checkpoints and stops BEFORE new work
+            # starts (resilience/preempt.py)
+            at_step_boundary()
+            self._ensure_ready()
+            self._optimizer.rescale_grad = self._rescale(batch_size)
+            plan = self._fused_plan(ignore_stale_grad)
+        if plan is None or not self._fused_launch(plan, tel):
             with tel.phase("allreduce"):
                 self._reduce()
             with tel.phase("optimizer"):
                 self._apply_updates(ignore_stale_grad)
-        self._numerics_boundary(tel)
-        tel.end_step(batch_size=batch_size)
+        with trace_span("step.finish"):
+            self._numerics_boundary(tel)
+            tel.end_step(batch_size=batch_size, close_root=False)
+        tel.close_root()
 
-    def _fused_step(self, ignore_stale_grad, tel):
-        """Try the one-program exchange+update step
-        (parallel/fused_step.py). Returns True when it ran; False falls
+    def _fused_plan(self, ignore_stale_grad):
+        """What the one-program exchange+update step
+        (parallel/fused_step.py) would run on: (slots, grads, weights,
+        kvstore), empty when there is nothing to update; None falls
         back to the staged bucketed path with nothing mutated.
 
         ZeRO-1 note (docs/performance.md): with ``MXTPU_ZERO1=1`` in a
@@ -224,7 +234,7 @@ class Trainer:
         a rank-0-only save_states would deadlock (save through
         `parallel.TrainerCheckpoint` or call it on every rank)."""
         if not _fstep.enabled() or self._update_via_kv:
-            return False
+            return None
         kv = self._kvstore if self._reduce_via_kv else None
         multi = getattr(kv, "num_workers", 1) > 1
         if ignore_stale_grad and multi:
@@ -232,27 +242,34 @@ class Trainer:
             # by it would desynchronize the SPMD program across ranks
             # (the staged path always exchanges the full trainable
             # set) — staged, unconditionally
-            return False
+            return None
         pairs = self._trainable()
         if ignore_stale_grad:
             pairs = [(i, p) for i, p in pairs if p.grad()._fresh_grad]
-        if not pairs:
-            return True      # nothing to update: zero dispatches
         idxs = [i for i, _ in pairs]
         # cheap latched pre-check BEFORE the phase opens: permanently
         # staged runs (RMSProp, compression, refused key sets) must
-        # not emit a bogus "step" trace span every iteration
-        if not _fstep.eligible(self._updaters[0], idxs, kvstore=kv):
-            return False
-        grads = [p.grad() for _, p in pairs]
-        with tel.phase("step"):
-            ran = _fstep.try_step(
-                self._updaters[0], idxs, grads,
-                [p.data() for _, p in pairs], kvstore=kv)
+        # not emit a bogus "step.launch" trace span every iteration
+        if pairs and not _fstep.eligible(self._updaters[0], idxs,
+                                         kvstore=kv):
+            return None
+        return (idxs, [p.grad() for _, p in pairs],
+                [p.data() for _, p in pairs], kv)
+
+    def _fused_launch(self, plan, tel):
+        """Run the planned one-program step. Returns True when it ran
+        (or had nothing to update: zero dispatches); False falls back
+        to the staged path with nothing mutated."""
+        idxs, grads, weights, kv = plan
+        if not idxs:
+            return True
+        with tel.phase("step.launch"):
+            ran = _fstep.try_step(self._updaters[0], idxs, grads,
+                                  weights, kvstore=kv)
         if not ran:
             # first-time collect refusal (now latched): drop the empty
             # phase so the staged record keeps its shape
-            tel._phases.pop("step", None)
+            tel._phases.pop("step.launch", None)
             return False
         if self._numerics is not None:
             # kept for the boundary's SDC replay digest (grads are not

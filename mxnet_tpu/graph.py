@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import MXNetError
+from .compile import programs as _programs
 from .ops import registry as _reg
 
 
@@ -141,6 +142,12 @@ def build_graph_fn(output_entries, mode="predict"):
         if node.op.takes_mode:
             p["_mode"] = mode
         node_params[id(node)] = p
+    # every node's ops carry `mx.<op>.<node>` in their HLO metadata, so
+    # a device trace's instructions have an owner (compile/programs.py);
+    # JAX writes `transpose(jvp(...))` around it for the backward ops
+    node_scope = {id(node): ("mx.%s.%s" % (node.op.name, node.name))
+                  .replace("/", "_")
+                  for node in order if not node.is_variable}
 
     train = mode == "train"
 
@@ -163,7 +170,8 @@ def build_graph_fn(output_entries, mode="predict"):
                         "provided" % op.name)
                 key, sub = jax.random.split(key)
                 arrs = [sub] + arrs
-            raw = op.fn(*arrs, **node_params[id(node)])
+            with _programs.scope(node_scope[id(node)]):
+                raw = op.fn(*arrs, **node_params[id(node)])
             if not isinstance(raw, tuple):
                 raw = (raw,)
             values[id(node)] = raw

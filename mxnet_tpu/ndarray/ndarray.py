@@ -17,6 +17,7 @@ jit-cached XLA executables, plus autograd tape recording via jax.vjp.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 import weakref
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 
 from ..base import MXNetError, dtype_from_name, dtype_name
 from ..context import Context, current_context
+from ..observability.trace import trace_span
 from ..ops import registry as _reg
 
 __all__ = ["NDArray", "invoke", "array", "zeros", "ones", "full", "empty",
@@ -153,7 +155,10 @@ class NDArray:
     # sync / conversion (reference: ndarray.py:1951 asnumpy sync point)
     # ------------------------------------------------------------------
     def asnumpy(self):
-        return np.asarray(self._data)
+        # `fence`: where a training thread blocks on the device; the
+        # span that lays the step spans on a device trace's clock
+        with trace_span("fence"):
+            return np.asarray(self._data)
 
     def asscalar(self):
         if self.size != 1:
@@ -167,7 +172,8 @@ class NDArray:
         return self.asnumpy().tolist()
 
     def wait_to_read(self):
-        _fence(self._data)
+        with trace_span("fence"):
+            _fence(self._data)
 
     wait_to_write = wait_to_read
 
@@ -485,6 +491,7 @@ def _compiled(op_name, hparams):
     def run(*arrays):
         return op.fn(*arrays, **params)
 
+    run.__name__ = "op_" + op_name      # the program's name in a trace
     return jax.jit(run)
 
 
@@ -559,6 +566,9 @@ def invoke(op, inputs, params, name=None):
 # ---------------------------------------------------------------------------
 
 
+_NO_SPAN = contextlib.nullcontext()
+
+
 def _place(arr, ctx):
     ctx = ctx or current_context()
     return NDArray(jax.device_put(arr, ctx.jax_device), ctx)
@@ -576,8 +586,12 @@ def array(source, ctx=None, dtype=None):
                 dtype = np.int32
         else:
             dtype = np.float32
-    arr = jnp.asarray(np.asarray(source, dtype=dtype_from_name(dtype)))
-    return _place(arr, ctx)
+    # `input.stage`: a host batch copied to its context on the
+    # consumer's thread
+    with (trace_span("input.stage") if isinstance(source, np.ndarray)
+          else _NO_SPAN):
+        arr = jnp.asarray(np.asarray(source, dtype=dtype_from_name(dtype)))
+        return _place(arr, ctx)
 
 
 def zeros(shape, ctx=None, dtype="float32", stype=None, **kw):

@@ -61,6 +61,7 @@ def debug_snapshot(extra=None):
     """The /debugz payload: one JSON-able dict of live process state.
     `extra` (the gateway passes admission queues, registry residency,
     decode slot occupancy) is merged in under its own keys."""
+    from ..compile import programs as _programs
     from ..resilience import lease as _lease
     from . import goodput as _goodput
     from . import memory as _memory
@@ -82,6 +83,10 @@ def debug_snapshot(extra=None):
             "aot_loads": _counter_value("compile.aot.loads"),
             "aot_fallbacks": _counter_value("compile.aot.fallbacks"),
         },
+        # what compiled or loaded here, by module name (docs/
+        # observability.md "Program table"): builds, cache outcomes,
+        # seconds, and whether the program carries the `mx.` scopes
+        "programs": _programs.snapshot(),
         "labels_dropped": _counter_value("observability.labels.dropped"),
         "trace": _trace.trace_stats(),
         "metric_families": len(REGISTRY.metrics()),

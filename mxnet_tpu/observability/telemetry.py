@@ -27,7 +27,6 @@ import warnings
 
 from . import trace as _trace
 from .registry import REGISTRY, counter, gauge, histogram
-from .span import span
 
 __all__ = ["StepTimer", "stream_path", "stream_enabled", "emit",
            "close_stream", "COMPILE_COUNT", "COMPILE_SECONDS",
@@ -224,21 +223,19 @@ def _counters_snapshot():
 
 
 class _Phase:
-    """Accumulates one named phase's wall time into its StepTimer,
-    doubles as a profiler span (chrome trace whenever the profiler
-    runs), and as a trace span child of the step's trace root (the
-    merged per-step timeline in tools/trace_report.py)."""
+    """Accumulates one named phase's wall time into its StepTimer and
+    records it once, as a trace span child of the step's trace root
+    (the merged per-step timeline in tools/trace_report.py; the ring's
+    sink mirrors it into the chrome trace while the profiler runs)."""
 
-    __slots__ = ("_timer", "_name", "_t0", "_span", "_tspan")
+    __slots__ = ("_timer", "_name", "_t0", "_tspan")
 
     def __init__(self, timer, name):
         self._timer = timer
         self._name = name
-        self._span = span("step/" + name)
         self._tspan = _trace.trace_span(name)
 
     def __enter__(self):
-        self._span.__enter__()
         self._tspan.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -246,7 +243,6 @@ class _Phase:
     def __exit__(self, *exc):
         dt = time.perf_counter() - self._t0
         self._tspan.__exit__(*exc)
-        self._span.__exit__(*exc)
         phases = self._timer._phases
         phases[self._name] = phases.get(self._name, 0.0) + dt
         return False
@@ -283,18 +279,14 @@ class StepTimer:
         self._phases = {}
         self._last_end = None
         self._snap = None
-        self._trace_span = None
+        self._root = _trace.StepRoot(source)
 
     def begin_step(self):
         # a failed step never reached end_step: drop its phase times so
-        # the aborted attempt doesn't inflate the next record, and
-        # close its abandoned trace root (restores this thread's ctx)
+        # the aborted attempt doesn't inflate the next record (its
+        # trace root stays open: this attempt goes on under it)
         self._phases = {}
-        if self._trace_span is not None:
-            self._trace_span.__exit__(None, None, None)
-            self._trace_span = None
-        first = self._last_end is None
-        if first:
+        if self._last_end is None:
             self._last_end = time.perf_counter()
             self._snap = _counters_snapshot()
         # live introspection plane: training ranks bind /metricsz +
@@ -303,29 +295,21 @@ class StepTimer:
         _httpz.maybe_start()
         # per-step trace root (docs/observability.md "Distributed
         # tracing"): trace id hashed from (gang dir, source, step) so
-        # all ranks share it; t0 backdated to the previous step's end,
-        # so the root covers the FULL iteration (fwd/bwd included)
-        ctx = _trace.step_trace_context(self.source, self.step)
-        if ctx is not None:
-            sp = _trace.trace_span("step", ctx=ctx, t0=self._last_end,
-                                   step=self.step, source=self.source)
-            sp.__enter__()
-            self._trace_span = sp
-            now = time.perf_counter()
-            if not first and sp.span_id and now - self._last_end > 1e-6:
-                # retroactive child covering previous-end -> here: the
-                # forward/backward + input window that ran before the
-                # trainer's step() call
-                _trace.record_span("fwd_bwd", _trace.current(),
-                                   self._last_end, now)
+        # all ranks share it; opened where the previous end_step
+        # returned, so the root covers the FULL iteration and the
+        # spans of forward, backward and input record under it
+        self._root.begin(self.step)
 
     def phase(self, name):
         return _Phase(self, name)
 
-    def end_step(self, batch_size=None, **extra):
+    def end_step(self, batch_size=None, close_root=True, **extra):
         """Close the current step: observe the step-time histogram and
         (streaming on) emit the JSONL record. Returns the record dict
-        (also when streaming is off — callers/tests can inspect it)."""
+        (also when streaming is off — callers/tests can inspect it).
+        A caller that holds a trace span open around this call passes
+        `close_root=False` and calls `close_root()` once its span has
+        closed: the iteration's root closes last."""
         now = time.perf_counter()
         if self._last_end is None:  # end without begin: degenerate step
             self._last_end = now
@@ -382,18 +366,20 @@ class StepTimer:
             if step_time > 0:
                 record["samples_per_sec"] = batch_size / step_time
         record.update(extra)
-        trace_id = None
-        if self._trace_span is not None:
-            if self._trace_span.span_id:
-                trace_id = self._trace_span.ctx.trace_id
-                record["trace_id"] = trace_id
-            self._trace_span.__exit__(None, None, None)
-            self._trace_span = None
         self.step += 1
+        trace_id = self._root.trace_id
+        if trace_id is not None:
+            record["trace_id"] = trace_id
         # worst-K step times retain their trace ids as exemplars: a
         # step-time p99 breach names a concrete traceable step
         STEP_SECONDS.observe(step_time, exemplar=trace_id,
                              source=self.source)
         if stream_path() is not None:
             emit(record)
+        if close_root:
+            self.close_root()
         return record
+
+    def close_root(self):
+        """Close this iteration's trace root and open the next one's."""
+        self._root.end(self.step)

@@ -26,8 +26,11 @@ and step-scoped* tracing plane on top:
   ``jax.profiler.TraceAnnotation`` named by the trace id, so host
   spans line up with the XLA profiler timeline.
 
-Env knobs (re-read per use — so tests/long jobs can toggle live —
-except MXTPU_TRACE_BUFFER, which sizes the ring once at import):
+Env knobs (resolved once a step or request, where a root context is
+made — `step_trace_context`, `TraceContext.new`/`from_traceparent`, a
+`trace_span` given its `ctx=`, `enabled()` — so tests/long jobs can
+toggle live and a recorded span reads no environment at all; except
+MXTPU_TRACE_BUFFER, which sizes the ring once at import):
 
   MXTPU_TRACE          0 disables the whole plane (contexts, spans,
                        shards all become no-ops)                  (1)
@@ -46,20 +49,22 @@ from __future__ import annotations
 
 import contextlib
 import hashlib
+import itertools
 import json
 import os
-import secrets
+import random
 import threading
 import time
 from collections import deque
 
+from .. import profiler as _prof
 from ..base import getenv
 
 __all__ = ["TraceContext", "trace_span", "record_span", "current",
-           "capture", "attached", "device_annotation", "enabled",
-           "sample_rate", "shard_dir", "shard_path", "ring_spans",
-           "reset_ring", "trace_stats", "step_trace_context",
-           "current_rank"]
+           "capture", "attached", "detach", "device_annotation",
+           "enabled", "sample_rate", "shard_dir", "shard_path",
+           "ring_spans", "reset_ring", "trace_stats",
+           "step_trace_context", "StepRoot", "current_rank"]
 
 # wall/perf clock pair captured at import: every span's `ts` is wall
 # time derived from perf_counter stamps (monotonic within the process),
@@ -69,31 +74,102 @@ _CLOCK_WALL = time.time()
 _CLOCK_PERF = time.perf_counter()
 
 
-def _wall(perf_t):
-    return _CLOCK_WALL + (perf_t - _CLOCK_PERF)
+# A step's context reads eight names, most of them unset, and
+# `os.environ.get` pays an encode and a raised KeyError for each
+# (1.5 us apiece where this was measured): look them up in the mapping
+# `os.environ` itself keeps, under keys encoded once. An interpreter
+# without that mapping takes the public way.
+_ENV_DATA = getattr(os.environ, "_data", None)
+_ENV_KEYS = {}
+
+
+def _env(name):
+    if _ENV_DATA is None:
+        return os.environ.get(name)
+    key = _ENV_KEYS.get(name)
+    if key is None:
+        key = _ENV_KEYS[name] = os.environ.encodekey(name)
+    val = _ENV_DATA.get(key)
+    return None if val is None else os.environ.decodevalue(val)
+
+
+def _env2(name):
+    """MXTPU_<x>, else the reference's spelling MXNET_<x> (`getenv`)."""
+    val = _env("MXTPU_" + name)
+    return val if val is not None else _env("MXNET_" + name)
+
+
+class _Config:
+    """The plane's switches as the environment last gave them."""
+
+    __slots__ = ("on", "rate", "shard", "rank", "token")
+
+    def __init__(self):
+        on, rate = _env2("TRACE"), _env2("TRACE_SAMPLE")
+        self.on = on not in ("0", "false", "False", "")
+        self.rate = 1.0 if rate is None else float(rate)
+        r = _env("JAX_PROCESS_ID") or _env("DMLC_WORKER_ID")
+        try:
+            self.rank = int(r)
+        except (TypeError, ValueError):
+            self.rank = 0
+        gang = _env("MXTPU_GANG_DIR")
+        d = _env("MXTPU_TRACE_DIR") or gang or None
+        self.shard = (os.path.join(d, "trace_rank_%d.jsonl" % self.rank)
+                      if d else None)
+        self.token = gang or ("pid:%d" % _PID)
+
+
+_PID = os.getpid()
+# span and trace ids: a per-process random prefix and a counter (unique
+# across ranks without a system call a span)
+_ID_PREFIX = random.getrandbits(32)
+_ID_COUNTER = itertools.count(1)
+
+
+def _after_fork():
+    global _PID, _ID_PREFIX, _cfg
+    _PID = os.getpid()
+    _ID_PREFIX = random.getrandbits(32)
+    _cfg = _Config()
+
+
+os.register_at_fork(after_in_child=_after_fork)
+_cfg = _Config()
+
+
+def _refresh():
+    """Re-read the environment: once a step or request, never a span."""
+    global _cfg
+    _cfg = _Config()
+    return _cfg
 
 
 def enabled():
-    return bool(getenv("MXTPU_TRACE", True))
+    return _refresh().on
 
 
 def sample_rate():
-    return float(getenv("MXTPU_TRACE_SAMPLE", 1.0))
+    return _refresh().rate
 
 
 def current_rank():
     """This process's gang/dist rank (0 outside a gang) — the shard
     tag and the `rank` attr on every span."""
-    r = os.environ.get("JAX_PROCESS_ID") or os.environ.get(
-        "DMLC_WORKER_ID")
-    try:
-        return int(r)
-    except (TypeError, ValueError):
-        return 0
+    return _refresh().rank
 
 
 def _new_id(nbytes):
-    return secrets.token_hex(nbytes)
+    """8 (a span id) or 16 (a trace id) bytes of hex: the process
+    prefix, then the counter."""
+    n = next(_ID_COUNTER)
+    if nbytes == 8:                                  # a span id
+        return "%08x%08x" % (_ID_PREFIX, n & 0xffffffff)
+    return "%08x%08x%016x" % (_ID_PREFIX, _PID & 0xffffffff, n)
+
+
+def _sampled(rate):
+    return rate >= 1.0 or (rate > 0.0 and random.random() < rate)
 
 
 class TraceContext:
@@ -115,10 +191,7 @@ class TraceContext:
         MXTPU_TRACE_SAMPLE (identity is always created — an unsampled
         request still echoes its trace id, it just records nothing)."""
         if sampled is None:
-            rate = sample_rate()
-            sampled = rate >= 1.0 or (
-                rate > 0.0 and
-                int(_new_id(4), 16) / float(0xffffffff) < rate)
+            sampled = _sampled(_refresh().rate)
         return cls(_new_id(16), None, sampled)
 
     @classmethod
@@ -126,6 +199,7 @@ class TraceContext:
         """Parse a ``traceparent`` header (version 00). Returns None on
         anything malformed — a bad header means a fresh root, never an
         error surfaced to the client."""
+        _refresh()
         if not header or not isinstance(header, str):
             return None
         parts = header.strip().lower().split("-")
@@ -165,13 +239,13 @@ def step_trace_context(source, step):
     step-S spans in the SAME trace id, and `tools/trace_report.py` can
     merge shards into one per-step timeline with zero coordination.
     The sampling verdict hashes too — ranks always agree."""
-    if not enabled():
+    cfg = _refresh()
+    if not cfg.on:
         return None
-    token = os.environ.get("MXTPU_GANG_DIR") or ("pid:%d" % os.getpid())
     digest = hashlib.sha256(
-        ("mxtpu-step:%s:%s:%d" % (token, source, int(step)))
+        ("mxtpu-step:%s:%s:%d" % (cfg.token, source, int(step)))
         .encode()).hexdigest()
-    rate = sample_rate()
+    rate = cfg.rate
     sampled = rate >= 1.0 or (
         rate > 0.0 and int(digest[32:40], 16) / float(0xffffffff) < rate)
     return TraceContext(digest[:32], None, sampled)
@@ -211,6 +285,13 @@ def attached(ctx):
         _tls.ctx = prev
 
 
+def detach():
+    """Drop the calling thread's context: a training loop's root stays
+    current on its thread between steps (`StepRoot`), so a thread that
+    goes on to other work after its last step calls this."""
+    _tls.ctx = None
+
+
 # -- span sink: in-memory ring + rank-tagged shard file -----------------
 _ring_lock = threading.Lock()
 _ring = deque(maxlen=int(getenv("MXTPU_TRACE_BUFFER", 4096)))
@@ -227,19 +308,13 @@ def shard_dir():
 
 
 def shard_path():
-    d = shard_dir()
-    if not d:
-        return None
-    return os.path.join(d, "trace_rank_%d.jsonl" % current_rank())
+    return _refresh().shard
 
 
-def _shard_file():
-    """Open (or re-resolve) this process's shard, writing one `clock`
-    record at open so the merger can map this rank's perf-derived
-    timestamps and estimate cross-rank offsets."""
-    path = shard_path()
-    if path is None:
-        return None
+def _shard_file(path):
+    """Open (or re-resolve) this process's shard at `path`, writing one
+    `clock` record at open so the merger can map this rank's
+    perf-derived timestamps and estimate cross-rank offsets."""
     with _shard_lock:
         if _shard["path"] != path or _shard["file"] is None:
             if _shard["file"] is not None:
@@ -263,7 +338,7 @@ def _shard_file():
             clock = {"source": "trace", "event": "clock",
                      "step_time": 0.0, "ts": time.time(),
                      "perf": time.perf_counter(),
-                     "rank": current_rank(), "pid": os.getpid()}
+                     "rank": _cfg.rank, "pid": _PID}
             try:
                 f.write(json.dumps(clock, sort_keys=True) + "\n")
             except (OSError, ValueError):
@@ -306,10 +381,11 @@ def trace_stats():
     for s in spans:
         traces.setdefault(s.get("trace_id"), 0)
         traces[s["trace_id"]] += 1
+    cfg = _refresh()
     return {
-        "enabled": enabled(),
-        "sample_rate": sample_rate(),
-        "shard": shard_path(),
+        "enabled": cfg.on,
+        "sample_rate": cfg.rate,
+        "shard": cfg.shard,
         "ring_spans": len(spans),
         "ring_traces": len(traces),
         "recent_trace_ids": list(traces)[-8:],
@@ -330,31 +406,36 @@ def record_span(name, ctx, t0, t1, parent_id=_INHERIT, span_id=None,
     retroactive sub-spans — batch consumers reconstruct per-request
     queue/compute spans this way), or None when the context is
     absent/unsampled/disabled — recording is best-effort and never
-    raises into the traced path."""
-    if ctx is None or not ctx.sampled or not enabled():
+    raises into the traced path. The record keeps the perf stamp
+    (`t0`) beside the wall time derived from it (`ts`), so a reader
+    can lay the span on any clock that ticks with `perf_counter`.
+    Reads no environment: the switches are those of the last root."""
+    cfg = _cfg
+    if ctx is None or not ctx.sampled or not cfg.on:
         return None
     span_id = span_id or _new_id(8)
     rec = {"source": "trace", "event": "span", "name": name,
            "trace_id": ctx.trace_id, "span_id": span_id,
            "parent_id": ctx.span_id if parent_id is _INHERIT
            else parent_id,
-           "ts": _wall(t0), "step_time": max(0.0, t1 - t0),
-           "rank": current_rank(), "pid": os.getpid(),
+           "t0": t0, "ts": _CLOCK_WALL + (t0 - _CLOCK_PERF),
+           "step_time": t1 - t0 if t1 > t0 else 0.0,
+           "rank": cfg.rank, "pid": _PID,
            "tid": threading.get_ident() & 0xffff}
     if attrs:
         rec.update({k: v for k, v in attrs.items() if v is not None})
     with _ring_lock:
         _ring.append(rec)
-    f = _shard_file()
-    if f is not None:
-        try:
-            with _shard_lock:
-                f.write(json.dumps(rec, sort_keys=True) + "\n")
-        except (OSError, ValueError, TypeError):
-            pass
+    if cfg.shard is not None:
+        f = _shard_file(cfg.shard)
+        if f is not None:
+            try:
+                with _shard_lock:
+                    f.write(json.dumps(rec, sort_keys=True) + "\n")
+            except (OSError, ValueError, TypeError):
+                pass
     # mirror into the profiler's chrome-trace stream when it is
     # running, so host trace spans and eager-op rows share a timeline
-    from .. import profiler as _prof
     if _prof._running["on"]:
         _prof._record_event(name, t0, t1, cat="trace",
                             args={"trace_id": ctx.trace_id,
@@ -381,8 +462,11 @@ class trace_span:
         self._t0_override = t0
 
     def __enter__(self):
-        parent = self.ctx if self.ctx is not None else current()
-        self._on = (parent is not None and parent.sampled and enabled())
+        if self.ctx is not None:
+            parent, cfg = self.ctx, _refresh()    # a root: once a request
+        else:
+            parent, cfg = getattr(_tls, "ctx", None), _cfg
+        self._on = (parent is not None and parent.sampled and cfg.on)
         if not self._on:
             # still make an explicitly-passed root context current, so
             # children opened inside inherit identity (for the echoed
@@ -407,6 +491,7 @@ class trace_span:
             if self._prev is not False:
                 _tls.ctx = self._prev
             return False
+        t1 = time.perf_counter()
         _tls.ctx = self._prev
         attrs = self.attrs
         if exc_type is not None:
@@ -415,10 +500,64 @@ class trace_span:
         # captured the context while we were active resolve to a real
         # recorded span; self._parent is None for roots, which
         # record_span keeps as an explicit root (no inherit)
-        record_span(self.name, self.ctx, self._t0, time.perf_counter(),
+        record_span(self.name, self.ctx, self._t0, t1,
                     parent_id=self._parent, span_id=self.span_id,
                     **attrs)
         return False
+
+
+class StepRoot:
+    """The root spans of one training loop, one an iteration, under one
+    rule for every trainer: the root of iteration n opens where the
+    previous `step()` returned (the first at its own entry) and closes
+    where this one returns, and its context stays current on the
+    training thread in between. So what a script runs between two
+    steps (the fence of the previous loss, the wait for a batch, the
+    forward and backward of a Gluon loop) records under the iteration
+    it belongs to, with that step's trace id. `begin` at the entry of
+    `step()`, `end` at its return; a step that raised never reached
+    `end`, and the next `begin` goes on under the same root."""
+
+    __slots__ = ("source", "_ctx", "_child", "_t0", "_step")
+
+    def __init__(self, source):
+        self.source = source
+        self._ctx = self._child = None
+        self._t0 = self._step = None
+
+    def _open(self, step, t0):
+        ctx = step_trace_context(self.source, step)
+        self._ctx, self._t0, self._step = ctx, t0, step
+        if ctx is not None and ctx.sampled:
+            self._child = TraceContext(ctx.trace_id, _new_id(8), True)
+        else:
+            self._child = ctx       # identity without records, or None
+        _tls.ctx = self._child
+
+    @property
+    def trace_id(self):
+        """The open root's trace id, or None where it records nothing."""
+        ctx = self._ctx
+        return ctx.trace_id if ctx is not None and ctx.sampled else None
+
+    def begin(self, step):
+        """Make iteration `step`'s root current on this thread, opening
+        it here if no earlier `end` did."""
+        if self._ctx is None or self._step != step:
+            self._open(step, time.perf_counter())
+        else:
+            _tls.ctx = self._child
+
+    def end(self, next_step):
+        """Close the open root and open iteration `next_step`'s where
+        this one ends."""
+        now = time.perf_counter()
+        ctx = self._ctx
+        if ctx is not None and ctx.sampled:
+            record_span("step", ctx, self._t0, now, parent_id=None,
+                        span_id=self._child.span_id, step=self._step,
+                        source=self.source)
+        self._open(next_step, now)
 
 
 def device_annotation(ctx=None, name=None):
@@ -427,7 +566,7 @@ def device_annotation(ctx=None, name=None):
     with host spans (`name` defaults to ``trace:<id>``). Returns a
     null context when there is nothing to annotate."""
     ctx = ctx if ctx is not None else current()
-    if ctx is None or not ctx.sampled or not enabled():
+    if ctx is None or not ctx.sampled or not _cfg.on:
         return contextlib.nullcontext()
     try:
         import jax

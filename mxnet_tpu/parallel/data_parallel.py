@@ -36,6 +36,8 @@ from ..ndarray import NDArray
 from .. import symbol as _sym
 from ..graph import build_graph_fn, collect_vars
 from .. import random as _random
+from ..compile.programs import scope as _scope
+from ..observability.trace import StepRoot, detach, trace_span
 from ..resilience import numerics as _num
 from ..resilience.preempt import at_step_boundary
 from . import fused_step as _fstep
@@ -264,6 +266,7 @@ class ShardedTrainer:
                                            self._opt_state, opt_sh)
         self._step_fn = None
         self._step_count = 0
+        self._root = StepRoot("sharded_trainer")
 
         if self._grad_compression is not None:
             # per-device error-feedback residuals: leading dp axis, one
@@ -362,41 +365,50 @@ class ShardedTrainer:
         data_names = set(self._data_names)
         guard = _num.enabled() if guarded is None else bool(guarded)
 
-        def step(params, aux, opt_state, inputs, key):
+        # the scopes name the owners of device time that no graph node
+        # is (compile/programs.py); the function's name is the program's
+        def sharded_step(params, aux, opt_state, inputs, key):
             if cd is not None:
                 # mixed precision: cast weights + data (not labels — class
                 # indices >256 are not exact in bf16) at the step boundary
-                inputs = {k: v.astype(cd)
-                          if k in data_names and
-                          jnp.issubdtype(v.dtype, jnp.floating) else v
-                          for k, v in inputs.items()}
+                with _scope("mx.cast"):
+                    inputs = {k: v.astype(cd)
+                              if k in data_names and
+                              jnp.issubdtype(v.dtype, jnp.floating) else v
+                              for k, v in inputs.items()}
 
             def loss_fn(p):
                 if cd is not None:
-                    p = {k: v.astype(cd) if v.ndim >= 2 else v
-                         for k, v in p.items()}
+                    with _scope("mx.cast"):
+                        p = {k: v.astype(cd) if v.ndim >= 2 else v
+                             for k, v in p.items()}
                 outs, auxup = fn({**p, **inputs}, aux, key)
                 return jnp.mean(outs[0].astype(jnp.float32)), auxup
 
             (loss, auxup), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-            new_params, new_state = opt_update(params, grads, opt_state,
-                                               **hp)
+            with _scope("mx.optimizer"):
+                new_params, new_state = opt_update(params, grads,
+                                                   opt_state, **hp)
             new_aux = dict(aux)
             new_aux.update(auxup or {})
             if guard:
-                ok = _grads_finite(grads)
                 keep = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
-                new_params = jax.tree.map(keep, new_params, params)
-                new_state = jax.tree.map(keep, new_state, opt_state)
-                # aux (BN stats) updated by a poisoned forward are
-                # suspect too: the skip preserves them with the rest
-                new_aux = jax.tree.map(keep, new_aux, dict(aux))
+                with _scope("mx.guard"):
+                    ok = _grads_finite(grads)
+                    # aux (BN stats) updated by a poisoned forward are
+                    # suspect too: the skip preserves them with the rest
+                    new_aux = jax.tree.map(keep, new_aux, dict(aux))
+                # XLA fuses these selects into the update and a fusion
+                # is owned by its root: they stay the optimizer's
+                with _scope("mx.optimizer/mx.guard"):
+                    new_params = jax.tree.map(keep, new_params, params)
+                    new_state = jax.tree.map(keep, new_state, opt_state)
             else:
                 ok = jnp.bool_(True)
             return new_params, new_aux, new_state, loss, ok
 
-        return step
+        return sharded_step
 
     def _shardings(self):
         param_sh = {n: NamedSharding(self._mesh, self._spec_for(n))
@@ -467,7 +479,8 @@ class ShardedTrainer:
         needs_rng = self._needs_rng
         guard = _num.enabled()
 
-        def many(params, aux, opt_state, inputs, key, n_steps, unroll):
+        def sharded_step_many(params, aux, opt_state, inputs, key,
+                              n_steps, unroll):
             def scan_body(carry, _):
                 params, aux, opt_state, key = carry
                 if needs_rng:
@@ -485,17 +498,18 @@ class ShardedTrainer:
                 # losses or the final params means some step of this
                 # window went bad (NaN in params persists once it
                 # appears, so the post-window check cannot miss it)
-                ok = jnp.all(jnp.stack(
-                    [jnp.isfinite(losses).all()]
-                    + [jnp.isfinite(p).all()
-                       for p in jax.tree.leaves(params)]))
+                with _scope("mx.guard"):
+                    ok = jnp.all(jnp.stack(
+                        [jnp.isfinite(losses).all()]
+                        + [jnp.isfinite(p).all()
+                           for p in jax.tree.leaves(params)]))
             else:
                 ok = jnp.bool_(True)
             return params, aux, opt_state, losses, ok
 
         param_sh, aux_sh, opt_sh, in_sh, rep = self._shardings()
         self._step_many_fn = jax.jit(
-            many,
+            sharded_step_many,
             in_shardings=(param_sh, aux_sh, opt_sh, in_sh, None),
             out_shardings=(param_sh, aux_sh, opt_sh, rep, rep),
             donate_argnums=(0, 1, 2), static_argnums=(5, 6))
@@ -598,6 +612,7 @@ class ShardedTrainer:
                         batch_end_callback(epoch, nbatch, loss)
             finally:
                 pf.close()
+        detach()        # the loop is over: its last root parents nothing
         return loss
 
     def _build_step_compressed(self):
@@ -626,15 +641,17 @@ class ShardedTrainer:
             if key is not None:
                 key = jax.random.fold_in(key, lax.axis_index(dp))
             if cd is not None:
-                inputs = {k: v.astype(cd)
-                          if k in data_names and
-                          jnp.issubdtype(v.dtype, jnp.floating) else v
-                          for k, v in inputs.items()}
+                with _scope("mx.cast"):
+                    inputs = {k: v.astype(cd)
+                              if k in data_names and
+                              jnp.issubdtype(v.dtype, jnp.floating) else v
+                              for k, v in inputs.items()}
 
             def loss_fn(p):
                 if cd is not None:
-                    p = {k: v.astype(cd) if v.ndim >= 2 else v
-                         for k, v in p.items()}
+                    with _scope("mx.cast"):
+                        p = {k: v.astype(cd) if v.ndim >= 2 else v
+                             for k, v in p.items()}
                 outs, auxup = fn({**p, **inputs}, aux, key)
                 return jnp.mean(outs[0].astype(jnp.float32)), auxup
 
@@ -682,11 +699,13 @@ class ShardedTrainer:
 
         guard = _num.enabled()
 
-        def step(params, aux, opt_state, residuals, inputs, key):
+        def sharded_step_compressed(params, aux, opt_state, residuals,
+                                    inputs, key):
             loss, grads, new_res, auxup = smapped(params, aux, inputs,
                                                   residuals, key)
-            new_params, new_state = opt_update(params, grads, opt_state,
-                                               **hp)
+            with _scope("mx.optimizer"):
+                new_params, new_state = opt_update(params, grads,
+                                                   opt_state, **hp)
             new_aux = dict(aux)
             new_aux.update(auxup or {})
             if guard:
@@ -695,12 +714,14 @@ class ShardedTrainer:
                 # state AND the error-feedback residuals through
                 # bit-identical (a NaN residual would otherwise poison
                 # every later compressed exchange)
-                ok = _grads_finite(grads)
                 keep = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
-                new_params = jax.tree.map(keep, new_params, params)
-                new_state = jax.tree.map(keep, new_state, opt_state)
-                new_aux = jax.tree.map(keep, new_aux, dict(aux))
-                new_res = jax.tree.map(keep, new_res, residuals)
+                with _scope("mx.guard"):
+                    ok = _grads_finite(grads)
+                    new_aux = jax.tree.map(keep, new_aux, dict(aux))
+                    new_res = jax.tree.map(keep, new_res, residuals)
+                with _scope("mx.optimizer/mx.guard"):
+                    new_params = jax.tree.map(keep, new_params, params)
+                    new_state = jax.tree.map(keep, new_state, opt_state)
             else:
                 ok = jnp.bool_(True)
             return new_params, new_aux, new_state, new_res, loss, ok
@@ -714,38 +735,43 @@ class ShardedTrainer:
         in_sh = {n: self._input_sharding(n, ndims.get(n))
                  for n in self._data_names + self._label_names}
         self._step_fn = jax.jit(
-            step,
+            sharded_step_compressed,
             in_shardings=(param_sh, aux_sh, opt_sh, res_sh, in_sh, None),
             out_shardings=(param_sh, aux_sh, opt_sh, res_sh, rep, rep),
             donate_argnums=(0, 1, 2, 3))
 
     def step(self, *batch_and_labels):
         """Run one fused train step; returns the scalar loss NDArray."""
-        # step boundary: state is consistent before new work begins, so
-        # a pending SIGTERM checkpoints and stops cleanly right here
-        # (resilience/preempt.py)
-        at_step_boundary()
-        names = self._data_names + self._label_names
-        if len(batch_and_labels) != len(names):
-            raise MXNetError("step expects %s" % (names,))
-        inputs = {}
-        ndims = {}
-        for n, x in zip(names, batch_and_labels):
-            arr = x._data if isinstance(x, NDArray) else jnp.asarray(x)
-            ndims[n] = arr.ndim
-            inputs[n] = jax.device_put(arr,
-                                       self._input_sharding(n, arr.ndim))
-        if self._step_fn is None:
-            self._input_ndims = ndims
-            if self._grad_compression is not None:
-                self._build_step_compressed()
-            else:
-                self._build_step()
-        key = _random.next_key() if self._needs_rng else None
+        # the iteration's root and the three spans every trainer's step
+        # has (docs/observability.md "Step spans"): host time between
+        # the fence and the launch is the chip's idle time
+        self._root.begin(self._step_count)
+        with trace_span("step.prepare"):
+            # step boundary: state is consistent before new work begins,
+            # so a pending SIGTERM checkpoints and stops cleanly right
+            # here (resilience/preempt.py)
+            at_step_boundary()
+            names = self._data_names + self._label_names
+            if len(batch_and_labels) != len(names):
+                raise MXNetError("step expects %s" % (names,))
+            inputs = {}
+            ndims = {}
+            for n, x in zip(names, batch_and_labels):
+                arr = x._data if isinstance(x, NDArray) else jnp.asarray(x)
+                ndims[n] = arr.ndim
+                inputs[n] = jax.device_put(
+                    arr, self._input_sharding(n, arr.ndim))
+            if self._step_fn is None:
+                self._input_ndims = ndims
+                if self._grad_compression is not None:
+                    self._build_step_compressed()
+                else:
+                    self._build_step()
+            key = _random.next_key() if self._needs_rng else None
         # trace (first call) under this trainer's mesh so mesh-aware ops
         # (contrib.RingAttention / contrib.MoEFFN) pick their sp/ep paths
         from .mesh import use_mesh
-        with use_mesh(self._mesh):
+        with use_mesh(self._mesh), trace_span("step.launch"):
             if self._grad_compression is not None:
                 (self._params, self._aux, self._opt_state,
                  self._gc_residuals, loss, ok) = self._step_fn(
@@ -755,11 +781,14 @@ class ShardedTrainer:
                 (self._params, self._aux, self._opt_state,
                  loss, ok) = self._step_fn(
                     self._params, self._aux, self._opt_state, inputs, key)
-        _fstep.STEP_DISPATCHES.inc()   # the whole step was ONE program
-        if _num.enabled():
-            _num.record_flag(ok, where="step")
-        self._step_count += 1
-        return NDArray(loss)
+        with trace_span("step.finish"):
+            _fstep.STEP_DISPATCHES.inc()   # the whole step was ONE program
+            if _num.enabled():
+                _num.record_flag(ok, where="step")
+            self._step_count += 1
+            loss = NDArray(loss)
+        self._root.end(self._step_count)
+        return loss
 
     # -- param sync back to the frontend --------------------------------
     @property

@@ -73,6 +73,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..base import getenv
 from ..compile import aot as _aot
+from ..compile.programs import scope as _scope
 from ..observability import goodput as _goodput
 from ..observability import memory as _memory
 from ..observability import registry as _obs
@@ -457,12 +458,13 @@ class FusedTrainStep:
                 # XLA schedules it behind the update math
                 g_flats = tuple(jnp.sum(g, axis=0) for g in g_flats)
             if guard:
-                ok = jnp.all(jnp.stack(
-                    [jnp.isfinite(g).all() for g in g_flats]))
+                with _scope("mx.guard"):
+                    ok = jnp.all(jnp.stack(
+                        [jnp.isfinite(g).all() for g in g_flats]))
             else:
                 ok = jnp.bool_(True)
 
-            def apply():
+            def update():
                 outs_w, outs_s = [], []
                 for (fn, wd, hyper), w, g, st, lr, t in zip(
                         statics, w_flats, g_flats, state_flats,
@@ -483,6 +485,10 @@ class FusedTrainStep:
                     outs_s.append(tuple(ns))
                 return tuple(outs_w), tuple(outs_s)
 
+            def apply():
+                with _scope("mx.optimizer"):
+                    return update()
+
             if guard:
                 # ONE lax.cond over the WHOLE step body (the PR-9
                 # contract): the false branch passes every weight and
@@ -495,6 +501,9 @@ class FusedTrainStep:
                 new_w, new_s = apply()
             return new_w, new_s, ok
 
+        # the program's name in a device trace and in the program table
+        program.__name__ = "fused_step_" + "_".join(
+            sorted({l.spec.name for l in lanes}))
         kw = {"donate_argnums": (0, 2) if donate else ()}
         if nproc > 1:
             state_out = tuple(

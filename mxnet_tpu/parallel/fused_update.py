@@ -50,6 +50,7 @@ import jax.numpy as jnp
 
 from ..base import getenv
 from ..compile import aot as _aot
+from ..compile.programs import scope as _scope
 from ..ndarray import NDArray
 from ..observability import registry as _obs
 from .. import optimizer as opt
@@ -207,8 +208,15 @@ def _jit_for(spec, donate, guarded=None):
         from ..compile.cache import enable_cache
         enable_cache()    # kernel build is a compile entry point
         body = _guard_wrap(spec.fn) if guarded else spec.fn
+        # named for the device trace and the program table; the scope
+        # owns the kernel's device time (compile/programs.py)
+        def kernel(w, g, states, lr, t, wd, hyper):
+            with _scope("mx.optimizer"):
+                return body(w, g, states, lr, t, wd, hyper)
+
+        kernel.__name__ = "fused_update_" + spec.name
         fn = _JITS[key] = jax.jit(
-            body, static_argnums=(5, 6),
+            kernel, static_argnums=(5, 6),
             donate_argnums=(0, 2) if donate else ())
     return fn
 

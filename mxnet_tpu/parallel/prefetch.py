@@ -20,6 +20,7 @@ import threading
 import time
 
 from ..observability import registry as _obs
+from ..observability import trace as _trace
 
 __all__ = ["DevicePrefetcher"]
 
@@ -56,8 +57,14 @@ class DevicePrefetcher:
         from ..observability.telemetry import mark_producer_thread
         mark_producer_thread()
         try:
-            for item in self._source:
-                staged = self._stage(item)
+            for n, item in enumerate(self._source):
+                # the staging thread runs ahead of the step that will
+                # eat the batch: a context of its own, one a batch
+                with _trace.trace_span(
+                        "input.stage",
+                        ctx=_trace.step_trace_context("input", n),
+                        batch=n):
+                    staged = self._stage(item)
                 while not self._stop.is_set():
                     try:
                         self._q.put(staged, timeout=0.1)
@@ -77,7 +84,8 @@ class DevicePrefetcher:
         if self._stop.is_set():
             raise StopIteration
         t0 = time.perf_counter()
-        item = self._q.get()
+        with _trace.trace_span("input.wait"):
+            item = self._q.get()
         _BATCH_WAIT.observe(time.perf_counter() - t0)
         if item is _END:
             self._stop.set()

@@ -28,3 +28,15 @@ jax.config.update("jax_platforms", "cpu")
 from mxnet_tpu.compile.cache import enable_cache
 
 enable_cache()
+
+import pytest
+
+
+@pytest.fixture(autouse=True)
+def _detach_step_trace():
+    """A trainer's step root stays current on its thread between steps
+    (observability/trace.StepRoot); a test's last step must not parent
+    the next test's spans."""
+    yield
+    from mxnet_tpu.observability import trace
+    trace.detach()

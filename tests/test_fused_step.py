@@ -149,12 +149,13 @@ def test_fused_step_telemetry_record_and_phase(step_env, tmp_path,
     recs = [json.loads(line) for line in tel.read_text().splitlines()]
     steps = [r for r in recs if r.get("source") == "gluon.trainer"]
     assert steps
-    # one "step" phase, no host allreduce/optimizer phases, and the
-    # dispatch budget field reads 1 (acceptance: the host-side Python
-    # between phases is gone from the trace)
+    # one "step.launch" phase, no host allreduce/optimizer phases, and
+    # the dispatch budget field reads 1 (acceptance: the host-side
+    # Python between phases is gone from the trace); the phase's time
+    # is a part of the iteration's, not written over it
     for r in steps[1:]:
         assert r.get("step_dispatches") == 1
-        assert "step_time" in r
+        assert 0 < r["step.launch_time"] < r["step_time"]
         assert "allreduce_time" not in r and "optimizer_time" not in r
 
 

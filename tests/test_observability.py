@@ -263,7 +263,7 @@ def test_gluon_5step_jsonl_and_report(tmp_path, monkeypatch):
     out = tmp_path / "telemetry.jsonl"
     # this test documents the STAGED trainer record shape (allreduce/
     # optimizer phases, kvstore bytes); the fused one-program step's
-    # record (single "step" phase, no kvstore hop) is covered in
+    # record (single "step.launch" phase, no kvstore hop) is covered in
     # tests/test_fused_step.py
     monkeypatch.setenv("MXTPU_FUSED_STEP", "0")
     # consume the once-per-process cold-start marker BEFORE the stream
@@ -271,6 +271,10 @@ def test_gluon_5step_jsonl_and_report(tmp_path, monkeypatch):
     # its source="compile" record into this strict 5-line assertion
     from mxnet_tpu.compile import coldstart
     coldstart.mark_ready("test-setup")
+    # an earlier test's trainer of the same size would leave the ledger
+    # cell as this one sets it, and an unchanged cell writes no record
+    from mxnet_tpu.observability import memory
+    memory.release("trainer")
     monkeypatch.setenv("MXTPU_TELEMETRY", str(out))
     _run_gluon_steps(5)
     close_stream()

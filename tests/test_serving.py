@@ -418,11 +418,14 @@ def test_server_telemetry_records(tmp_path):
         telemetry.close_stream()
     allrecs = [json.loads(l) for l in open(path) if l.strip()]
     # the stream is shared: the process's one-off cold-start record
-    # (source="compile", docs/compilation.md) may ride along with the
+    # (source="compile", docs/compilation.md) and the HBM ledger's
+    # timeline record of the frozen engine (source="memory",
+    # docs/observability.md "Memory ledger") may ride along with the
     # per-batch serving records under test
     recs = [r for r in allrecs if r["source"] == "serving"]
     assert recs
-    assert all(r["source"] in ("serving", "compile") for r in allrecs)
+    assert all(r["source"] in ("serving", "compile", "memory")
+               for r in allrecs)
     assert all("step_time" in r and "fill_ratio" in r for r in recs)
     assert sum(r["requests"] for r in recs) == 5
 
