@@ -272,8 +272,8 @@ class Trainer:
             tel._phases.pop("step.launch", None)
             return False
         if self._numerics is not None:
-            # kept for the boundary's SDC replay digest (grads are not
-            # donated — the packed exchange consumed copies)
+            # kept for the boundary's SDC replay digest (the fused
+            # program never donates its gradient arguments)
             self._last_grads = grads
         for g in grads:
             g._fresh_grad = False

@@ -16,6 +16,28 @@ backward already execute as one compiled program on every path
 single device program on the `ShardedTrainer` path and a single
 exchange+update program behind the imperative facades.
 
+What "one program a step" covers depends on where the program's
+boundary lies, and the step chooses that from what it can observe:
+
+- **leaves** (one process, replicated state: `gluon.Trainer` on a chip,
+  `Module.update`): the program takes the per-parameter weight (or
+  master), gradient and state arrays and returns per-parameter arrays.
+  The concatenation into lane flats, the multi-precision casts and the
+  slicing back are traced inside it with the lane's own `Bucket`
+  layout, so NO device program runs around it: `run()` gathers
+  references, calls once, and assigns the results to the NDArrays.
+- **flats** (the multi-process exchange over the `proc` mesh, ZeRO-1's
+  carried sharded state, or an armed `grad.post` / `weight.post` chaos
+  site, which must fire on the flat itself): the flats are packed and
+  unpacked eagerly around the program with `Bucket.pack` / `unpack`,
+  O(parameters) small programs a step. Their inputs are global arrays
+  built from one flat a process; per-leaf `device_put`s would cost
+  them more than the pack does.
+
+Both share `_plan_lanes`, the layout, the kernels and the guard: one
+algorithm with the program's boundary moved.
+``train.step.fused_path{path}`` counts which one a step took.
+
 On top rides **ZeRO-1 weight-update sharding** ("Automatic
 Cross-Replica Sharding of Weight Update in Data-Parallel Training",
 arXiv:2004.13336): with ``MXTPU_ZERO1=1`` the optimizer state and the
@@ -35,16 +57,18 @@ bit-identically, and the single verdict lands in the PR-9 flag
 collector as ``where="step"`` (a protected provenance: it counts as a
 skipped step, feeds the DivergenceWatchdog, and keeps SDC replay
 sound). The ``grad.post`` / ``weight.post`` chaos corruption sites of
-the staged path fire at the same places around the fused program.
+the staged path fire at the same places around the fused program
+(an armed site puts the step on the flats' boundary for that).
 The guard is never applied inside a ``lax.scan`` — `step_many`'s
 post-scan window verdict stays as-is (see data_parallel.py).
 
-Bit parity: flats are packed with the SAME `GradBucketer` layout plans
-the staged `FusedUpdater` uses and updated by the SAME kernel
-functions, and the cross-replica sum is the same stacked `jnp.sum` the
-bucketed exchange issues — elementwise IEEE ops commute with
-concatenation, so the fused step is bit-identical to the staged path
-(asserted in tests/test_fused_step.py). ``MXTPU_FUSED_STEP=0``
+Bit parity: flats are packed (eagerly or inside the trace) with the
+SAME `GradBucketer` layout plans the staged `FusedUpdater` uses and
+updated by the SAME kernel functions, and the cross-replica sum is the
+same stacked `jnp.sum` the bucketed exchange issues — elementwise IEEE
+ops commute with concatenation, so the fused step is bit-identical to
+the staged path on either boundary (asserted in
+tests/test_fused_step.py). ``MXTPU_FUSED_STEP=0``
 restores the staged bucketed path, which remains the parity oracle.
 
 Artifact subsystem (PR 11): program builds run under the persistent
@@ -69,7 +93,8 @@ import time
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import (NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
 from ..base import getenv
 from ..compile import aot as _aot
@@ -79,11 +104,11 @@ from ..observability import memory as _memory
 from ..observability import registry as _obs
 from .. import optimizer as opt
 from ..resilience import numerics as _num
-from ..resilience.chaos import corrupt_point
+from ..resilience.chaos import armed as _chaos_armed, corrupt_point
 
 __all__ = ["FusedTrainStep", "enabled", "zero1_enabled", "try_step",
            "eligible",
-           "STEP_DISPATCHES", "ZERO1_SHARD_PARAMS",
+           "STEP_DISPATCHES", "FUSED_PATH", "ZERO1_SHARD_PARAMS",
            "ZERO1_ALLGATHER_SECONDS"]
 
 # every device program dispatched on behalf of a training step's
@@ -95,6 +120,12 @@ STEP_DISPATCHES = _obs.counter(
     "train.step.dispatches",
     "Device programs dispatched per training step for gradient "
     "exchange + optimizer update (fused path: exactly 1)")
+FUSED_PATH = _obs.counter(
+    "train.step.fused_path",
+    "Fused steps by the boundary of their one program (label path: "
+    "leaves = per-parameter arrays in and out, pack and unpack traced "
+    "inside; flats = packed eagerly around it: multi-process, ZeRO-1, "
+    "an armed chaos corruption site)")
 ZERO1_SHARD_PARAMS = _obs.gauge(
     "zero1.shard_params",
     "Parameters whose optimizer state/update is ZeRO-1-sharded over "
@@ -190,7 +221,7 @@ class FusedTrainStep:
     def run(self, indices, grads, weights, kvstore=None):
         """Run one fused exchange+update step over the whole trainable
         set. Returns True when the fused program ran (gradient arrays
-        are left UNREDUCED — the program consumed packed copies);
+        are left UNREDUCED and alive — the program never donates them);
         False means the caller must take the staged path (no state was
         mutated, no update counts were bumped)."""
         from .fused_update import _SUPPORTED
@@ -218,17 +249,28 @@ class FusedTrainStep:
         zero1 = zero1_enabled() and mesh is not None
         guard = _num.enabled()
         donate = opt.donate_update_enabled()
-        sig = (tuple(l.key for l in lanes), nproc, zero1, guard, donate)
+        # the program's boundary, from what the step can observe: one
+        # process with replicated state hands the per-parameter leaves
+        # in and gets leaves back (ZeRO-1 needs a mesh, so nproc == 1
+        # rules it out); the process mesh's global arrays are built
+        # from one flat a process, and an armed corruption site must
+        # fire on the flat itself, so both keep the eager pack
+        leaves = nproc == 1 and not (_chaos_armed("grad.post")
+                                     or _chaos_armed("weight.post"))
+        sig = (tuple(l.key for l in lanes), nproc, zero1, guard, donate,
+               leaves)
         if self._state_flats and sig not in self._state_flats:
             # layout/cohort/knob change: re-materialize the carried
             # state before the old flats' lane map goes stale
             self.flush_state()
-        packed = self._pack(lanes, sig, nproc, mesh, zero1)
-        fn = self._program_for(sig, lanes, packed, nproc, mesh, zero1,
-                               guard, donate)
+        args = self._leaf_args(lanes) if leaves else \
+            self._pack(lanes, sig, nproc, mesh, zero1)
+        fn = self._program_for(sig, lanes, args, nproc, mesh, zero1,
+                               guard, donate, leaves)
         with _memory.oom_guard("train.step", "trainer"):
-            new_w, new_states, ok = fn(*packed)
+            out = fn(*args)
         STEP_DISPATCHES.inc()
+        FUSED_PATH.inc(path="leaves" if leaves else "flats")
         self._charge_goodput(sig, lanes, nproc)
         n_sharded = sum(len(l.group) for l in lanes) if zero1 else 0
         if n_sharded != self._gauge_val:
@@ -236,8 +278,11 @@ class FusedTrainStep:
             ZERO1_SHARD_PARAMS.set(n_sharded)
         if guard:
             keys = [e.index for l in lanes for e in l.group]
-            _num.record_flag(ok, keys=keys, where="step")
-        self._unpack(lanes, new_w, new_states, sig, nproc, zero1)
+            _num.record_flag(out[2], keys=keys, where="step")
+        if leaves:
+            self._assign_leaves(lanes, out[0], out[1], out[3])
+        else:
+            self._unpack(lanes, out[0], out[1], sig, nproc, zero1)
         return True
 
     def _charge_goodput(self, sig, lanes, nproc):
@@ -326,7 +371,31 @@ class FusedTrainStep:
                 for bucket, group, t, lr, _wd
                 in self._updater._plan_cohorts(entries)]
 
-    # -- packing ---------------------------------------------------------
+    # -- the program's arguments: leaves, or eagerly packed flats ----------
+    @staticmethod
+    def _leaf_args(lanes):
+        """The per-parameter arrays as they are, a tuple a lane: no
+        device program runs here. Masters and user-visible weights ride
+        separate arguments because only the first may be donated: a
+        weight's buffer can be shared with another live NDArray
+        (`detach()` hands out the same array), a master or a state
+        leaf lives only inside the updater."""
+        masters, weights, grads, states = [], [], [], []
+        for lane in lanes:
+            group = lane.group
+            mp = group[0].master is not None    # the lane key holds mp
+            w = tuple(e.pack_w for e in group)
+            masters.append(w if mp else ())
+            weights.append(() if mp else w)
+            grads.append(tuple(e.grad for e in group))
+            states.append(tuple(
+                tuple(e.state_leaves[s]._data for e in group)
+                for s in range(lane.n_states)))
+        # host scalars, traced weakly: see _pack
+        return (tuple(masters), tuple(weights), tuple(grads),
+                tuple(states), tuple(l.lr for l in lanes),
+                tuple(l.t for l in lanes))
+
     @staticmethod
     def _zero1_pad(flat, nproc):
         pad = (-int(flat.shape[0])) % nproc
@@ -438,8 +507,8 @@ class FusedTrainStep:
         return jnp.asarray(out.addressable_data(0))
 
     # -- the program -----------------------------------------------------
-    def _program_for(self, sig, lanes, packed, nproc, mesh, zero1,
-                     guard, donate):
+    def _program_for(self, sig, lanes, args, nproc, mesh, zero1,
+                     guard, donate, leaves):
         cached = self._programs.get(sig)
         if cached is not None:
             return cached
@@ -451,7 +520,7 @@ class FusedTrainStep:
         rep = NamedSharding(mesh, PartitionSpec()) \
             if nproc > 1 else None
 
-        def program(w_flats, g_flats, state_flats, lrs, ts):
+        def core(w_flats, g_flats, state_flats, lrs, ts):
             if nproc > 1:
                 # the gradient exchange: the same stacked sum the
                 # bucketed kvstore allreduce jits, fused in-program so
@@ -501,18 +570,69 @@ class FusedTrainStep:
                 new_w, new_s = apply()
             return new_w, new_s, ok
 
+        buckets = tuple(l.bucket for l in lanes)
+        # multi-precision lanes also hand back the weights cast down
+        low = tuple(l.group[0].weight._data.dtype
+                    if l.group[0].master is not None else None
+                    for l in lanes)
+
+        def from_leaves(masters, weights, grads, states, lrs, ts):
+            """The same step with the program's boundary at the
+            per-parameter leaves: the lane's own `Bucket` layout packs
+            them into the flats `core` takes and slices its results
+            back, all inside the trace."""
+            w_flats, g_flats, state_flats = [], [], []
+            with _scope("mx.optimizer"):
+                for b, m, w, g, st in zip(buckets, masters, weights,
+                                          grads, states):
+                    w = b.pack(m or w)
+                    g = b.pack(g)
+                    if g.dtype != w.dtype:
+                        # multi-precision: ONE fp32 cast of the whole
+                        # flat, as _pack spells it
+                        g = g.astype(w.dtype)
+                    w_flats.append(w)
+                    g_flats.append(g)
+                    state_flats.append(
+                        tuple(b.pack(s) for s in st))
+            new_w, new_s, ok = core(w_flats, g_flats, state_flats,
+                                    lrs, ts)
+            with _scope("mx.optimizer"):
+                new_w = tuple(tuple(b.unpack(f))
+                              for b, f in zip(buckets, new_w))
+                new_s = tuple(tuple(tuple(b.unpack(f)) for f in st)
+                              for b, st in zip(buckets, new_s))
+                cast = tuple(
+                    () if dt is None else tuple(x.astype(dt) for x in w)
+                    for dt, w in zip(low, new_w))
+            return new_w, new_s, ok, cast
+
+        program = from_leaves if leaves else core
         # the program's name in a device trace and in the program table
         program.__name__ = "fused_step_" + "_".join(
             sorted({l.spec.name for l in lanes}))
-        kw = {"donate_argnums": (0, 2) if donate else ()}
+        # masters or flats, and states; never gradients or (on the
+        # leaves' boundary) user-visible weights: _leaf_args
+        kw = {"donate_argnums":
+              ((0, 3) if leaves else (0, 2)) if donate else ()}
+        if leaves:
+            devices = lanes[0].group[0].pack_w.devices()
+            if len(devices) == 1:
+                # say where the leaves live: left to be inferred, a
+                # leaf that is committed to its device (step 1's
+                # results are) and one that is not (a fresh
+                # initializer's) lower to different modules, and step
+                # 2 would build the program a second time
+                kw["in_shardings"] = SingleDeviceSharding(
+                    next(iter(devices)))
         if nproc > 1:
             state_out = tuple(
                 tuple((dp if zero1 else rep) for _ in lane_states)
-                for lane_states in packed[2])
+                for lane_states in args[2])
             kw["out_shardings"] = (tuple(rep for _ in lanes),
                                    state_out, rep)
         jitted = jax.jit(program, **kw)
-        fn = self._aot_or_jit(sig, jitted, packed, nproc, zero1,
+        fn = self._aot_or_jit(sig, jitted, args, nproc, zero1,
                               guard, donate, lanes)
         if len(self._programs) > 64:
             # membership/cohort churn: same bound as the layout-plan
@@ -522,7 +642,7 @@ class FusedTrainStep:
         self._programs[sig] = fn
         return fn
 
-    def _aot_or_jit(self, sig, jitted, packed, nproc, zero1, guard,
+    def _aot_or_jit(self, sig, jitted, args, nproc, zero1, guard,
                     donate, lanes):
         """Try the PR-11 artifact store for this program signature;
         fall back to (and optionally export from) the jit.
@@ -544,13 +664,15 @@ class FusedTrainStep:
             "plan": self._updater._layout.plan_signature(
                 [l.bucket for l in lanes]),
             "zero1": zero1, "guard": guard, "donate": donate,
-            "args": _aot.aval_signature(packed),
+            # the avals of the program's own arguments (leaves or
+            # flats): an artifact built for the other boundary misses
+            "args": _aot.aval_signature(args),
         }
         name = "fused_step/%s" % _aot.fingerprint(extra)[:16]
         loaded = store.load_jit(name, extra)
         if loaded is None and _aot.export_enabled():
             try:
-                avals = _aot.abstract(packed)
+                avals = _aot.abstract(args)
                 compiled = _aot.compile_fresh(jitted, avals)
                 _aot.record_analyses(name, compiled)
                 store.put(name, _aot.fingerprint(extra), compiled)
@@ -578,7 +700,21 @@ class FusedTrainStep:
                 return jitted(*args)
         return call
 
-    # -- unpacking -------------------------------------------------------
+    # -- the program's results: leaves, or flats to unpack eagerly --------
+    @staticmethod
+    def _assign_leaves(lanes, new_w, new_states, cast):
+        """Hand the program's leaves to the NDArrays that own them:
+        attribute writes only."""
+        for lane, w, states, low in zip(lanes, new_w, new_states, cast):
+            for j, e in enumerate(lane.group):
+                if e.master is not None:
+                    e.master._data = w[j]
+                    e.weight._data = low[j]
+                else:
+                    e.weight._data = w[j]
+                for s in range(lane.n_states):
+                    e.state_leaves[s]._data = states[s][j]
+
     def _unpack(self, lanes, new_w, new_states, sig, nproc, zero1):
         from .bucketing import UNPACK_SECONDS
         t0 = time.perf_counter()

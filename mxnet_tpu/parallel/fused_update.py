@@ -301,6 +301,19 @@ def update_cost(optimizer, n_elems, itemsize=4):
             "flops": flops * int(n_elems)}
 
 
+_DTYPE_NAMES = {}
+
+
+def _dtype_name(dtype):
+    """`str(dtype)`, remembered: numpy assembles the name anew at every
+    call (microseconds), and the planning below asks twice a parameter
+    a step."""
+    name = _DTYPE_NAMES.get(dtype)
+    if name is None:
+        name = _DTYPE_NAMES[dtype] = str(dtype)
+    return name
+
+
 class _Entry:
     """One fused-eligible parameter's resolved update inputs."""
 
@@ -405,7 +418,7 @@ class FusedUpdater(opt.Updater):
             # lane: the stable group identity — raw weight dtype rides
             # along so mp groups never mix fp16 and bf16 grads in one
             # packed buffer (the flat itself is master-fp32 for mp)
-            lane = (spec.name, mp, str(w._data.dtype),
+            lane = (spec.name, mp, _dtype_name(w._data.dtype),
                     o._resolved_mult(i, "lr_mult"),
                     o._resolved_mult(i, "wd_mult"))
             entries.append(_Entry(i, w, pack_w, g_arr, leaves, master,
@@ -474,7 +487,8 @@ class FusedUpdater(opt.Updater):
             self._layout.clear()
         for (t, lr, wd), cohort in sorted(by_cohort.items()):
             items = tuple(
-                (e.index, tuple(e.pack_w.shape), str(e.pack_w.dtype),
+                (e.index, tuple(e.pack_w.shape),
+                 _dtype_name(e.pack_w.dtype),
                  -pos, e.lane)
                 for pos, e in cohort)
             by_index = {e.index: e for _, e in cohort}

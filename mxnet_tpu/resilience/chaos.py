@@ -86,7 +86,7 @@ from .retry import TransientError
 from . import metrics
 
 __all__ = ["InjectedFault", "InjectedFailure", "parse_spec", "configure",
-           "reset", "chaos_point", "corrupt_point", "trip_count"]
+           "reset", "armed", "chaos_point", "corrupt_point", "trip_count"]
 
 
 class InjectedFault(TransientError):
@@ -252,6 +252,14 @@ def _lookup(site):
         if site.startswith(prefix):
             return psite
     return None
+
+
+def armed(site):
+    """Whether a chaos spec arms `site` (one dict lookup). A caller that
+    has moved a corruption site's array inside a compiled program asks
+    this to decide whether the array must be brought out for the site
+    to fire on."""
+    return _lookup(site) is not None
 
 
 def chaos_point(site):

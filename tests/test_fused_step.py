@@ -337,6 +337,178 @@ def test_staged_oracle_unused_paths_intact(step_env):
     assert tr._updaters[0]._fused_step_owner is None
 
 
+# -- the program's boundary: leaves in, leaves out --------------------------
+
+_LEAF_CASES = [
+    ("sgd", dict(learning_rate=0.1, momentum=0.9, wd=0.01), "float32"),
+    ("adam", dict(learning_rate=0.01, multi_precision=True), "float16"),
+]
+
+
+def _hybrid_loop(optname, optkw, dtype):
+    """A small hybridized net under gluon.Trainer; returns (net,
+    trainer, one_iteration) with the programs of step 1 built."""
+    mx.random.seed(0)
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(8, activation="relu"), gluon.nn.Dense(3))
+    net.initialize()
+    if dtype != "float32":
+        net.cast(dtype)
+    net.hybridize()
+    x = mx.nd.array(np.random.RandomState(1).randn(4, 5).astype(dtype))
+    y = mx.nd.array(np.random.RandomState(2).randn(4, 3).astype(dtype))
+    tr = gluon.Trainer(net.collect_params(), optname, dict(optkw))
+    loss_fn = gluon.loss.L2Loss()
+
+    def fwd_bwd():
+        with autograd.record():
+            loss = loss_fn(net(x), y)
+        loss.backward()
+
+    fwd_bwd()
+    tr.step(4)
+    return net, tr, fwd_bwd
+
+
+def _executions(tmp_path, fn):
+    """Compiled-program executions while `fn` runs, counted from a
+    `jax.profiler` trace of the host: the CPU client records one
+    `PjRtCpuExecutable::Execute` a run of a compiled program, through
+    the C++ fast path too (neither the program table nor
+    `jax.monitoring` sees a run, only builds)."""
+    import glob
+    import jax
+
+    def count(fn, d):
+        jax.profiler.start_trace(str(d))
+        try:
+            fn()
+        finally:
+            jax.profiler.stop_trace()
+        pb = glob.glob(str(d / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(pb[0])
+        return sum(e.name == "PjRtCpuExecutable::Execute"
+                   for plane in data.planes for line in plane.lines
+                   for e in line.events)
+
+    double = jax.jit(lambda a: a * 2)
+    one = double(jax.numpy.ones(3))
+
+    def three():
+        for _ in range(3):
+            double(one).block_until_ready()
+
+    if count(three, tmp_path / "calibrate") != 3:
+        pytest.skip("this jaxlib's host trace does not name executions")
+    return count(fn, tmp_path / "measured")
+
+
+@pytest.mark.parametrize("name,kw,dtype", _LEAF_CASES)
+def test_fused_step_is_one_program_no_eager_pack(name, kw, dtype, step_env,
+                                                 monkeypatch, tmp_path):
+    """Steps 2-4: `Trainer.step` runs ONE compiled program and never
+    calls `Bucket.pack` / `Bucket.unpack` (steady state retraces
+    nothing, so any call would be an eager one)."""
+    from mxnet_tpu.parallel.bucketing import Bucket
+    step_env(True)
+    _net, tr, fwd_bwd = _hybrid_loop(name, kw, dtype)
+    calls = []
+    for meth in ("pack", "unpack"):
+        orig = getattr(Bucket, meth)
+
+        def spy(self, arg, _orig=orig, _meth=meth):
+            calls.append(_meth)
+            return _orig(self, arg)
+        monkeypatch.setattr(Bucket, meth, spy)
+    for i in range(3):
+        fwd_bwd()
+        assert _executions(tmp_path / str(i), lambda: tr.step(4)) == 1
+    assert calls == []
+    assert tr._updaters[0]._fused_step_owner.program_count() == 1
+
+
+@pytest.mark.parametrize("donate", [True, False])
+@pytest.mark.parametrize("name,kw,dtype", _LEAF_CASES)
+def test_fused_step_leaves_alive_and_path_counter(name, kw, dtype, donate,
+                                                  step_env, monkeypatch,
+                                                  tmp_path):
+    """After fused steps every weight, master and state NDArray holds a
+    live array of its own shape and dtype, whatever the donation, and
+    an NDArray that shared a weight's buffer (`detach()`) still reads:
+    weights are the one class of leaf the program never donates."""
+    step_env(True)
+    monkeypatch.setenv("MXTPU_DONATE_UPDATE", "1" if donate else "0")
+    path = obs.REGISTRY.get("train.step.fused_path")
+    net, tr, fwd_bwd = _hybrid_loop(name, kw, dtype)
+    params = list(net.collect_params().values())
+    shapes = [(p.data().shape, p.data().dtype) for p in params]
+    held = [p.data().detach() for p in params]
+    before = [h.asnumpy() for h in held]
+    leaves0, flats0 = path.get(path="leaves"), path.get(path="flats")
+    for _ in range(3):
+        fwd_bwd()
+        tr.step(4)
+    assert path.get(path="leaves") - leaves0 == 3
+    assert path.get(path="flats") == flats0
+
+    def alive(nd, shape, dtype):
+        assert not nd._data.is_deleted()
+        assert (nd.shape, nd.dtype) == (shape, dtype)
+        assert np.isfinite(nd.asnumpy().astype("float64")).all()
+
+    for p, (shape, dt), h, b in zip(params, shapes, held, before):
+        alive(p.data(), shape, dt)
+        alive(p.grad(), shape, dt)
+        assert h.asnumpy().tobytes() == b.tobytes()      # not donated
+        assert p.data().asnumpy().tobytes() != b.tobytes()
+    upd = tr._updaters[0]
+    mp = kw.get("multi_precision", False)
+    for i, p in enumerate(params):
+        stack = [upd.states[i]]
+        n = 0
+        while stack:
+            st = stack.pop()
+            if isinstance(st, (list, tuple)):
+                stack.extend(st)
+            elif st is not None:
+                alive(st, p.data().shape,
+                      np.float32 if mp else p.data().dtype)
+                n += 1
+        assert n == (3 if mp else 1)     # (master,) + m, v | momentum
+    f = tmp_path / "states"
+    tr.save_states(str(f))
+    tr.load_states(str(f))
+    fwd_bwd()
+    tr.step(4)
+    assert path.get(path="leaves") - leaves0 == 4
+
+
+def test_armed_corruption_site_takes_the_flat_boundary(step_env):
+    """An armed `grad.post` / `weight.post` site must fire on the flat
+    itself: while one is armed the step packs eagerly around its
+    program, and goes back to the leaves when it is disarmed."""
+    step_env(True)
+    path = obs.REGISTRY.get("train.step.fused_path")
+    net, tr, fwd_bwd = _hybrid_loop(*_LEAF_CASES[0])
+    pre = [p.data().asnumpy() for p in net.collect_params().values()]
+    leaves0, flats0 = path.get(path="leaves"), path.get(path="flats")
+    chaos.configure("weight.post:kind=bitflip,n=1", seed=3)
+    try:
+        fwd_bwd()
+        tr.step(4)
+        assert chaos.trip_count("weight.post") == 1
+    finally:
+        chaos.reset()
+    assert (path.get(path="leaves") - leaves0,
+            path.get(path="flats") - flats0) == (0, 1)
+    fwd_bwd()
+    tr.step(4)
+    assert (path.get(path="leaves") - leaves0,
+            path.get(path="flats") - flats0) == (1, 1)
+    for a, p in zip(pre, net.collect_params().values()):
+        assert a.tobytes() != p.data().asnumpy().tobytes()
+
+
 # -- ZeRO-1 ---------------------------------------------------------------
 
 def test_zero1_env_defaults_sharded_trainer(step_env):
