@@ -545,6 +545,19 @@ class HybridBlock(Block):
         """Override to construct symbolic graph for this Block."""
         raise NotImplementedError
 
+    def remat_scope(self, name):
+        """`with self.remat_scope("l3"):` inside `hybrid_forward` marks the
+        graph nodes made in the block as one group of rematerialisation:
+        a traced training graph runs the group as one `jax.checkpoint`
+        (graph.build_graph_fn), which keeps what enters and what leaves the
+        group and computes the inside again in the backward pass. The mark
+        is a node attribute; the eager path and `predict` ignore it. The
+        group has to be closed: no node outside it between two of its own
+        (docs/performance.md "Rematerialisation by layer")."""
+        from ..attribute import AttrScope
+        from ..graph import REMAT_ATTR
+        return AttrScope(**{REMAT_ATTR: self.prefix + str(name)})
+
 
 def _as_list(obj):
     if isinstance(obj, (list, tuple)):

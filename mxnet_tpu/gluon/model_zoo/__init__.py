@@ -3,6 +3,8 @@
 from . import model_store
 from . import vision
 from . import gpt
+from . import qwen3_next
 
 from .vision import get_model
 from .gpt import GPTDecoder, get_gpt
+from .qwen3_next import Qwen3NextDecoder, get_qwen3_next

@@ -15,6 +15,8 @@ The same builder serves the Executor (Module/symbolic path), CachedOp
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -102,6 +104,68 @@ def aux_var_ids(order):
     return aux
 
 
+REMAT_ATTR = "__remat__"        # a node's group of rematerialisation
+# what an op names so (`jax.ad_checkpoint.checkpoint_name`) a group keeps
+# from its first pass in place of computing it again: an output that is
+# dear to recompute and cheap to hold
+REMAT_KEEP = "mx.keep"
+
+
+def counter_vars(output_entries):
+    """{aux variable name: (metric name, ...)} for the aux inputs that an
+    op of this graph declares as device counters (`Op.counters`)."""
+    out = {}
+    for node in topo_order(output_entries):
+        if node.is_variable or not node.op.counters:
+            continue
+        for ii, names in node.op.counters.items():
+            in_node, _ = node.inputs[ii]
+            if in_node.is_variable:
+                out[in_node.name] = tuple(names)
+    return out
+
+
+def _remat_units(order):
+    """Cut `order` into units of execution: a node alone, or all the nodes
+    that carry one `__remat__` mark (gluon.block.remat_scope). Returns
+    [(mark or None, [nodes])] in an order in which every unit comes after
+    the units it reads, or `None` where no node is marked. A marked group
+    that another unit both reads and feeds cannot run as one function:
+    that raises."""
+    mark = {id(n): n.attrs.get(REMAT_ATTR) for n in order
+            if not n.is_variable}
+    if not any(mark.values()):
+        return None
+    unit_of, members = {}, {}
+    for n in order:
+        u = mark.get(id(n)) or id(n)
+        unit_of[id(n)] = u
+        members.setdefault(u, []).append(n)
+    reads = {u: [r for r in dict.fromkeys(
+        unit_of[id(i)] for n in nodes for i, _ in n.inputs) if r != u]
+        for u, nodes in members.items()}
+    done, out, visiting = set(), [], set()
+    for start in members:                  # dict order: first appearance
+        stack = [(start, False)]
+        while stack:
+            u, expanded = stack.pop()
+            if u in done:
+                continue
+            if expanded:
+                visiting.discard(u)
+                done.add(u)
+                out.append((u if isinstance(u, str) else None, members[u]))
+                continue
+            if u in visiting:
+                raise MXNetError(
+                    "remat group %r is not closed: a node outside it lies "
+                    "on a path between two of its nodes" % (u,))
+            visiting.add(u)
+            stack.append((u, True))
+            stack.extend((r, False) for r in reads[u] if r not in done)
+    return out
+
+
 def collect_vars(output_entries):
     """Return (arg_nodes, aux_nodes) in first-seen topo order."""
     order = topo_order(output_entries)
@@ -151,16 +215,9 @@ def build_graph_fn(output_entries, mode="predict"):
 
     train = mode == "train"
 
-    def fn(args, aux, key=None):
-        values = {}
-        aux_updates = {}
-        for node in order:
-            if node.is_variable:
-                if id(node) in aux_ids:
-                    values[id(node)] = (aux[node.name],)
-                else:
-                    values[id(node)] = (args[node.name],)
-                continue
+    def run(nodes, values, aux_updates, key):
+        """Evaluate `nodes` in order into `values`; returns the key."""
+        for node in nodes:
             arrs = [values[id(n)][i] for n, i in node.inputs]
             op = node.op
             if op.needs_rng:
@@ -180,6 +237,66 @@ def build_graph_fn(output_entries, mode="predict"):
                     in_node, _ = node.inputs[ii]
                     if in_node.is_variable and id(in_node) in aux_ids:
                         aux_updates[in_node.name] = raw[oi]
+        return key
+
+    # rematerialisation by group (gluon.block.remat_scope): the marked
+    # nodes of one group run as one `jax.checkpoint`, which keeps the
+    # group's inputs and what leaves it, and computes the inside again in
+    # the backward pass. A graph with no mark runs node by node as ever.
+    units = _remat_units(order) if train else None
+    used_outside = set()
+    if units is not None:
+        group_of = {id(n): m for m, nodes in units for n in nodes if m}
+        for node in order:
+            for n, i in node.inputs:
+                if group_of.get(id(n)) and group_of.get(id(n)) != \
+                        group_of.get(id(node)):
+                    used_outside.add((id(n), i))
+        used_outside.update((id(n), i) for n, i in output_entries
+                            if group_of.get(id(n)))
+
+    def run_group(nodes, values, aux_updates, key):
+        inside = {id(n) for n in nodes}
+        # in the order the group first reads them: the same program in
+        # every process (an order by id() would miss the compile cache)
+        ext = list(dict.fromkeys((id(n), i) for node in nodes
+                                 for n, i in node.inputs
+                                 if id(n) not in inside))
+        leaving = [(id(n), i) for n in nodes for i in range(n.n_raw())
+                   if (id(n), i) in used_outside]
+
+        @functools.partial(
+            jax.checkpoint,
+            policy=jax.checkpoint_policies.save_only_these_names(REMAT_KEEP))
+        def group(ext_vals, key):
+            vals, ups = {}, {}
+            for (nid, i), v in zip(ext, ext_vals):
+                vals.setdefault(nid, {})[i] = v
+            key = run(nodes, vals, ups, key)
+            return [vals[nid][i] for nid, i in leaving], ups, key
+
+        outs, ups, key = group([values[nid][i] for nid, i in ext], key)
+        for (nid, i), v in zip(leaving, outs):
+            values.setdefault(nid, {})[i] = v
+        aux_updates.update(ups)
+        return key
+
+    def fn(args, aux, key=None):
+        values = {}
+        aux_updates = {}
+        for node in order:
+            if node.is_variable:
+                values[id(node)] = ((aux if id(node) in aux_ids else args)
+                                    [node.name],)
+        if units is None:
+            run([n for n in order if not n.is_variable], values,
+                aux_updates, key)
+        else:
+            for mark, nodes in units:
+                if mark:
+                    key = run_group(nodes, values, aux_updates, key)
+                elif not nodes[0].is_variable:
+                    key = run(nodes, values, aux_updates, key)
         outs = [values[id(n)][i] for n, i in output_entries]
         return outs, aux_updates
 

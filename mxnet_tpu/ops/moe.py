@@ -1,0 +1,251 @@
+"""A dropless expert layer that is told which experts it holds.
+
+`moe_held_ffn` routes every token over ALL the experts of the layer in
+float32 (softmax, top-k, the k weights renormalised to sum to one) and
+computes, for the experts `held_start .. held_start + E - 1` whose
+weights it is given, their part of the result:
+
+    y[n] = sum over the token's chosen experts e that are held of
+           w[n, e] * W_down[e] (silu(W_gate[e] x[n]) * (W_up[e] x[n]))
+
+What the absent experts would add is left out; nothing stands in for the
+chips that hold them. No token is dropped: the assignments that land on
+the held range are sorted by expert, each expert's run is cut into tiles
+of `tile` rows, and a loop over the tiles that hold a row (its trip count
+comes from the data, every shape is static) gathers a tile's rows,
+multiplies them by that one expert's matrices and adds the weighted
+result back to the tokens' rows. The backward pass is the same
+loop with the tile's products transposed. `parallel/moe.py` is the other
+kind of layer: a fixed capacity, tokens over it dropped, a dispatch
+tensor, experts exchanged over a mesh axis.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from .linear_attention import _precision
+from .registry import register
+
+__all__ = ["route_top_k", "moe_held_ffn", "shared_expert_ffn"]
+
+
+def _kept(x):
+    """Named for a rematerialised group to keep (graph.REMAT_KEEP): the
+    router's choices are small, and computing them again would sort
+    every token's scores a second time."""
+    return checkpoint_name(x, "mx.keep")
+
+
+def _mm(a, b, contract, prec):
+    """a . b over one axis of each, float32 sums."""
+    return lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                           precision=prec,
+                           preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(probs, k):
+    """(the k largest of each row, their columns). Both are named for a
+    rematerialised group to keep, so a second pass never sorts again,
+    and the cotangent goes back through a mask of the columns (compares
+    and sums; a gather of N k scalars took 3 ms on the chip)."""
+    return _top_k_fwd(probs, k)[0]
+
+
+def _top_k_fwd(probs, k):
+    top_w, top_i = lax.top_k(probs, k)
+    top_w, top_i = _kept(top_w), _kept(top_i.astype(jnp.int32))
+    # the second residual is there for its shape: the row's width
+    return (top_w, top_i), (top_i, jnp.zeros((0, probs.shape[-1]), jnp.int8))
+
+
+def _top_k_bwd(k, res, cts):
+    top_i, width = res
+    chosen = top_i[..., None] == jnp.arange(width.shape[-1])     # (N, k, E)
+    return (jnp.sum(jnp.where(chosen, cts[0][..., None], 0.0), axis=-2),)
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
+def route_top_k(x, router_w, top_k):
+    """(indices (N, k) int32, weights (N, k) float32 summing to one, and
+    each expert's count of assignments (E_all,) float32). The logits, the
+    softmax and the weights are float32 whatever x's dtype."""
+    # operands as they are stored (bfloat16 values multiply exactly into
+    # the float32 sum; float32 ones at full precision)
+    logits = _mm(x, router_w.astype(x.dtype), (1, 1), _precision(x.dtype))
+    top_w, top_i = _top_k(jax.nn.softmax(logits, axis=-1), int(top_k))
+    counts = jnp.sum(top_i.reshape(-1, 1) == jnp.arange(router_w.shape[0]),
+                     axis=0).astype(jnp.float32)
+    return top_i, top_w / jnp.sum(top_w, axis=-1, keepdims=True), counts
+
+
+def _tiles(top_i, counts_held, held_start, n_held, tile):
+    """Lay the held assignments out in tiles of one expert each. Returns
+    (order, first, tile_expert, tile_first, counts, n_tiles): the flat
+    assignments (token * k + choice) sorted by held expert, those that
+    are not held last; the place in `order` of each expert's first; each
+    tile's expert and each expert's first tile; the held counts; the
+    number of tiles that hold a row. Tile t of expert e reads the places
+    first[e] + (t - tile_first[e]) * tile + j, j < tile, as far as the
+    expert's count goes: a contiguous slice of `order`."""
+    N, k = top_i.shape
+    E = n_held
+    local = top_i.reshape(N * k) - held_start
+    key = jnp.where((local >= 0) & (local < E), local, E)
+    # padded by a tile: the last tile's slice may run past the end
+    order = jnp.pad(jnp.argsort(key, stable=True).astype(jnp.int32),
+                    (0, tile))
+    counts = counts_held.astype(jnp.int32)
+    first = jnp.cumsum(counts) - counts
+    tiles_e = -(-counts // tile)
+    tile_end = jnp.cumsum(tiles_e)
+    tile_first = tile_end - tiles_e
+    most = -(-(N * min(k, E)) // tile) + E            # tiles at the worst
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        tile_end, jnp.arange(most, dtype=jnp.int32), side="right"),
+        E - 1).astype(jnp.int32)
+    return order, first, tile_expert, tile_first, counts, tile_end[-1]
+
+
+def _tile_rows(t, lay, k, n_tokens, tile):
+    """(expert, assignments (tile,), token rows (tile,), live (tile,)) of
+    tile t; a slot past the expert's count reads token `n_tokens`, which
+    no array has: gathers fill it with 0 and scatters drop it."""
+    order, first, tile_expert, tile_first, counts = lay
+    e = tile_expert[t]
+    done = (t - tile_first[e]) * tile
+    a = lax.dynamic_slice(order, (first[e] + done,), (tile,))
+    live = jnp.arange(tile, dtype=jnp.int32) < counts[e] - done
+    return e, a, jnp.where(live, a // k, n_tokens), live
+
+
+def _tile_forward(xt, wg, wu, wd, prec):
+    """One tile through one expert: (gate, up, act, out), float32."""
+    gate = _mm(xt, wg, (1, 1), prec)
+    up = _mm(xt, wu, (1, 1), prec)
+    act = jax.nn.silu(gate) * up
+    return gate, up, act, _mm(act.astype(xt.dtype), wd, (1, 1), prec)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _held_products(x, wg, wu, wd, top_w, lay, n_tiles, tile):
+    """y (N, H): every held assignment's token through its expert, times
+    the assignment's weight, added up by token."""
+    return _held_fwd(x, wg, wu, wd, top_w, lay, n_tiles, tile)[0]
+
+
+def _held_fwd(x, wg, wu, wd, top_w, lay, n_tiles, tile):
+    N, H = x.shape
+    k = top_w.shape[1]
+    prec = _precision(x.dtype)
+    flat_w = top_w.reshape(N * k)
+
+    def body(t, y):
+        e, a, r, live = _tile_rows(t, lay, k, N, tile)
+        w = jnp.where(live, flat_w[a], 0.0)
+        xt = x.at[r].get(mode="fill", fill_value=0)
+        out = _tile_forward(xt, wg[e], wu[e], wd[e], prec)[3]
+        return y.at[r].add(out * w[:, None], mode="drop")
+
+    y = lax.fori_loop(0, n_tiles, body, jnp.zeros((N, H), jnp.float32))
+    return y.astype(x.dtype), (x, wg, wu, wd, top_w, lay, n_tiles)
+
+
+def _held_bwd(tile, res, dy):
+    x, wg, wu, wd, top_w, lay, n_tiles = res
+    N, k = top_w.shape
+    prec = _precision(x.dtype)
+    cd = x.dtype
+    dy = dy.astype(cd)
+    flat_w = top_w.reshape(N * k)
+
+    def body(t, carry):
+        dx, dwg, dwu, dwd, dw = carry
+        e, a, r, live = _tile_rows(t, lay, k, N, tile)
+        w = jnp.where(live, flat_w[a], 0.0)
+        xt = x.at[r].get(mode="fill", fill_value=0)
+        dyt = dy.at[r].get(mode="fill", fill_value=0)
+        gate, up, act, out = _tile_forward(xt, wg[e], wu[e], wd[e], prec)
+        dw = dw.at[jnp.where(live, a, N * k)].add(
+            jnp.sum(dyt.astype(jnp.float32) * out, axis=-1), mode="drop")
+        dout = (dyt.astype(jnp.float32) * w[:, None]).astype(cd)
+        dact = _mm(dout, wd[e], (1, 0), prec)                 # (tile, I)
+        sig = jax.nn.sigmoid(gate)
+        dgate = (dact * up * sig * (1.0 + gate * (1.0 - sig))).astype(cd)
+        dup = (dact * gate * sig).astype(cd)
+        dxt = _mm(dgate, wg[e], (1, 0), prec) + _mm(dup, wu[e], (1, 0), prec)
+        dx = dx.at[r].add(dxt, mode="drop")
+        dwg = dwg.at[e].add(_mm(dgate, xt, (0, 0), prec))
+        dwu = dwu.at[e].add(_mm(dup, xt, (0, 0), prec))
+        dwd = dwd.at[e].add(_mm(dout, act.astype(cd), (0, 0), prec))
+        return dx, dwg, dwu, dwd, dw
+
+    f32 = jnp.float32
+    dx, dwg, dwu, dwd, dw = lax.fori_loop(0, n_tiles, body, (
+        jnp.zeros(x.shape, f32), jnp.zeros(wg.shape, f32),
+        jnp.zeros(wu.shape, f32), jnp.zeros(wd.shape, f32),
+        jnp.zeros((N * k,), f32)))
+    return (dx.astype(x.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
+            dwd.astype(wd.dtype), dw.reshape(N, k).astype(top_w.dtype),
+            None, None)
+
+
+_held_products.defvjp(_held_fwd, _held_bwd)
+
+
+def moe_held_ffn(x, router_w, w_gate, w_up, w_down, top_k, held_start=0,
+                 tile=256):
+    """x: (N, H); router_w: (E_all, H); w_gate, w_up: (E, I, H) and
+    w_down: (E, H, I), the held experts'. Returns (y (N, H) in x's dtype,
+    rows that landed on held experts, max over mean of all experts'
+    counts)."""
+    E = w_gate.shape[0]
+    tile = int(tile)
+    top_i, top_w, counts = route_top_k(x, router_w, int(top_k))
+    counts_held = lax.dynamic_slice(counts, (int(held_start),), (E,))
+    *lay, n_tiles = _tiles(top_i, counts_held, int(held_start), E, tile)
+    y = _held_products(x, w_gate, w_up, w_down, top_w, tuple(lay), n_tiles,
+                       tile)
+    return y, jnp.sum(counts_held), jnp.max(counts) / jnp.mean(counts)
+
+
+def shared_expert_ffn(x, w_gate, w_up, w_down, w_sgate):
+    """sigmoid(x . w_sgate) * W_down (silu(W_gate x) * (W_up x)); the
+    matrices are (out, in); float32 sums, x's dtype between products."""
+    prec = _precision(x.dtype)
+    gate = _mm(x, w_gate, (x.ndim - 1, 1), prec)
+    up = _mm(x, w_up, (x.ndim - 1, 1), prec)
+    act = (jax.nn.silu(gate) * up).astype(x.dtype)
+    out = _mm(act, w_down, (x.ndim - 1, 1), prec)
+    sg = jax.nn.sigmoid(_mm(x, w_sgate, (x.ndim - 1, 1), prec))
+    return (sg * out).astype(x.dtype)
+
+
+@register("_contrib_moe_held_ffn", num_outputs=2, visible_outputs=1,
+          aux_write={1: 5},
+          counters={5: ("moe.assignments.held", "moe.load.max_over_mean")})
+def _moe_held_ffn_op(x, router_weight, gate_weight, up_weight, down_weight,
+                     stats, *, top_k, held_start=0, tile=256):
+    """The routed experts' part of a sparse layer, for the experts held
+    here (see the module). x: (..., H). `stats` (2,) is a device counter:
+    the held rows of the last step and its load's max over mean."""
+    lead = x.shape[:-1]
+    y, held, load = moe_held_ffn(x.reshape(-1, x.shape[-1]), router_weight,
+                                 gate_weight, up_weight, down_weight, top_k,
+                                 held_start, tile)
+    return (y.reshape(lead + (x.shape[-1],)),
+            jnp.stack([held, load]).astype(stats.dtype))
+
+
+@register("_contrib_shared_expert_ffn")
+def _shared_expert_ffn_op(x, gate_weight, up_weight, down_weight,
+                          expert_gate_weight):
+    return shared_expert_ffn(x, gate_weight, up_weight, down_weight,
+                             expert_gate_weight)

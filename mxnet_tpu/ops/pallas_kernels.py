@@ -133,10 +133,9 @@ def _attn_reference(q, k, v, causal):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal=False, block_q=128, block_k=128):
-    """Fused attention, q/k/v: (B, H, T, D). Pallas forward; backward
-    recomputes attention (flash-style rematerialization: O(T) memory in
-    fwd, FLOPs traded in bwd — the same tradeoff as
-    MXNET_BACKWARD_DO_MIRROR)."""
+    """Fused attention, q/k/v: (B, H, T, D). Pallas forward; the
+    backward recomputes the scores one block of `block_q` query rows at
+    a time (ops/attention.py), so neither pass holds a T x T array."""
     return _flash_fwd(q, k, v, causal, block_q, block_k)
 
 
@@ -145,9 +144,11 @@ def _fa_fwd(q, k, v, causal, block_q, block_k):
 
 
 def _fa_bwd(causal, block_q, block_k, res, g):
+    from .attention import blocked_causal_attention
     q, k, v = res
-    _, vjp = jax.vjp(lambda a, b, c: _attn_reference(a, b, c, causal),
-                     q, k, v)
+    t = lambda x: x.transpose(0, 2, 1, 3)                # noqa: E731
+    _, vjp = jax.vjp(lambda a, b, c: t(blocked_causal_attention(
+        t(a), t(b), t(c), block_q, causal=causal)), q, k, v)
     return vjp(g)
 
 

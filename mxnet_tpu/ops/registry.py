@@ -29,11 +29,11 @@ _OPS = {}
 class Op:
     __slots__ = ("name", "fn", "num_outputs", "doc", "params",
                  "needs_rng", "takes_mode", "visible_outputs", "aux_write",
-                 "input_names", "allow_extra_params")
+                 "input_names", "allow_extra_params", "counters")
 
     def __init__(self, name, fn, num_outputs=1, doc=None, needs_rng=False,
                  takes_mode=False, visible_outputs=None, aux_write=None,
-                 input_names=None):
+                 input_names=None, counters=None):
         self.name = name
         self.fn = fn
         # int, or callable(params_dict) -> int for ops whose output arity
@@ -54,6 +54,12 @@ class Op:
         # call, hidden output i must be written back into input j's array
         # (reference: mutable aux_states, e.g. BatchNorm moving stats).
         self.aux_write = dict(aux_write or {})
+        # counters: {input_index: (metric name, ...)} — that aux input is
+        # a device counter: a float32 vector, one element a metric, that
+        # the op overwrites each training step (through aux_write) and a
+        # trainer publishes without a host sync
+        # (observability/device_counters.py)
+        self.counters = dict(counters or {})
         sig = inspect.signature(fn)
         self.params = {
             p.name: p.default
@@ -88,7 +94,7 @@ class Op:
 
 def register(name=None, num_outputs=1, aliases=(), needs_rng=False,
              takes_mode=False, visible_outputs=None, aux_write=None,
-             input_names=None):
+             input_names=None, counters=None):
     """Register an op. Usable as decorator::
 
         @register("relu")
@@ -103,7 +109,8 @@ def register(name=None, num_outputs=1, aliases=(), needs_rng=False,
         opname = _name or fn.__name__
         op = Op(opname, fn, num_outputs=num_outputs, needs_rng=needs_rng,
                 takes_mode=takes_mode, visible_outputs=visible_outputs,
-                aux_write=aux_write, input_names=input_names)
+                aux_write=aux_write, input_names=input_names,
+                counters=counters)
         if opname in _OPS:
             raise MXNetError("op %r already registered" % opname)
         _OPS[opname] = op

@@ -34,9 +34,10 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..base import MXNetError
 from ..ndarray import NDArray
 from .. import symbol as _sym
-from ..graph import build_graph_fn, collect_vars
+from ..graph import build_graph_fn, collect_vars, counter_vars
 from .. import random as _random
 from ..compile.programs import scope as _scope
+from ..observability import device_counters as _devc
 from ..observability.trace import StepRoot, detach, trace_span
 from ..resilience import numerics as _num
 from ..resilience.preempt import at_step_boundary
@@ -148,14 +149,20 @@ class ShardedTrainer:
         Reference: src/kvstore/gradient_compression.h. Requires a pure
         data-parallel mesh (no param_rules).
 
-        remat: rematerialize the forward during backward
-        (jax.checkpoint) instead of keeping all activations live —
-        trades ~33% more FLOPs for activation memory, the lever that
-        lets batch sizes that would spill HBM compile (reference
-        analog: MXNET_BACKWARD_DO_MIRROR, docs/faq/env_var.md). True
-        for full remat, or the name of a jax.checkpoint_policies
-        member (e.g. "dots_with_no_batch_dims_saveable") for selective
-        remat."""
+        remat: wrap the WHOLE traced graph in one jax.checkpoint: the
+        forward pass keeps no residual, and the backward pass begins by
+        computing the whole forward again, every residual of which is
+        then live at once. That costs ~33% more FLOPs and does not
+        lower the step's peak memory; what it shortens is the time the
+        residuals are held, and with a policy name (a
+        jax.checkpoint_policies member, e.g.
+        "dots_with_no_batch_dims_saveable") which of them are kept from
+        the first pass. To fit a model whose activations do not, mark
+        its layers in the block (`HybridBlock.remat_scope`,
+        docs/performance.md "Rematerialisation by layer"): each marked
+        group is recomputed on its own and the peak falls to one
+        group's residuals. (Reference analog:
+        MXNET_BACKWARD_DO_MIRROR, docs/faq/env_var.md.)"""
         self._net = net
         self._compute_dtype = (jnp.dtype(compute_dtype)
                                if compute_dtype is not None else None)
@@ -215,6 +222,10 @@ class ShardedTrainer:
         self._param_names = [n.name for n in arg_nodes
                              if n.name not in input_set]
         self._aux_names = [n.name for n in aux_nodes]
+        # aux states that an op declares as device counters: the step
+        # returns them a second time, not donated, for
+        # observability/device_counters.py (none: nothing is added)
+        self._counter_vars = counter_vars(loss_sym._entries)
         self._fn, _, _, self._needs_rng = build_graph_fn(
             loss_sym._entries, aux_mode)
         if remat:
@@ -345,13 +356,16 @@ class ShardedTrainer:
     # -- compiled step --------------------------------------------------
     def _make_step_body(self, guarded=None):
         """The pure per-step function (params, aux, opt_state, inputs,
-        key) -> (params', aux', opt_state', loss, ok), shared by the
+        key) -> (params', aux', opt_state', loss, ok, counters), shared by the
         single-step jit and the scanned multi-step program. `ok` is the
         numerics guard's in-graph verdict: with MXTPU_NUMERICS (read at
         trace time) a step whose gradients are not all finite is
         SKIPPED — params/aux/opt state pass through bit-identical via
         `jnp.where` — and `ok` reports it; with the guard off `ok` is a
         constant True and the jaxpr is exactly the pre-guard one.
+
+        `counters` is {var: array} of the aux states that are device
+        counters, as the step left them ({} for a graph with none).
 
         `guarded=False` forces the unguarded body regardless of the
         env: the scanned multi-step program uses it — a few hundred
@@ -364,6 +378,7 @@ class ShardedTrainer:
         cd = self._compute_dtype
         data_names = set(self._data_names)
         guard = _num.enabled() if guarded is None else bool(guarded)
+        counter_names = sorted(self._counter_vars)
 
         # the scopes name the owners of device time that no graph node
         # is (compile/programs.py); the function's name is the program's
@@ -406,7 +421,8 @@ class ShardedTrainer:
                     new_state = jax.tree.map(keep, new_state, opt_state)
             else:
                 ok = jnp.bool_(True)
-            return new_params, new_aux, new_state, loss, ok
+            counters = {k: new_aux[k] for k in counter_names}
+            return new_params, new_aux, new_state, loss, ok, counters
 
         return sharded_step
 
@@ -457,7 +473,8 @@ class ShardedTrainer:
         self._step_fn = jax.jit(
             step,
             in_shardings=(param_sh, aux_sh, opt_sh, in_sh, None),
-            out_shardings=(param_sh, aux_sh, opt_sh, rep, rep),
+            out_shardings=(param_sh, aux_sh, opt_sh, rep, rep,
+                           {k: rep for k in self._counter_vars}),
             donate_argnums=(0, 1, 2))
 
     def _build_step_many(self):
@@ -487,7 +504,7 @@ class ShardedTrainer:
                     key, sub = jax.random.split(key)
                 else:
                     sub = None
-                params, aux, opt_state, loss, _ok = body(
+                params, aux, opt_state, loss, _ok, _counters = body(
                     params, aux, opt_state, inputs, sub)
                 return (params, aux, opt_state, key), loss
             (params, aux, opt_state, _), losses = lax.scan(
@@ -772,6 +789,7 @@ class ShardedTrainer:
         # (contrib.RingAttention / contrib.MoEFFN) pick their sp/ep paths
         from .mesh import use_mesh
         with use_mesh(self._mesh), trace_span("step.launch"):
+            counters = None
             if self._grad_compression is not None:
                 (self._params, self._aux, self._opt_state,
                  self._gc_residuals, loss, ok) = self._step_fn(
@@ -779,12 +797,14 @@ class ShardedTrainer:
                     self._gc_residuals, inputs, key)
             else:
                 (self._params, self._aux, self._opt_state,
-                 loss, ok) = self._step_fn(
+                 loss, ok, counters) = self._step_fn(
                     self._params, self._aux, self._opt_state, inputs, key)
         with trace_span("step.finish"):
             _fstep.STEP_DISPATCHES.inc()   # the whole step was ONE program
             if _num.enabled():
                 _num.record_flag(ok, where="step")
+            if counters:
+                _devc.publish(self._counter_vars, counters)
             self._step_count += 1
             loss = NDArray(loss)
         self._root.end(self._step_count)

@@ -347,6 +347,42 @@ case("_CrossDeviceCopy", [U((3, 4))])
 # ---------------------------------------------------------------------------
 
 # outputs are indices / ints / bools / shapes: no gradient exists
+# --- the linear-attention decoder's ops (PR 32); their own generator, so
+# that the cases above keep the inputs they had --------------------------
+_Q = np.random.RandomState(32)
+
+
+def _q(shape, lo=-1.0, hi=1.0):
+    return _Q.uniform(lo, hi, shape).astype("float32")
+
+
+case("_contrib_gated_delta_rule",
+     [_q((1, 8, 1, 4)), _q((1, 8, 1, 4)), _q((1, 8, 2, 4)), _q((1, 8, 2)),
+      _q((1, 8, 2)), _q((2,), -3, -2), _q((2,), 0.5, 1.5),
+      np.zeros(1, "float32")],
+     params={"chunk": 4}, wrt=(0, 1, 2, 3, 4, 5, 6), atol=2e-2)
+case("_contrib_causal_conv1d", [_q((1, 6, 3)), _q((3, 4))],
+     params={"activation": "silu"}, wrt=(0, 1))
+case("_contrib_rms_norm", [_q((2, 5)), _q((5,))], params={"offset": 1.0},
+     wrt=(0, 1))
+case("_contrib_gated_rms_norm", [_q((2, 5)), _q((2, 5)), _q((5,), 0.5, 1.5)],
+     wrt=(0, 1, 2))
+case("_contrib_causal_gqa_attention",
+     [_q((1, 8, 4, 4)), _q((1, 8, 2, 4)), _q((1, 8, 2, 4))],
+     params={"block_q": 4}, wrt=(0, 1, 2), atol=2e-2)
+case("_contrib_rotary_embedding", [_q((1, 4, 2, 8))],
+     params={"rotary_dim": 4, "theta": 100.0})
+# the held experts' matrices: smooth at the sample (the router's choice
+# is piecewise constant in x and in its own weights)
+case("_contrib_moe_held_ffn",
+     [_q((6, 8)), _q((8, 8)), _q((2, 4, 8)), _q((2, 4, 8)), _q((2, 8, 4)),
+      np.zeros(2, "float32")],
+     params={"top_k": 3, "held_start": 2, "tile": 4}, wrt=(2, 3, 4),
+     atol=2e-2)
+case("_contrib_shared_expert_ffn",
+     [_q((3, 8)), _q((4, 8)), _q((4, 8)), _q((8, 4)), _q((1, 8))],
+     wrt=(0, 1, 2, 3, 4), atol=2e-2)
+
 EXEMPT_NONFLOAT_OUTPUT = {
     "argmax", "argmin", "argsort", "topk", "sort",  # sort: permutation —
     # value-grads exist but are just scatter of ones; covered via topk in
