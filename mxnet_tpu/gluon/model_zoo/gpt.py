@@ -245,14 +245,6 @@ class GPTDecoder(HybridBlock):
         pos = F.slice_like(P["pos_embed_weight"], F.transpose(tokens),
                            axes=(0,))
         x = F.broadcast_add(x, F.expand_dims(pos, axis=0))
-        # causal mask from token positions (no constant buffers, so the
-        # trace stays shape-agnostic): r = 1..T per row
-        r = F.cast(F.cumsum(F.ones_like(tokens), axis=1),
-                   dtype="float32")
-        allowed = F.broadcast_lesser_equal(F.expand_dims(r, axis=1),
-                                           F.expand_dims(r, axis=2))
-        add = F.expand_dims((allowed - 1.0) * _MASK, axis=1)
-        scale = 1.0 / float(np.sqrt(D))
         for i in range(cfg["num_layers"]):
             h = F.LayerNorm(x, gamma=P["h%d_ln1_gamma" % i],
                             beta=P["h%d_ln1_beta" % i], axis=-1,
@@ -269,9 +261,10 @@ class GPTDecoder(HybridBlock):
             k = heads(F.slice_axis(qkv, axis=-1, begin=E, end=2 * E))
             v = heads(F.slice_axis(qkv, axis=-1, begin=2 * E,
                                    end=3 * E))
-            scores = F.batch_dot(q, k, transpose_b=True) * scale
-            p = F.softmax(F.broadcast_add(scores, add), axis=-1)
-            ctx = F.batch_dot(p, v)      # (B,H,T,D)
+            # one op, which chooses from the shape it is traced with: the
+            # Pallas kernels where T and D tile, else batch_dot, softmax
+            # over the masked scores and batch_dot as they stood here
+            ctx = F.contrib.flash_attention(q, k, v, causal=True)
             ctx = F.reshape(F.transpose(ctx, axes=(0, 2, 1, 3)),
                             shape=(0, 0, E))
             x = x + F.FullyConnected(ctx,

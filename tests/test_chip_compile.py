@@ -68,13 +68,26 @@ def _compile(fn, one_chip, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("causal", [True, False],
-                         ids=["causal", "full"])
-def test_flash_attention_gpt2_small(one_chip, for_the_chip, causal):
-    # one GPT-2-small attention call: B=8, H=12, T=1024, D=64, bf16
-    qkv = ((8, 12, 1024, 64), jnp.bfloat16)
-    _compile(lambda q, k, v: pk.flash_attention(q, k, v, causal),
-             one_chip, qkv, qkv, qkv)
+@pytest.mark.parametrize("causal,dtype", [
+    (True, jnp.bfloat16), (False, jnp.bfloat16), (True, jnp.float32)],
+    ids=["causal", "full", "causal-float32"])
+def test_flash_attention_gpt2_small(one_chip, for_the_chip, causal, dtype):
+    # one GPT-2-small attention call, B=8, H=12, T=1024, D=64, at the
+    # blocks the op chooses: the forward kernel and both kernels of the
+    # backward (tiling and VMEM limits of all three). bfloat16, and
+    # float32 as `ShardedTrainer`'s bf16 step hands q, k and v over
+    # (LayerNorm's float32 gamma promotes them): at the default precision
+    # the kernels then read them rounded once to bfloat16
+    qkv = ((8, 12, 1024, 64), dtype)
+    compiled = _compile(jax.value_and_grad(
+        lambda q, k, v: pk.flash_attention(q, k, v, causal)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)),
+        one_chip, qkv, qkv, qkv)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "1024,1024]" not in text             # no T x T array a head
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert all("constraints={bf16[96,1024,64]" in line for line in calls)
 
 
 def test_layer_norm_8192x768(one_chip, for_the_chip):

@@ -312,6 +312,9 @@ def test_wedged_prefill_requeues_prompt_until_recovery(monkeypatch):
     re-admits it — only mid-decode sequences fail typed."""
     _arm(monkeypatch, timeout="0.2", trips="2", canary="0.05")
     engine = DecodeEngine(_gpt_block(), max_slots=2, name="requeue")
+    # the programs compile before the 0.2 s watchdog is in the way: a
+    # cold compile is not the wedge this test plants
+    engine.warmup()
     sched = ContinuousBatchScheduler(engine, max_new_tokens=3,
                                      name="requeue/0").start()
     try:
