@@ -640,7 +640,7 @@ def _memledger_overhead_pct(steps=120, warmup=20):
                                              momentum=0.9))
             idx = list(range(len(ws)))
             for _ in range(warmup):
-                if not _fstep.try_step(upd, idx, gs, ws):
+                if not _fstep.step(upd, idx, gs, ws):
                     raise RuntimeError("fused step refused — the "
                                        "memledger probe measures its "
                                        "dispatch wrapper")
@@ -648,7 +648,7 @@ def _memledger_overhead_pct(steps=120, warmup=20):
             jax.block_until_ready([w._data for w in ws])
             t0 = time.perf_counter()
             for _ in range(steps):
-                _fstep.try_step(upd, idx, gs, ws)
+                _fstep.step(upd, idx, gs, ws)
             jax.block_until_ready([w._data for w in ws])
             return time.perf_counter() - t0
         finally:

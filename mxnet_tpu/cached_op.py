@@ -17,7 +17,7 @@ import re
 import jax
 import jax.numpy as jnp
 
-from .base import MXNetError, getenv
+from .base import MXNetError
 from .graph import build_graph_fn, collect_vars
 from .ndarray import NDArray
 from .observability import registry as _obs
@@ -97,16 +97,10 @@ class CachedOp:
                 return vjp_fn(list(cots))[0]
 
             bwd.__name__ = "cachedop_bwd_" + self._program
-
-            # MXTPU_DONATE_CACHEDOP=1: donate the output cotangents —
-            # the one backward input that is step-local (weights/aux
-            # must outlive the call). Opt-in: a cotangent can alias a
-            # user-visible .grad buffer when an intermediate output has
-            # attach_grad, and donation would invalidate it
-            # (docs/performance.md "donation caveats").
-            donate = (3,) if getenv("MXTPU_DONATE_CACHEDOP", False) \
-                else ()
-            self._bwd_jits[mode] = jax.jit(bwd, donate_argnums=donate)
+            # nothing is donated: weights and aux must outlive the
+            # call, and a cotangent can alias a user-visible .grad
+            # buffer (an intermediate output with attach_grad)
+            self._bwd_jits[mode] = jax.jit(bwd)
         return self._bwd_jits[mode]
 
     def __call__(self, *inputs):

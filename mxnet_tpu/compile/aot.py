@@ -79,8 +79,7 @@ _HOLDERS = "holders"
 # the env knobs that change generated programs: part of every
 # fingerprint, so flipping one can never replay a stale executable
 _KEYED_FLAGS = ("MXTPU_SERVE_DTYPE", "MXTPU_SERVE_DONATE",
-                "MXTPU_NUMERICS", "MXTPU_FUSED_UPDATE",
-                "MXTPU_DONATE_UPDATE", "MXTPU_BUCKET_MB")
+                "MXTPU_NUMERICS", "MXTPU_BUCKET_MB")
 
 
 class StoreHeld(MXNetError):

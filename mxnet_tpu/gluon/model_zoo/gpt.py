@@ -26,6 +26,8 @@ row count tracked by a per-slot position vector.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import numpy as np
 
 import jax
@@ -142,6 +144,15 @@ def _prefill_jax(cfg, P, tokens, length):
     return next_token, k, v
 
 
+def _next_token_jax(cfg, P, tokens, length):
+    """Greedy next token of one sequence by a full forward pass, no
+    cache: tokens (1, L) padded to the context window, `length` the
+    real length (traced). Attention is causal, so what lies beyond
+    `length` never reaches the row that is read."""
+    logits = _forward_jax(cfg, P, tokens)[0]
+    return jnp.argmax(jnp.take(logits[0], length - 1, axis=0))
+
+
 def _step_jax(cfg, P, cache_k, cache_v, positions, active, tokens):
     """One decode step for every slot at once. cache_k/cache_v:
     (num_layers, S, L, H, D) donated; positions (S,) int32 donated —
@@ -232,6 +243,12 @@ class GPTDecoder(HybridBlock):
                 p("h%d_mlp_down_bias" % i, (E,), "zeros")
             p("lnf_gamma", (E,), "ones")
             p("lnf_beta", (E,), "zeros")
+        # the engine-less paths (`step`, `generate_reference`) each
+        # compile once: run op by op they compile every `jnp` call
+        # again for every sequence length
+        self._step_jit = jax.jit(partial(_step_jax, self._cfg))
+        self._next_token_jit = jax.jit(partial(_next_token_jax,
+                                               self._cfg))
 
     # -- Gluon path ----------------------------------------------------
     def hybrid_forward(self, F, tokens, **P):
@@ -334,18 +351,18 @@ class GPTDecoder(HybridBlock):
                 _step_jax(cfg, P, ck, cv, pos, act, tok))
 
     def step(self, token, kv_cache, position):
-        """Eager single-token decode over all slots: `token` (S,) int
-        array (the last generated token per slot), `kv_cache` the
-        (k, v) pair from `init_cache`, `position` (S,) int32 cached-row
-        counts. Returns (next_token NDArray (S,), (k, v), position')."""
+        """Single-token decode over all slots, without an engine:
+        `token` (S,) int array (the last generated token per slot),
+        `kv_cache` the (k, v) pair from `init_cache`, `position` (S,)
+        int32 cached-row counts. Returns (next_token NDArray (S,), (k, v), position')."""
         ck, cv = kv_cache
         tok = token._data if isinstance(token, NDArray) \
             else jnp.asarray(np.asarray(token))
         pos = position._data if isinstance(position, NDArray) \
             else jnp.asarray(np.asarray(position, dtype=np.int32))
         active = jnp.ones(pos.shape, bool)
-        ck, cv, pos, nxt = _step_jax(
-            self._cfg, self.decode_params(), ck, cv,
+        ck, cv, pos, nxt = self._step_jit(
+            self.decode_params(), ck, cv,
             pos.astype(jnp.int32), active, tok.astype(jnp.int32))
         return NDArray(nxt), (ck, cv), NDArray(pos)
 
@@ -357,13 +374,13 @@ class GPTDecoder(HybridBlock):
         cfg = self._cfg
         P = self.decode_params()
         seq = [int(t) for t in np.asarray(tokens).reshape(-1)]
+        padded = np.zeros((1, cfg["max_seq_len"]), np.int32)
         out = []
         for _ in range(int(max_new_tokens)):
             if len(seq) > cfg["max_seq_len"]:
                 break          # context window full: nothing to forward
-            logits = _forward_jax(
-                cfg, P, jnp.asarray([seq], dtype=jnp.int32))[0]
-            nxt = int(jnp.argmax(logits[0, -1]))
+            padded[0, :len(seq)] = seq
+            nxt = int(self._next_token_jit(P, padded, len(seq)))
             out.append(nxt)
             seq.append(nxt)
             if cfg["eos_token"] is not None and nxt == cfg["eos_token"]:

@@ -20,10 +20,12 @@ Fused one-program step (docs/performance.md "Fused train step &
 ZeRO-1", default on): `step()` runs gradient exchange + optimizer
 update as ONE donated jit program (parallel/fused_step.py) — no
 host-visible buffers or Python between the phases, recorded as a
-single "step.launch" phase in telemetry. ``MXTPU_FUSED_STEP=0``, unsupported
-optimizers, compression, or update-on-kvstore fall back to the staged
-bucketed path below (the bit-parity oracle); `allreduce_grads()` /
-`update()` always take the staged halves, unchanged.
+single "step.launch" phase in telemetry. Whether it runs is
+`fused_step.step`'s answer alone, from what the step observes
+(optimizer class, kvstore, process count, the key set); a refusal
+takes the staged bucketed path below (the bit-parity oracle).
+`allreduce_grads()` / `update()` always take the staged halves: that
+pair is how a caller, or a test, asks for them.
 """
 from __future__ import annotations
 
@@ -193,8 +195,8 @@ class Trainer:
     # -- the step -------------------------------------------------------
     def step(self, batch_size, ignore_stale_grad=False):
         """One optimization step: reduce grads, then update params
-        (reference: trainer.py:241). With ``MXTPU_FUSED_STEP`` (default
-        on) both phases run as ONE donated jit program — the gradient
+        (reference: trainer.py:241). Where `fused_step.step` takes it,
+        both phases run as ONE donated jit program — the gradient
         exchange and the fused update share an XLA computation, so the
         telemetry record carries a single "step.launch" phase and
         `train.step.dispatches` reads exactly 1. The spans are every
@@ -212,7 +214,7 @@ class Trainer:
             self._ensure_ready()
             self._optimizer.rescale_grad = self._rescale(batch_size)
             plan = self._fused_plan(ignore_stale_grad)
-        if plan is None or not self._fused_launch(plan, tel):
+        if not self._fused_launch(plan, tel, ignore_stale_grad):
             with tel.phase("allreduce"):
                 self._reduce()
             with tel.phase("optimizer"):
@@ -225,50 +227,32 @@ class Trainer:
     def _fused_plan(self, ignore_stale_grad):
         """What the one-program exchange+update step
         (parallel/fused_step.py) would run on: (slots, grads, weights,
-        kvstore), empty when there is nothing to update; None falls
-        back to the staged bucketed path with nothing mutated.
+        kvstore). Whether it runs is `fused_step.step`'s to say.
 
         ZeRO-1 note (docs/performance.md): with ``MXTPU_ZERO1=1`` in a
         multi-process run, `save_states`/`get_states` all-gathers the
         sharded optimizer state — a COLLECTIVE every rank must enter;
         a rank-0-only save_states would deadlock (save through
         `parallel.TrainerCheckpoint` or call it on every rank)."""
-        if not _fstep.enabled() or self._update_via_kv:
-            return None
-        kv = self._kvstore if self._reduce_via_kv else None
-        multi = getattr(kv, "num_workers", 1) > 1
-        if ignore_stale_grad and multi:
-            # freshness is RANK-LOCAL: filtering collective membership
-            # by it would desynchronize the SPMD program across ranks
-            # (the staged path always exchanges the full trainable
-            # set) — staged, unconditionally
-            return None
         pairs = self._trainable()
         if ignore_stale_grad:
             pairs = [(i, p) for i, p in pairs if p.grad()._fresh_grad]
-        idxs = [i for i, _ in pairs]
-        # cheap latched pre-check BEFORE the phase opens: permanently
-        # staged runs (RMSProp, compression, refused key sets) must
-        # not emit a bogus "step.launch" trace span every iteration
-        if pairs and not _fstep.eligible(self._updaters[0], idxs,
-                                         kvstore=kv):
-            return None
-        return (idxs, [p.grad() for _, p in pairs],
-                [p.data() for _, p in pairs], kv)
+        return ([i for i, _ in pairs], [p.grad() for _, p in pairs],
+                [p.data() for _, p in pairs],
+                self._kvstore if self._reduce_via_kv else None)
 
-    def _fused_launch(self, plan, tel):
-        """Run the planned one-program step. Returns True when it ran
-        (or had nothing to update: zero dispatches); False falls back
-        to the staged path with nothing mutated."""
+    def _fused_launch(self, plan, tel, ignore_stale_grad):
+        """Offer the planned step to `fused_step.step`. Returns True
+        when the one program ran; False falls back to the staged path
+        with nothing mutated."""
         idxs, grads, weights, kv = plan
-        if not idxs:
-            return True
         with tel.phase("step.launch"):
-            ran = _fstep.try_step(self._updaters[0], idxs, grads,
-                                  weights, kvstore=kv)
+            ran = _fstep.step(self._updaters[0], idxs, grads, weights,
+                              kvstore=kv,
+                              ignore_stale_grad=ignore_stale_grad)
         if not ran:
-            # first-time collect refusal (now latched): drop the empty
-            # phase so the staged record keeps its shape
+            # refused (microseconds: the rule is checked before
+            # anything is collected): the staged record keeps its shape
             tel._phases.pop("step.launch", None)
             return False
         if self._numerics is not None:

@@ -88,10 +88,10 @@ def _update_params(param_arrays, grad_arrays, updater, num_device,
     reduce and the optimizer update fuse into ONE donated jit
     program (parallel/fused_step.py) — this is `Module.fit`'s update
     half, so a fit step becomes forward+backward (one executor
-    program) plus exactly one exchange+update program.
-    ``MXTPU_FUSED_STEP=0`` (or any ineligible key/optimizer/store)
-    restores the staged push_all/pull_all + update_all path below,
-    which remains the bit-parity oracle."""
+    program) plus exactly one exchange+update program. Whether it runs
+    is `fused_step.step`'s answer (optimizer class, store, key set); a
+    refusal takes the staged push_all/pull_all + update_all path
+    below, which remains the bit-parity oracle."""
     updates = [[] for _ in range(num_device)]
     names, kv_grads, prios = [], [], []
     for i, pair in enumerate(zip(param_arrays, grad_arrays)):
@@ -108,16 +108,12 @@ def _update_params(param_arrays, grad_arrays, updater, num_device,
         for k, p in enumerate(zip(arg_list, grad_list)):
             w, g = p
             updates[k].append((index * num_device + k, g, w))
-    if num_device == 1 and updates[0]:
+    if num_device == 1:
         from .parallel import fused_step as _fstep
-        idxs = [u[0] for u in updates[0]]
-        if _fstep.enabled() and \
-                _fstep.eligible(updater, idxs,
-                                kvstore=kvstore or None) and \
-                _fstep.try_step(
-                    updater, idxs, [u[1] for u in updates[0]],
-                    [u[2] for u in updates[0]],
-                    kvstore=kvstore or None):
+        if _fstep.step(updater, [u[0] for u in updates[0]],
+                       [u[1] for u in updates[0]],
+                       [u[2] for u in updates[0]],
+                       kvstore=kvstore or None):
             return
     if kvstore and names:
         kvstore.push_all(names, kv_grads, priorities=prios)

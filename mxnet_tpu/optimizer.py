@@ -21,7 +21,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .base import MXNetError, getenv
+from .base import MXNetError
 from .ndarray import NDArray
 from .observability import registry as _obs
 
@@ -42,29 +42,20 @@ _UPDATE_DISPATCHES = _obs.counter(
 # avoid an import cycle)
 _STEP_DISPATCHES = _obs.counter("train.step.dispatches")
 
-def donate_update_enabled():
-    """Buffer donation for the update jits (weights/optimizer state
-    only — never grads, which other code may still read): XLA reuses
-    the donated input storage for the same-shaped output, so
-    steady-state updates allocate nothing. MXTPU_DONATE_UPDATE=0
-    restores allocate-and-swap (docs/performance.md aliasing caveat).
-    Re-read per call so the opt-out works after import — the jit
-    wrappers below are cached per flag value."""
-    return getenv("MXTPU_DONATE_UPDATE", True)
-
-
 _KERNEL_JITS = {}
 
 
 def _jit_update_kernel(name, fn, static_argnums, donate_argnums):
-    """Per-(kernel, donation-flag) jit wrapper cache for the per-op
-    update kernels; jax.jit's own cache handles shapes/statics."""
-    donate = donate_argnums if donate_update_enabled() else ()
-    key = (name, donate)
-    jitted = _KERNEL_JITS.get(key)
+    """One jit a per-op update kernel; jax.jit's own cache handles
+    shapes/statics. The weight and the optimizer state are donated
+    (never grads, which other code may still read): XLA reuses the
+    donated input storage for the same-shaped output, so steady-state
+    updates allocate nothing (docs/performance.md aliasing caveat)."""
+    jitted = _KERNEL_JITS.get(name)
     if jitted is None:
-        jitted = _KERNEL_JITS[key] = jax.jit(
-            fn, static_argnums=static_argnums, donate_argnums=donate)
+        jitted = _KERNEL_JITS[name] = jax.jit(
+            fn, static_argnums=static_argnums,
+            donate_argnums=donate_argnums)
     return jitted
 
 
@@ -399,9 +390,15 @@ class Adam(Optimizer):
 # RMSProp/AdaGrad math in the fused-kernel signature
 # (w, g, states, lr, t, wd, hyper): the per-key jits below AND the
 # fused group jits (parallel/fused_update.py) wrap this SAME function,
-# so both paths trace identical jaxprs — the structural guarantee
-# behind the bit-parity contract (an eager per-key path would let XLA
-# make different fusion/FMA choices than the fused kernel).
+# so both paths trace the same expressions (an eager per-key path
+# would let XLA make different fusion/FMA choices than the fused
+# kernel). Same expressions are the same bits for AdaGrad. For RMSProp
+# they are not quite: XLA rewrites `x / sqrt(y)` to `x * rsqrt(y)`, and
+# XLA:CPU emits rsqrt as the hardware estimate refined by two Newton
+# steps whose multiply-adds it contracts by the loop it is emitting, so
+# centered RMSProp over a (n, 4) array and over the same elements in a
+# flat buffer can differ by one ulp of the quotient a step (a reshape
+# inside the jit does not pin the loop's shape: XLA folds it away).
 
 
 def _adagrad_math(weight, grad, states, lr, t, wd, hyper):
@@ -806,8 +803,9 @@ class Updater:
 def get_updater(optimizer):
     """An updater for kvstore/trainer/module drive loops. Returns the
     fusing variant (parallel/fused_update.py) — it degrades to the
-    per-key path per call for unsupported optimizers, sparse keys, or
-    MXTPU_FUSED_UPDATE=0, so it is always a safe default."""
+    per-key path per call for unsupported optimizers and sparse keys,
+    so it is always a safe default. `Updater(optimizer)` is the
+    per-key reference the tests hold it against."""
     try:
         from .parallel.fused_update import FusedUpdater
     except ImportError:

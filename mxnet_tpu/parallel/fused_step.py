@@ -68,8 +68,17 @@ updated by the SAME kernel functions, and the cross-replica sum is the
 same stacked `jnp.sum` the bucketed exchange issues — elementwise IEEE
 ops commute with concatenation, so the fused step is bit-identical to
 the staged path on either boundary (asserted in
-tests/test_fused_step.py). ``MXTPU_FUSED_STEP=0``
-restores the staged bucketed path, which remains the parity oracle.
+tests/test_fused_step.py).
+
+Which path a step takes is decided HERE and nowhere else: `step()` is
+what `gluon.Trainer.step` and `Module.update` call, and `_staged_by`
+is the whole rule, read from what the step observes (the updater's
+type, the optimizer's class, the kvstore, the process count with
+`ignore_stale_grad`, a key set that refused before). A refusal sends
+the caller to the staged bucketed exchange + grouped update
+(parallel/fused_update.py), which stays the parity oracle and is what
+`Trainer.allreduce_grads()` + `Trainer.update()` always run: that pair
+is how a test reaches it.
 
 Artifact subsystem (PR 11): program builds run under the persistent
 compilation cache, and single-device programs register with the
@@ -82,8 +91,7 @@ captures the step program by driving a tiny Trainer loop under
 touch the store — a deserialized multi-device CPU executable can
 segfault jaxlib (the compile/cache.py guard).
 
-Env knobs:
-  MXTPU_FUSED_STEP   one-program step behind Trainer/Module (default 1)
+Env knob:
   MXTPU_ZERO1        shard optimizer state over the dp axis (default 0)
 """
 from __future__ import annotations
@@ -106,8 +114,7 @@ from .. import optimizer as opt
 from ..resilience import numerics as _num
 from ..resilience.chaos import armed as _chaos_armed, corrupt_point
 
-__all__ = ["FusedTrainStep", "enabled", "zero1_enabled", "try_step",
-           "eligible",
+__all__ = ["FusedTrainStep", "zero1_enabled", "step",
            "STEP_DISPATCHES", "FUSED_PATH", "ZERO1_SHARD_PARAMS",
            "ZERO1_ALLGATHER_SECONDS"]
 
@@ -134,13 +141,6 @@ ZERO1_ALLGATHER_SECONDS = _obs.histogram(
     "zero1.allgather.seconds",
     "Wall time all-gathering ZeRO-1-sharded optimizer state into a "
     "full copy (get_states / checkpoint / staged-fallback boundaries)")
-
-
-def enabled():
-    """MXTPU_FUSED_STEP gate, re-read per call (default on): the
-    one-program exchange+update step behind gluon.Trainer and
-    Module.update. 0 restores the staged bucketed path."""
-    return getenv("MXTPU_FUSED_STEP", True)
 
 
 def zero1_enabled():
@@ -192,10 +192,6 @@ class FusedTrainStep:
     """
 
     def __init__(self, updater):
-        from .fused_update import FusedUpdater
-        if not isinstance(updater, FusedUpdater):
-            raise TypeError("FusedTrainStep needs a FusedUpdater "
-                            "(optimizer.get_updater default)")
         self._updater = updater
         updater._fused_step_owner = self     # get_states flush hook
         self._programs = {}       # signature -> callable
@@ -203,10 +199,10 @@ class FusedTrainStep:
         self._refused = set()     # program signatures latched staged
         # FULL program signature -> (lanes_meta, [per-lane flats]):
         # the ZeRO-1 carried state (authoritative until flushed). The
-        # key includes the zero1/guard/donate flags, so ANY knob
-        # toggled mid-run (MXTPU_ZERO1 off, donation off) mismatches
-        # and flushes instead of feeding sharded padded flats to a
-        # program traced for replicated unpadded ones
+        # key includes the zero1/guard flags, so a knob toggled
+        # mid-run (MXTPU_ZERO1 off) mismatches and flushes instead of
+        # feeding sharded padded flats to a program traced for
+        # replicated unpadded ones
         self._state_flats = {}
         self._gather_fn = {}      # (shape, dtype, mesh) -> gather jit
         self._gauge_val = None    # last zero1.shard_params value set
@@ -218,26 +214,19 @@ class FusedTrainStep:
         jit-cache census hook (steady-state training holds exactly 1)."""
         return len(self._programs)
 
-    def run(self, indices, grads, weights, kvstore=None):
+    def run(self, indices, grads, weights, kvstore=None,
+            ignore_stale_grad=False):
         """Run one fused exchange+update step over the whole trainable
         set. Returns True when the fused program ran (gradient arrays
         are left UNREDUCED and alive — the program never donates them);
         False means the caller must take the staged path (no state was
         mutated, no update counts were bumped)."""
         from .fused_update import _SUPPORTED
-        o = self._updater.optimizer
-        spec = _SUPPORTED.get(type(o))
-        if spec is None or type(o) not in _STEP_OPTS or not indices:
+        nproc, mesh = _exchange_plan(kvstore)
+        probe_key = (type(self._updater.optimizer), tuple(indices))
+        if self._staged_by(probe_key, nproc, ignore_stale_grad):
             return False
-        probe_key = (type(o), tuple(indices))
-        if probe_key in self._refused:
-            # a set that refused once (row-sparse key, unpackable
-            # leaves) refuses every step — don't re-run the full
-            # collection probe just to fall back again
-            return False
-        nproc, mesh = self._exchange_plan(kvstore)
-        if nproc is None:
-            return False
+        spec = _SUPPORTED[probe_key[0]]
         entries, _left = self._updater._collect(
             spec, indices, grads, weights, require_all=True)
         if entries is None:     # ineligible key: nothing was mutated
@@ -248,7 +237,6 @@ class FusedTrainStep:
         lanes = self._plan_lanes(spec, entries)
         zero1 = zero1_enabled() and mesh is not None
         guard = _num.enabled()
-        donate = opt.donate_update_enabled()
         # the program's boundary, from what the step can observe: one
         # process with replicated state hands the per-parameter leaves
         # in and gets leaves back (ZeRO-1 needs a mesh, so nproc == 1
@@ -257,8 +245,7 @@ class FusedTrainStep:
         # fire on the flat itself, so both keep the eager pack
         leaves = nproc == 1 and not (_chaos_armed("grad.post")
                                      or _chaos_armed("weight.post"))
-        sig = (tuple(l.key for l in lanes), nproc, zero1, guard, donate,
-               leaves)
+        sig = (tuple(l.key for l in lanes), nproc, zero1, guard, leaves)
         if self._state_flats and sig not in self._state_flats:
             # layout/cohort/knob change: re-materialize the carried
             # state before the old flats' lane map goes stale
@@ -266,7 +253,7 @@ class FusedTrainStep:
         args = self._leaf_args(lanes) if leaves else \
             self._pack(lanes, sig, nproc, mesh, zero1)
         fn = self._program_for(sig, lanes, args, nproc, mesh, zero1,
-                               guard, donate, leaves)
+                               guard, leaves)
         with _memory.oom_guard("train.step", "trainer"):
             out = fn(*args)
         STEP_DISPATCHES.inc()
@@ -284,6 +271,26 @@ class FusedTrainStep:
         else:
             self._unpack(lanes, out[0], out[1], sig, nproc, zero1)
         return True
+
+    def _staged_by(self, probe_key, nproc, ignore_stale_grad):
+        """THE rule: what sends a step to the staged path before
+        anything is collected, each clause something the step observes.
+        Cheap enough to run every step of a run that stays staged."""
+        opt_class, indices = probe_key
+        return (
+            # RMSProp/AdaGrad and every per-key optimizer: _STEP_OPTS
+            opt_class not in _STEP_OPTS
+            or not indices
+            # a compressing or updating store, several workers behind
+            # a store that is not the process mesh's: _exchange_plan
+            or nproc is None
+            # freshness is RANK-LOCAL: filtering a collective's members
+            # by it would desynchronize the SPMD program across ranks
+            # (the staged path exchanges the full trainable set)
+            or (ignore_stale_grad and nproc > 1)
+            # a set that refused once (row-sparse key, unpackable
+            # leaves) refuses every step: no second collection probe
+            or probe_key in self._refused)
 
     def _charge_goodput(self, sig, lanes, nproc):
         """Charge the step program's FLOPs to the goodput ledger.
@@ -354,10 +361,6 @@ class FusedTrainStep:
         replaced the authoritative per-key states)."""
         self._state_flats.clear()
         _memory.release("trainer", "optimizer", "zero1_state")
-
-    # -- exchange topology ----------------------------------------------
-    def _exchange_plan(self, kvstore):
-        return _exchange_plan(kvstore)
 
     # -- lane planning ---------------------------------------------------
     def _plan_lanes(self, spec, entries):
@@ -508,7 +511,7 @@ class FusedTrainStep:
 
     # -- the program -----------------------------------------------------
     def _program_for(self, sig, lanes, args, nproc, mesh, zero1,
-                     guard, donate, leaves):
+                     guard, leaves):
         cached = self._programs.get(sig)
         if cached is not None:
             return cached
@@ -611,10 +614,9 @@ class FusedTrainStep:
         # the program's name in a device trace and in the program table
         program.__name__ = "fused_step_" + "_".join(
             sorted({l.spec.name for l in lanes}))
-        # masters or flats, and states; never gradients or (on the
-        # leaves' boundary) user-visible weights: _leaf_args
-        kw = {"donate_argnums":
-              ((0, 3) if leaves else (0, 2)) if donate else ()}
+        # donated: masters or flats, and states; never gradients or
+        # (on the leaves' boundary) user-visible weights: _leaf_args
+        kw = {"donate_argnums": (0, 3) if leaves else (0, 2)}
         if leaves:
             devices = lanes[0].group[0].pack_w.devices()
             if len(devices) == 1:
@@ -633,7 +635,7 @@ class FusedTrainStep:
                                    state_out, rep)
         jitted = jax.jit(program, **kw)
         fn = self._aot_or_jit(sig, jitted, args, nproc, zero1,
-                              guard, donate, lanes)
+                              guard, lanes)
         if len(self._programs) > 64:
             # membership/cohort churn: same bound as the layout-plan
             # and refusal caches — steady-state training holds one
@@ -643,7 +645,7 @@ class FusedTrainStep:
         return fn
 
     def _aot_or_jit(self, sig, jitted, args, nproc, zero1, guard,
-                    donate, lanes):
+                    lanes):
         """Try the PR-11 artifact store for this program signature;
         fall back to (and optionally export from) the jit.
         Multi-process (process-spanning mesh) programs never touch the
@@ -663,7 +665,7 @@ class FusedTrainStep:
             # re-fingerprints -> counted fallback, never a stale load
             "plan": self._updater._layout.plan_signature(
                 [l.bucket for l in lanes]),
-            "zero1": zero1, "guard": guard, "donate": donate,
+            "zero1": zero1, "guard": guard,
             # the avals of the program's own arguments (leaves or
             # flats): an artifact built for the other boundary misses
             "args": _aot.aval_signature(args),
@@ -756,11 +758,14 @@ class FusedTrainStep:
 def _exchange_plan(kvstore):
     """(nproc, mesh) for the in-program gradient exchange, or
     (None, None) when the kvstore's semantics cannot be fused (a
-    compressing store, an exotic type)."""
+    compressing store, one that applies the optimizer itself, an
+    exotic type)."""
     if kvstore is None:
         return 1, None
     if getattr(kvstore, "_compression", None) is not None:
         return None, None     # compressed exchange: staged path
+    if getattr(kvstore, "_updater", None) is not None:
+        return None, None     # update_on_kvstore: a push IS the update
     from .kvstore_dist import DistKVStore
     if isinstance(kvstore, DistKVStore):
         if kvstore.num_workers <= 1:
@@ -773,33 +778,17 @@ def _exchange_plan(kvstore):
     return None, None
 
 
-def eligible(updater, indices, kvstore=None):
-    """Cheap, side-effect-free pre-check for the fused step: the
-    latched/static refusals (env gate, updater type, optimizer class,
-    exchange topology, a previously refused key set). Callers use it
-    to avoid opening telemetry phases / trace spans for runs that are
-    permanently staged; `run()` still re-checks everything."""
-    if not enabled():
-        return False
-    from .fused_update import FusedUpdater, _SUPPORTED
+def step(updater, indices, grads, weights, kvstore=None,
+         ignore_stale_grad=False):
+    """Module/Trainer entry, and the one place the update path is
+    chosen: run the one-program exchange+update step over
+    (indices, grads, weights) when what the step observes allows it
+    (`FusedTrainStep._staged_by`, then what `_collect` finds in the key
+    set). Returns True when it ran; False sends the caller to its
+    staged path with nothing mutated."""
+    from .fused_update import FusedUpdater
     if not isinstance(updater, FusedUpdater):
-        return False
-    o = updater.optimizer
-    if _SUPPORTED.get(type(o)) is None or type(o) not in _STEP_OPTS:
-        return False
-    step = getattr(updater, "_fused_step_owner", None)
-    if step is not None and (type(o), tuple(indices)) in step._refused:
-        return False
-    return _exchange_plan(kvstore)[0] is not None
-
-
-def try_step(updater, indices, grads, weights, kvstore=None):
-    """Module/Trainer entry: run the fused one-program step when the
-    updater supports it. Returns True when it ran."""
-    step = getattr(updater, "_fused_step_owner", None)
-    if step is None:
-        try:
-            step = FusedTrainStep(updater)
-        except TypeError:
-            return False
-    return step.run(indices, grads, weights, kvstore=kvstore)
+        return False        # a plain optimizer.Updater, a user's closure
+    owner = updater._fused_step_owner or FusedTrainStep(updater)
+    return owner.run(indices, grads, weights, kvstore=kvstore,
+                     ignore_stale_grad=ignore_stale_grad)

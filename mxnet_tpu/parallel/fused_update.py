@@ -22,24 +22,33 @@ back into contiguous flats without a host round-trip — and runs ONE
 state buffers: XLA writes the new values into the donated storage, so
 a steady-state step allocates no fresh weight/state buffers.
 
-Bit parity: every fused kernel repeats the *exact* elementwise
+Parity: every fused kernel repeats the *exact* elementwise
 expressions of the per-parameter path in optimizer.py (same `_prep`,
-same operand order). Elementwise float ops are IEEE-deterministic per
-element, so fused and per-parameter updates are bit-identical
-(asserted in tests/test_fused_update.py).
+same operand order). Add, multiply, divide and sqrt are
+IEEE-deterministic per element, so for SGD, Adam and AdaGrad fused and
+per-parameter updates are bit-identical. RMSProp divides by a square
+root, which XLA turns into an rsqrt that XLA:CPU only approximates
+(optimizer.py, above `_adagrad_math`): there the two agree to one ulp
+of the quotient a step, and bit for bit wherever the loops XLA emits
+have one shape. Both are asserted in tests/test_fused_update.py.
 
-Fallbacks (always bit-exact, per-key):
-- ``MXTPU_FUSED_UPDATE=0`` (re-read per call),
+Grouped or per-key is chosen in `update_all` from the optimizer's
+class and the keys it is given, nothing else. Per-key (always
+bit-exact) are:
 - optimizer classes without a fused kernel (exact-type match: a
   subclass with its own `update` never rides a parent's kernel),
-- row-sparse grads/weights, multi-device grad lists, malformed states.
+- row-sparse grads/weights, multi-device grad lists, malformed states,
+- a call with a single key.
+The per-key reference for a whole set is the base class:
+`optimizer.Updater(o)` beside `optimizer.get_updater(o)` (how the
+tests compare the two).
 
-Donation caveat (docs/performance.md): a donated buffer's old
-`jax.Array` handle is invalidated. The framework's own aliases are
-re-pointed immediately after the call, but external code that captured
-a parameter's raw `.asjax()` array before a step must not read it
-after; set ``MXTPU_DONATE_UPDATE=0`` to keep the old allocate-and-swap
-behavior.
+Donation caveat (docs/performance.md): the update jits always donate
+the weight and state buffers, and a donated buffer's old `jax.Array`
+handle is invalidated. The framework's own aliases are re-pointed
+immediately after the call, but external code that captured a
+parameter's raw `.asjax()` array before a step must not read it after
+(copy it first: `jnp.array(x)`, `.asnumpy()`).
 """
 from __future__ import annotations
 
@@ -48,7 +57,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from ..base import getenv
 from ..compile import aot as _aot
 from ..compile.programs import scope as _scope
 from ..ndarray import NDArray
@@ -59,8 +67,7 @@ from ..resilience import numerics as _num
 from ..resilience.chaos import corrupt_point
 from .bucketing import GradBucketer
 
-__all__ = ["FusedUpdater", "fused_enabled", "donate_enabled",
-           "update_cost"]
+__all__ = ["FusedUpdater", "update_cost"]
 
 # effectively unbounded bucket target: one fusion buffer per group lane
 _NO_LIMIT = 1 << 62
@@ -74,18 +81,6 @@ FUSED_PACK_SECONDS = _obs.histogram(
 FUSED_UPDATE_SECONDS = _obs.histogram(
     "optimizer.fused.update.seconds",
     "Wall time dispatching one fused group update (async dispatch)")
-
-
-def fused_enabled():
-    """MXTPU_FUSED_UPDATE gate, re-read per call so tests/jobs can
-    toggle without re-importing; default on."""
-    return getenv("MXTPU_FUSED_UPDATE", True)
-
-
-def donate_enabled():
-    """MXTPU_DONATE_UPDATE gate for buffer donation on the fused jits —
-    the SAME re-read-per-call flag the per-op kernels honor."""
-    return opt.donate_update_enabled()
 
 
 # ---------------------------------------------------------------------------
@@ -123,7 +118,8 @@ def _adam_fused(w, g, states, lr, t, wd, hyper):
 
 # RMSProp/AdaGrad reuse the exact math function the per-key jitted
 # kernels wrap (optimizer._rmsprop_math/_adagrad_math): identical
-# source function → identical jaxpr → bit-identical results.
+# source function → identical jaxpr (how close that brings the bits:
+# the module docstring).
 _rmsprop_fused = opt._rmsprop_math
 _adagrad_fused = opt._adagrad_math
 
@@ -194,7 +190,7 @@ def _guard_wrap(fn):
     return guarded
 
 
-def _jit_for(spec, donate, guarded=None):
+def _jit_for(spec, guarded=None):
     """The jitted fused kernel for one optimizer class. jax.jit's own
     cache handles per-(shape, static-hyper) specialization; donation
     covers the weight flat (0) and every state flat (2). `guarded`
@@ -202,7 +198,7 @@ def _jit_for(spec, donate, guarded=None):
     re-read per call)."""
     if guarded is None:
         guarded = _num.enabled()
-    key = (spec.name, bool(donate), bool(guarded))
+    key = (spec.name, bool(guarded))
     fn = _JITS.get(key)
     if fn is None:
         from ..compile.cache import enable_cache
@@ -216,15 +212,14 @@ def _jit_for(spec, donate, guarded=None):
 
         kernel.__name__ = "fused_update_" + spec.name
         fn = _JITS[key] = jax.jit(
-            kernel, static_argnums=(5, 6),
-            donate_argnums=(0, 2) if donate else ())
+            kernel, static_argnums=(5, 6), donate_argnums=(0, 2))
     return fn
 
 
 # -- ahead-of-time fused kernels (docs/compilation.md) ----------------------
 # The fused-update program set is fixed once the model and optimizer
-# are: one kernel per (optimizer class, guard, donation, group layout,
-# static hypers). With MXTPU_AOT_STORE set, each group signature tries
+# are: one kernel per (optimizer class, guard, group layout, static
+# hypers). With MXTPU_AOT_STORE set, each group signature tries
 # its serialized executable first; with MXTPU_AOT_EXPORT=1 a miss is
 # compiled ahead of time (`jit.lower().compile()`) and captured into
 # the store — how `tools/aot_build.py --train` harvests kernels whose
@@ -232,16 +227,16 @@ def _jit_for(spec, donate, guarded=None):
 _AOT = {}    # signature -> loaded executable, or False (known miss)
 
 
-def _aot_sig(spec, donate, guarded, w_flat, g_flat, state_flats, wd,
-             hyper, layout=None):
-    return (spec.name, bool(donate), bool(guarded),
+def _aot_sig(spec, guarded, w_flat, g_flat, state_flats, wd, hyper,
+             layout=None):
+    return (spec.name, bool(guarded),
             tuple(w_flat.shape), str(w_flat.dtype), str(g_flat.dtype),
             tuple((tuple(s.shape), str(s.dtype)) for s in state_flats),
             wd, hyper, layout)
 
 
-def _aot_kernel(spec, donate, guarded, w_flat, g_flat, state_flats,
-                wd, hyper, layout=None):
+def _aot_kernel(spec, guarded, w_flat, g_flat, state_flats, wd, hyper,
+                layout=None):
     """The AOT executable for one group signature, or None (JIT path).
     lr/t stay traced inputs (they change per step); wd/hyper are baked
     into the exported closure exactly as static_argnums bakes them into
@@ -253,8 +248,8 @@ def _aot_kernel(spec, donate, guarded, w_flat, g_flat, state_flats,
     store = _aot.default_store()
     if store is None:
         return None
-    sig = _aot_sig(spec, donate, guarded, w_flat, g_flat, state_flats,
-                   wd, hyper, layout)
+    sig = _aot_sig(spec, guarded, w_flat, g_flat, state_flats, wd,
+                   hyper, layout)
     cached = _AOT.get(sig)
     if cached is not None:
         return cached or None
@@ -265,7 +260,7 @@ def _aot_kernel(spec, donate, guarded, w_flat, g_flat, state_flats,
              jax.ShapeDtypeStruct((), jnp.float32),
              jax.ShapeDtypeStruct((), jnp.int32))
     extra = {"kind": "fused_update", "spec": spec.name,
-             "donate": bool(donate), "guarded": bool(guarded),
+             "guarded": bool(guarded),
              "wd": wd, "hyper": hyper, "layout": layout,
              "args": _aot.aval_signature(avals)}
     name = "fused/%s/%s" % (spec.name, _aot.fingerprint(extra)[:16])
@@ -277,8 +272,7 @@ def _aot_kernel(spec, donate, guarded, w_flat, g_flat, state_flats,
             return body(w, g, states, lr, t, wd, hyper)
 
         try:
-            jitted = jax.jit(kernel,
-                             donate_argnums=(0, 2) if donate else ())
+            jitted = jax.jit(kernel, donate_argnums=(0, 2))
             fn = _aot.compile_fresh(jitted, avals)
             store.put(name, _aot.fingerprint(extra), fn)
         except Exception:  # noqa: BLE001 — capture is best-effort
@@ -444,7 +438,7 @@ class FusedUpdater(opt.Updater):
         # as sharded flats: re-materialize before any per-key use
         self._flush_fused_step()
         spec = _SUPPORTED.get(type(self.optimizer))
-        if spec is None or not fused_enabled() or len(indices) < 2:
+        if spec is None or len(indices) < 2:
             super().update_all(indices, grads, weights)
             return
         entries, leftovers = self._collect(spec, indices, grads, weights)
@@ -457,9 +451,8 @@ class FusedUpdater(opt.Updater):
         # they must NOT be rerouted through per-key __call__ (update()
         # would bump the count again). A 1-entry group still runs the
         # fused kernel — same math, one dispatch.
-        donate = donate_enabled()
         for bucket, group, t, _lr, _wd in self._plan_cohorts(entries):
-            self._run_group(spec, bucket, group, t, donate)
+            self._run_group(spec, bucket, group, t)
         for i, g, w in leftovers:
             self(i, g, w)
 
@@ -480,10 +473,8 @@ class FusedUpdater(opt.Updater):
         if len(self._layout._plans) > 64:
             # membership churn (a trainable subset that varies per
             # step) would grow the memoized layouts without bound;
-            # steady-state training holds exactly one plan. Each new
-            # membership still costs an XLA retrace — models with
-            # per-step subsets should run MXTPU_FUSED_UPDATE=0
-            # (docs/performance.md).
+            # steady-state training holds exactly one plan (each new
+            # membership still costs an XLA retrace)
             self._layout.clear()
         for (t, lr, wd), cohort in sorted(by_cohort.items()):
             items = tuple(
@@ -511,7 +502,7 @@ class FusedUpdater(opt.Updater):
             self._fused_step_owner.drop_state()
         super().set_states(states)
 
-    def _run_group(self, spec, bucket, group, t, donate):
+    def _run_group(self, spec, bucket, group, t):
         o = self.optimizer
         n_states = spec.n_states(o)
         t0 = time.perf_counter()
@@ -539,8 +530,8 @@ class FusedUpdater(opt.Updater):
         # repr+sha256 walk is wasted work on the storeless hot path
         layout = self._layout.plan_signature([bucket]) \
             if _aot.default_store() is not None else None
-        aot_fn = _aot_kernel(spec, donate, guarded, w_flat, g_flat,
-                             state_flats, wd, hyper, layout)
+        aot_fn = _aot_kernel(spec, guarded, w_flat, g_flat, state_flats,
+                             wd, hyper, layout)
         if aot_fn is not None:
             try:
                 out = aot_fn(w_flat, g_flat, state_flats,
@@ -552,19 +543,19 @@ class FusedUpdater(opt.Updater):
                 # executable every step) and take the JIT path. The
                 # sig is rebuilt HERE, not on the hot path — failure
                 # is the rare case
-                _AOT[_aot_sig(spec, donate, guarded, w_flat, g_flat,
-                              state_flats, wd, hyper, layout)] = False
+                _AOT[_aot_sig(spec, guarded, w_flat, g_flat, state_flats,
+                              wd, hyper, layout)] = False
                 _aot.FALLBACKS.inc(reason="dispatch")
             except Exception:
                 # a failure DURING execution may have consumed the
                 # donated weight/state flats — re-dispatching them
                 # would corrupt the update; latch and surface
-                _AOT[_aot_sig(spec, donate, guarded, w_flat, g_flat,
-                              state_flats, wd, hyper, layout)] = False
+                _AOT[_aot_sig(spec, guarded, w_flat, g_flat, state_flats,
+                              wd, hyper, layout)] = False
                 _aot.FALLBACKS.inc(reason="dispatch")
                 raise
         if out is None:
-            out = _jit_for(spec, donate, guarded)(
+            out = _jit_for(spec, guarded)(
                 w_flat, g_flat, state_flats, lr, t, wd, hyper)
         if guarded:
             new_w, new_states, ok = out

@@ -227,7 +227,8 @@ def main():
     from mxnet_tpu.observability import registry as obs
 
     def _train(fused, zero1, tag):
-        os.environ["MXTPU_FUSED_STEP"] = "1" if fused else "0"
+        # the staged side is the pair of halves that always stage:
+        # allreduce_grads() (the bucketed exchange) + update()
         os.environ["MXTPU_ZERO1"] = "1" if zero1 else "0"
         mx.random.seed(7)
         net = gluon.nn.Dense(5, prefix="z1%s_" % tag)
@@ -247,7 +248,11 @@ def main():
             with autograd.record():
                 loss = loss_fn(net(x), y)
             loss.backward()
-            tr.step(2 * nw)
+            if fused:
+                tr.step(2 * nw)
+            else:
+                tr.allreduce_grads()
+                tr.update(2 * nw)
         params = [p.data().asnumpy()
                   for p in net.collect_params().values()]
         states = tr._updaters[0].get_states()
@@ -272,7 +277,6 @@ def main():
     # at the knob boundary (full-signature keyed), never feed a
     # replicated program — and numerics stay bit-exact
     def _train_toggle(tag):
-        os.environ["MXTPU_FUSED_STEP"] = "1"
         mx.random.seed(7)
         net = gluon.nn.Dense(5, prefix="z1%s_" % tag)
         net.initialize()
@@ -301,7 +305,6 @@ def main():
     # a 4th reference step; instead compare against a fresh 4-step
     # replicated run
     def _train4(tag):
-        os.environ["MXTPU_FUSED_STEP"] = "1"
         mx.random.seed(7)
         net = gluon.nn.Dense(5, prefix="z1%s_" % tag)
         net.initialize()

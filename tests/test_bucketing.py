@@ -239,14 +239,14 @@ def test_dist_push_all_uninitialized_key_raises(monkeypatch):
 
 def test_trainer_step_uses_batched_exchange(monkeypatch):
     """gluon Trainer's STAGED reduce routes through push_all/pull_all
-    (the fused one-program step subsumes the kvstore hop entirely —
-    pinned off here; tests/test_fused_step.py covers that path)."""
-    monkeypatch.setenv("MXTPU_FUSED_STEP", "0")
+    (the fused one-program step subsumes the kvstore hop entirely;
+    tests/test_fused_step.py covers that path). RMSProp is outside the
+    one-program step's optimizers, so `step()` itself stages."""
     from mxnet_tpu import gluon, autograd
     from mxnet_tpu.gluon import nn
     net = nn.Dense(3, in_units=4)
     net.initialize()
-    trainer = gluon.Trainer(net.collect_params(), "sgd",
+    trainer = gluon.Trainer(net.collect_params(), "rmsprop",
                             {"learning_rate": 0.1}, kvstore="device")
     trainer._ensure_ready()
     pushed = {}

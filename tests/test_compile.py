@@ -524,7 +524,7 @@ else:
 # fused-update AOT capture/replay
 # ---------------------------------------------------------------------------
 class TestFusedUpdateAOT:
-    def _train(self, seed=0, steps=3):
+    def _train(self, seed=0, steps=3, staged=False):
         from mxnet_tpu import autograd, gluon
         from mxnet_tpu.gluon import nn
         np.random.seed(seed)
@@ -543,7 +543,11 @@ class TestFusedUpdateAOT:
             with autograd.record():
                 loss = loss_fn(net(x), y)
             loss.backward()
-            tr.step(8)
+            if staged:      # the halves that always stage
+                tr.allreduce_grads()
+                tr.update(8)
+            else:
+                tr.step(8)
         return {k: p.data().asnumpy()
                 for k, p in net.collect_params().items()}
 
@@ -558,16 +562,14 @@ class TestFusedUpdateAOT:
             # fused-step era (ISSUE 15): the Trainer loop dispatches
             # ONE exchange+update program per step, so the capture
             # harvests a fused_step/ executable; the staged kernels
-            # are captured under the MXTPU_FUSED_STEP=0 escape hatch
+            # are captured where the staged halves run
             captured = self._train()              # capture pass
             store = ArtifactStore(tmp_path)
             assert any(n.startswith("fused_step/")
                        for n in store.entries())
-            monkeypatch.setenv("MXTPU_FUSED_STEP", "0")
-            staged = self._train()                # staged capture pass
+            staged = self._train(staged=True)     # staged capture pass
             assert any(n.startswith("fused/adam/")
                        for n in store.entries())
-            monkeypatch.delenv("MXTPU_FUSED_STEP")
             fused_update._AOT.clear()             # force a re-load
             monkeypatch.setenv("MXTPU_AOT_EXPORT", "0")
             loads_before = _total("compile.aot.loads")

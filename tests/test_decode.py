@@ -14,6 +14,7 @@ import os
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -69,7 +70,8 @@ def test_gpt_jax_forward_matches_block():
     blk = make_block()
     toks = np.random.RandomState(1).randint(0, VOCAB, size=(2, 7))
     want = blk(mx.nd.array(toks.astype(np.int32))).asnumpy()
-    got = np.asarray(blk.forward_fn()(
+    # jitted, as the engines run it: op by op it compiles every call
+    got = np.asarray(jax.jit(blk.forward_fn())(
         blk.decode_params(), toks.astype(np.int32)))
     assert np.allclose(want, got, atol=1e-5)
 
@@ -97,7 +99,6 @@ def test_gpt_eager_step_api():
 # DecodeEngine: token identity + the exactly-two-programs invariant
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow      # ~200 s alone on the CPU: eager greedy oracle
 def test_engine_prefill_step_token_identity():
     blk = make_block()
     eng = DecodeEngine(blk, max_slots=1, name="ti")
@@ -138,9 +139,6 @@ def test_exactly_two_decode_programs():
     assert counter.get(engine="two", kind="prefill") == progs["prefill"]
 
 
-# 131-862 s in tier-1 runs (ninety eager reference forwards);
-# test_staggered_joins_stay_token_identical keeps the oracle in tier-1
-@pytest.mark.slow
 def test_continuous_batching_token_identity_with_joins():
     """More sequences than slots, random lengths: late sequences join
     mid-batch into freed slots, and every one of them still decodes

@@ -7,9 +7,8 @@ The contract under test:
 1. bit parity: the fused one-program step behind gluon.Trainer /
    Module update produces byte-identical weights AND optimizer state
    vs the staged bucketed path (exchange then update) — SGD, momentum,
-   Adam, fp16-under-fp32-master multi-precision — with the
-   MXTPU_FUSED_STEP=0 and MXTPU_ZERO1=0 escape hatches exercised both
-   ways;
+   Adam, fp16-under-fp32-master multi-precision. The staged side is
+   reached through the API: `allreduce_grads()` + `update()`;
 2. dispatch count: the fused path issues exactly ONE device program
    per step (train.step.dispatches metric + program-cache census),
    the staged path O(buckets)+O(groups);
@@ -45,17 +44,20 @@ from mxnet_tpu.resilience import chaos
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.fixture
-def step_env(monkeypatch):
-    def set_fused(on, zero1=False):
-        monkeypatch.setenv("MXTPU_FUSED_STEP", "1" if on else "0")
-        monkeypatch.setenv("MXTPU_ZERO1", "1" if zero1 else "0")
-    yield set_fused
+def _update(tr, batch_size, staged=False):
+    """One update through `step()`, or through the staged halves that
+    `allreduce_grads()` + `update()` always run."""
+    if staged:
+        tr.allreduce_grads()
+        tr.update(batch_size)
+    else:
+        tr.step(batch_size)
 
 
-def _train_gluon(optname, optkw, steps=4, dtype="float32", seed=0):
+def _train_gluon(optname, optkw, steps=4, dtype="float32", seed=0,
+                 staged=False):
     """A tiny gluon loop: returns (param arrays, pickled updater
-    states) after `steps` autograd+Trainer.step iterations."""
+    states) after `steps` iterations of autograd + `_update`."""
     mx.random.seed(seed)
     net = gluon.nn.Sequential()
     net.add(gluon.nn.Dense(8, activation="relu"), gluon.nn.Dense(3))
@@ -76,7 +78,7 @@ def _train_gluon(optname, optkw, steps=4, dtype="float32", seed=0):
         with autograd.record():
             loss = loss_fn(net(x), y)
         loss.backward()
-        tr.step(4)
+        _update(tr, 4, staged)
     params = [p.data().asnumpy() for p in net.collect_params().values()]
     states = pickle.loads(tr._updaters[0].get_states())
     return params, states, tr
@@ -107,20 +109,17 @@ def _state_bytes(states):
     ("adam", dict(learning_rate=0.01,
                   multi_precision=True), "float16"),
 ])
-def test_fused_step_bit_parity(name, kw, dtype, step_env):
-    step_env(True)
+def test_fused_step_bit_parity(name, kw, dtype):
     a_p, a_s, _ = _train_gluon(name, kw, dtype=dtype)
-    step_env(False)
-    b_p, b_s, _ = _train_gluon(name, kw, dtype=dtype)
+    b_p, b_s, _ = _train_gluon(name, kw, dtype=dtype, staged=True)
     for a, b in zip(a_p, b_p):
         assert a.dtype == b.dtype
         assert a.tobytes() == b.tobytes()
     assert _state_bytes(a_s) == _state_bytes(b_s)
 
 
-def test_fused_step_one_dispatch_per_step(step_env):
+def test_fused_step_one_dispatch_per_step():
     disp = obs.REGISTRY.counter("train.step.dispatches")
-    step_env(True)
     d0 = disp.total()
     _, _, tr = _train_gluon("sgd", dict(learning_rate=0.1,
                                         momentum=0.9), steps=5)
@@ -131,18 +130,16 @@ def test_fused_step_one_dispatch_per_step(step_env):
     assert owner is not None and owner.program_count() == 1
     # staged path: O(groups) per step (two lanes here: weight wd_mult
     # lane + bias lane collapse into one fp32 bucket per cohort)
-    step_env(False)
     d0 = disp.total()
-    _train_gluon("sgd", dict(learning_rate=0.1, momentum=0.9), steps=5)
+    _train_gluon("sgd", dict(learning_rate=0.1, momentum=0.9), steps=5,
+                 staged=True)
     staged = disp.total() - d0
     assert staged >= 5                     # at least one per step
 
 
-def test_fused_step_telemetry_record_and_phase(step_env, tmp_path,
-                                               monkeypatch):
+def test_fused_step_telemetry_record_and_phase(tmp_path, monkeypatch):
     tel = tmp_path / "t.jsonl"
     monkeypatch.setenv("MXTPU_TELEMETRY", str(tel))
-    step_env(True)
     _train_gluon("sgd", dict(learning_rate=0.1), steps=3)
     from mxnet_tpu.observability.telemetry import close_stream
     close_stream()
@@ -159,10 +156,9 @@ def test_fused_step_telemetry_record_and_phase(step_env, tmp_path,
         assert "allreduce_time" not in r and "optimizer_time" not in r
 
 
-def test_perf_gate_dispatch_budget(step_env, tmp_path, monkeypatch):
+def test_perf_gate_dispatch_budget(tmp_path, monkeypatch):
     tel = tmp_path / "t.jsonl"
     monkeypatch.setenv("MXTPU_TELEMETRY", str(tel))
-    step_env(True)
     _train_gluon("adam", dict(learning_rate=0.01), steps=3)
     from mxnet_tpu.observability.telemetry import close_stream
     close_stream()
@@ -188,11 +184,10 @@ def test_perf_gate_dispatch_budget(step_env, tmp_path, monkeypatch):
     assert r.returncode == 1
 
 
-def test_guard_composition_chaos_nan(step_env):
+def test_guard_composition_chaos_nan():
     """kind=nan at grad.post INSIDE the fused step: the lax.cond skip
     preserves weights + opt state bit-identically and the verdict
     reaches the watchdog/telemetry exactly once."""
-    step_env(True)
     mx.random.seed(0)
     net = gluon.nn.Dense(3)
     net.initialize()
@@ -237,10 +232,10 @@ def test_guard_composition_chaos_nan(step_env):
     assert tr.numerics.watchdog.bad_streak == 0
 
 
-def test_escape_hatch_mid_run(step_env):
-    """Toggling MXTPU_FUSED_STEP mid-run keeps training exact: the
-    fused and staged paths share updater state."""
-    step_env(True)
+def test_step_and_staged_halves_alternate_mid_run():
+    """`step()` and `allreduce_grads()` + `update()` alternating mid-run
+    keep training exact: the fused and staged paths share updater
+    state."""
     mx.random.seed(3)
     net = gluon.nn.Dense(4)
     net.initialize()
@@ -251,20 +246,20 @@ def test_escape_hatch_mid_run(step_env):
                        {"learning_rate": 0.05, "momentum": 0.9})
     loss_fn = gluon.loss.L2Loss()
 
-    def steps(n):
+    def steps(n, staged):
         for _ in range(n):
             with autograd.record():
                 loss = loss_fn(net(x), y)
             loss.backward()
-            tr.step(4)
+            _update(tr, 4, staged)
 
-    steps(2)
-    step_env(False)
-    steps(2)
-    step_env(True)
-    steps(2)
+    path = obs.REGISTRY.get("train.step.fused_path")
+    fused0 = path.total()
+    steps(2, staged=False)
+    steps(2, staged=True)
+    steps(2, staged=False)
+    assert path.total() - fused0 == 4
     mixed = [p.data().asnumpy() for p in net.collect_params().values()]
-    step_env(False)
     b_p, _, _ = _train_gluon_fixed_dense(net_seed=3, steps=6)
     for a, b in zip(mixed, b_p):
         assert a.tobytes() == b.tobytes()
@@ -284,14 +279,20 @@ def _train_gluon_fixed_dense(net_seed, steps):
         with autograd.record():
             loss = loss_fn(net(x), y)
         loss.backward()
-        tr.step(4)
+        _update(tr, 4, staged=True)
     return ([p.data().asnumpy() for p in net.collect_params().values()],
             None, tr)
 
 
-def test_module_fit_fused_parity(step_env):
+def test_module_fit_fused_parity(monkeypatch):
+    path = obs.REGISTRY.get("train.step.fused_path")
+
     def fit(fused):
-        step_env(fused)
+        # the reference: `Module.update` with the per-key
+        # `optimizer.Updater`, which `fused_step.step` leaves to the
+        # staged push/pull + update path
+        if not fused:
+            monkeypatch.setattr(opt, "get_updater", opt.Updater)
         mx.random.seed(0)
         np.random.seed(0)
         data = mx.sym.var("data")
@@ -311,16 +312,18 @@ def test_module_fit_fused_parity(step_env):
         args, _ = mod.get_params()
         return {k: v.asnumpy() for k, v in args.items()}
 
+    n0 = path.total()
     a = fit(True)
+    n1 = path.total()
     b = fit(False)
+    assert n1 - n0 == 4 and path.total() == n1     # 2 epochs x 2 batches
     for k in sorted(a):
         assert a[k].tobytes() == b[k].tobytes(), k
 
 
-def test_staged_oracle_unused_paths_intact(step_env):
-    """allreduce_grads()/update() keep the staged halves regardless of
-    the fused-step default (facade contract)."""
-    step_env(True)
+def test_staged_oracle_unused_paths_intact():
+    """allreduce_grads()/update() keep the staged halves (facade
+    contract)."""
     mx.random.seed(1)
     net = gluon.nn.Dense(2)
     net.initialize()
@@ -335,6 +338,105 @@ def test_staged_oracle_unused_paths_intact(step_env):
     tr.update(2)
     # params moved; no fused program was built for these facades
     assert tr._updaters[0]._fused_step_owner is None
+
+
+# -- the decision: which path a step takes, from what it observes ----------
+
+def _two_process_store(monkeypatch):
+    """A `DistKVStore` that believes it has a peer: the cross-process
+    sum is replaced by the identity (test_bucketing's stand-in)."""
+    from mxnet_tpu.parallel.kvstore_dist import DistKVStore
+    kv = DistKVStore("dist_sync")   # single process: init is a no-op
+    kv._nproc = 2
+    monkeypatch.setattr(kv, "_cross_process_sum", lambda x: x)
+    return kv
+
+
+# (optimizer, Trainer arguments, step arguments) -> the path `step()`
+# took, read from the counters. Adam on the single-worker `device`
+# store -> fused is `test_fused_step_leaves_alive_and_path_counter`'s.
+_DECISIONS = {
+    "sgd-no-store": ("sgd", dict(kvstore=None), {}, "fused"),
+    "rmsprop": ("rmsprop", {}, {}, "grouped"),
+    "adagrad": ("adagrad", {}, {}, "grouped"),
+    "no-fused-kernel": ("nag", {}, {}, "per-key"),
+    "update-on-kvstore": ("sgd", dict(update_on_kvstore=True), {},
+                          "on the store"),
+    "compressing-store": ("sgd", dict(compression_params={
+        "type": "2bit", "threshold": 0.5}), {}, "grouped"),
+    "two-processes-ignore-stale": (
+        "sgd", dict(kvstore=_two_process_store),
+        dict(ignore_stale_grad=True), "grouped"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DECISIONS))
+def test_step_decides_the_path_from_what_it_observes(case, monkeypatch):
+    optname, trainer_kw, step_kw, want = _DECISIONS[case]
+    trainer_kw = dict(trainer_kw)
+    if callable(trainer_kw.get("kvstore")):
+        trainer_kw["kvstore"] = trainer_kw["kvstore"](monkeypatch)
+    mx.random.seed(0)
+    net = gluon.nn.Dense(3)
+    net.initialize()
+    x = mx.nd.array(np.random.RandomState(1).randn(4, 5).astype("f"))
+    net(x)
+    tr = gluon.Trainer(net.collect_params(), optname,
+                       {"learning_rate": 0.01}, **trainer_kw)
+    fused = obs.REGISTRY.get("train.step.fused_path")
+    grouped = obs.REGISTRY.get("optimizer.fused.groups")
+    per_key = obs.REGISTRY.get("optimizer.update.dispatches")
+    programs = obs.REGISTRY.get("train.step.dispatches")
+    before = [c.total() for c in (fused, grouped, per_key, programs)]
+    n_steps, n_params = 2, len(net.collect_params())
+    for _ in range(n_steps):
+        with autograd.record():
+            loss = net(x).sum()
+        loss.backward()
+        tr.step(4, **step_kw)
+    d_fused, d_grouped, d_updates, d_programs = (
+        c.total() - b for c, b in
+        zip((fused, grouped, per_key, programs), before))
+    if want == "fused":
+        assert (d_fused, d_grouped, d_programs) == (n_steps, 0, n_steps)
+    elif want == "grouped":     # the staged exchange + grouped update
+        assert d_fused == 0 and d_grouped == d_updates >= n_steps
+    elif want == "per-key":
+        assert (d_fused, d_grouped) == (0, 0)
+        assert d_updates == n_steps * n_params
+    else:                       # the store's own updater applied it
+        assert d_fused == 0 and d_updates >= n_steps
+        assert tr._updaters[0].states == {}
+
+
+def test_row_sparse_key_stages_once_then_is_latched(monkeypatch):
+    """A key set with a row-sparse gradient is found out by `_collect`
+    once (nothing mutated), and from then on refused by the rule
+    before anything is collected."""
+    upd = opt.get_updater(opt.create("sgd", learning_rate=0.1,
+                                     momentum=0.9))
+    ws = [mx.nd.array(np.ones((4, 3), "f")), mx.nd.array(np.ones(5, "f"))]
+    gs = [mx.nd.NDArray(mx.nd.array(np.ones((4, 3), "f"))._data,
+                        _stype="row_sparse"),
+          mx.nd.array(np.ones(5, "f"))]
+    probes = []
+    collect = upd._collect
+
+    def spy(*args, **kw):
+        probes.append(kw.get("require_all", False))
+        return collect(*args, **kw)
+
+    monkeypatch.setattr(upd, "_collect", spy)
+    fused = obs.REGISTRY.get("train.step.fused_path")
+    f0 = fused.total()
+    for _ in range(3):
+        assert not fs.step(upd, [0, 1], gs, ws)
+    assert probes == [True]                 # collected once, latched
+    assert fused.total() == f0
+    assert upd.optimizer._index_update_count == {}      # not mutated
+    # another key set of the same updater is judged on its own
+    assert fs.step(upd, [1], gs[1:], ws[1:])
+    assert fused.total() == f0 + 1
 
 
 # -- the program's boundary: leaves in, leaves out --------------------------
@@ -404,13 +506,12 @@ def _executions(tmp_path, fn):
 
 
 @pytest.mark.parametrize("name,kw,dtype", _LEAF_CASES)
-def test_fused_step_is_one_program_no_eager_pack(name, kw, dtype, step_env,
+def test_fused_step_is_one_program_no_eager_pack(name, kw, dtype,
                                                  monkeypatch, tmp_path):
     """Steps 2-4: `Trainer.step` runs ONE compiled program and never
     calls `Bucket.pack` / `Bucket.unpack` (steady state retraces
     nothing, so any call would be an eager one)."""
     from mxnet_tpu.parallel.bucketing import Bucket
-    step_env(True)
     _net, tr, fwd_bwd = _hybrid_loop(name, kw, dtype)
     calls = []
     for meth in ("pack", "unpack"):
@@ -427,17 +528,14 @@ def test_fused_step_is_one_program_no_eager_pack(name, kw, dtype, step_env,
     assert tr._updaters[0]._fused_step_owner.program_count() == 1
 
 
-@pytest.mark.parametrize("donate", [True, False])
 @pytest.mark.parametrize("name,kw,dtype", _LEAF_CASES)
-def test_fused_step_leaves_alive_and_path_counter(name, kw, dtype, donate,
-                                                  step_env, monkeypatch,
+def test_fused_step_leaves_alive_and_path_counter(name, kw, dtype,
                                                   tmp_path):
     """After fused steps every weight, master and state NDArray holds a
-    live array of its own shape and dtype, whatever the donation, and
-    an NDArray that shared a weight's buffer (`detach()`) still reads:
-    weights are the one class of leaf the program never donates."""
-    step_env(True)
-    monkeypatch.setenv("MXTPU_DONATE_UPDATE", "1" if donate else "0")
+    live array of its own shape and dtype though masters and states are
+    donated, and an NDArray that shared a weight's buffer (`detach()`)
+    still reads: weights are the one class of leaf the program never
+    donates."""
     path = obs.REGISTRY.get("train.step.fused_path")
     net, tr, fwd_bwd = _hybrid_loop(name, kw, dtype)
     params = list(net.collect_params().values())
@@ -483,11 +581,10 @@ def test_fused_step_leaves_alive_and_path_counter(name, kw, dtype, donate,
     assert path.get(path="leaves") - leaves0 == 4
 
 
-def test_armed_corruption_site_takes_the_flat_boundary(step_env):
+def test_armed_corruption_site_takes_the_flat_boundary():
     """An armed `grad.post` / `weight.post` site must fire on the flat
     itself: while one is armed the step packs eagerly around its
     program, and goes back to the leaves when it is disarmed."""
-    step_env(True)
     path = obs.REGISTRY.get("train.step.fused_path")
     net, tr, fwd_bwd = _hybrid_loop(*_LEAF_CASES[0])
     pre = [p.data().asnumpy() for p in net.collect_params().values()]
@@ -511,9 +608,13 @@ def test_armed_corruption_site_takes_the_flat_boundary(step_env):
 
 # -- ZeRO-1 ---------------------------------------------------------------
 
-def test_zero1_env_defaults_sharded_trainer(step_env):
+def test_zero1_env_defaults_sharded_trainer(monkeypatch):
     from mxnet_tpu.parallel import make_mesh, ShardedTrainer
-    step_env(True, zero1=True)
+
+    def step_env(zero1):
+        monkeypatch.setenv("MXTPU_ZERO1", "1" if zero1 else "0")
+
+    step_env(zero1=True)
     mx.random.seed(0)
     net = gluon.nn.Dense(8)
     net.initialize()
@@ -524,14 +625,14 @@ def test_zero1_env_defaults_sharded_trainer(step_env):
     assert st._shard_opt
     g = obs.REGISTRY.get("zero1.shard_params")
     assert g is not None
-    step_env(True, zero1=False)
+    step_env(zero1=False)
     st2 = ShardedTrainer(net, lambda o, l: gluon.loss.L2Loss()(o, l),
                          "sgd", {"learning_rate": 0.1,
                                  "momentum": 0.9},
                          mesh=make_mesh({"dp": 8}))
     assert not st2._shard_opt
     # explicit bool wins over env
-    step_env(True, zero1=True)
+    step_env(zero1=True)
     st3 = ShardedTrainer(net, lambda o, l: gluon.loss.L2Loss()(o, l),
                          "sgd", {"learning_rate": 0.1,
                                  "momentum": 0.9},
@@ -635,8 +736,8 @@ def test_fused_update_aot_sig_covers_layout():
     spec = fu._SUPPORTED[type(o)]
     w = jnp.zeros((10,), jnp.float32)
     g = jnp.zeros((10,), jnp.float32)
-    s1 = fu._aot_sig(spec, True, True, w, g, (), 0.0, (1, None, 0.0),
+    s1 = fu._aot_sig(spec, True, w, g, (), 0.0, (1, None, 0.0),
                      layout="aaaa")
-    s2 = fu._aot_sig(spec, True, True, w, g, (), 0.0, (1, None, 0.0),
+    s2 = fu._aot_sig(spec, True, w, g, (), 0.0, (1, None, 0.0),
                      layout="bbbb")
     assert s1 != s2

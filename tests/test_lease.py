@@ -445,19 +445,43 @@ def _kill_stale(*args):
         + list(args), capture_output=True, text=True, timeout=120)
 
 
-def test_kill_stale_refuses_fresh_holder_even_forced(lease_path):
+def _kill_stale_seeing(pids, monkeypatch, *args):
+    """`tools/kill_stale.py` run in this process with /proc's listing
+    cut to `pids`; returns its exit code. Under --force the tool
+    signals EVERY python process whose command line names the
+    repository, and a suite under xdist is full of those (another
+    worker's beacon and sleeper children, the workers themselves once
+    they have mapped libtpu): a forced call may see only what its test
+    started. The calls without --force can reach an expired holder of
+    their own lease file and an accelerator-mapped process that idled
+    past the ten-minute grace, which no test leaves behind."""
+    spec = importlib.util.spec_from_file_location(
+        "kill_stale_seeing", os.path.join(ROOT, "tools", "kill_stale.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    listdir = os.listdir
+    with monkeypatch.context() as m:
+        m.setattr(tool.os, "listdir", lambda path=".": (
+            [str(p) for p in pids] if path == "/proc" else listdir(path)))
+        return tool.main(list(args))
+
+
+def test_kill_stale_refuses_fresh_holder_even_forced(lease_path,
+                                                     monkeypatch, capsys):
     holder = _sleeper()
     try:
         time.sleep(0.2)
         _write_lease(lease_path, _lease_record(holder.pid,
                                                takeover_s=600.0))
-        r = _kill_stale("--kill", "--force", "--lease-path", lease_path)
-        assert r.returncode == 2, r.stdout + r.stderr
-        assert "refused" in r.stdout
+        rc = _kill_stale_seeing([holder.pid], monkeypatch, "--kill",
+                                "--force", "--lease-path", lease_path)
+        out = capsys.readouterr().out
+        assert rc == 2, out
+        assert "refused" in out
         assert holder.poll() is None          # still alive
         assert os.path.exists(lease_path)     # lease intact
         # the old dead-end wording is gone for good
-        assert "holds the device lease?" not in r.stdout
+        assert "holds the device lease?" not in out
     finally:
         holder.kill()
         holder.wait()

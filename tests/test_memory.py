@@ -151,9 +151,8 @@ def test_decode_ledger_matches_device_bytes():
     assert by["decode/kv_cache"] > 0
 
 
-def test_trainer_params_registered(monkeypatch):
+def test_trainer_params_registered():
     from mxnet_tpu import autograd, gluon
-    monkeypatch.setenv("MXTPU_FUSED_STEP", "1")
     mx.random.seed(0)
     net = gluon.nn.Dense(3)
     net.initialize()
@@ -171,18 +170,17 @@ def test_trainer_params_registered(monkeypatch):
     assert by["trainer/params"] == want
 
 
-def test_zero1_state_cell_accounting(monkeypatch):
+def test_zero1_state_cell_accounting():
     """The carried-state accounting the fused step registers under
     trainer/optimizer/zero1_state: addressable-shard bytes only (the
     1/N per-replica share), released at the flush/drop boundaries."""
     import jax.numpy as jnp
     from mxnet_tpu.parallel import fused_step as fs
-    monkeypatch.setenv("MXTPU_FUSED_STEP", "1")
     upd = opt.get_updater(opt.create("sgd", learning_rate=0.1,
                                      momentum=0.9))
     ws = [mx.nd.array(np.zeros((4, 4), "f"))]
     gs = [mx.nd.array(np.ones((4, 4), "f"))]
-    assert fs.try_step(upd, [0], gs, ws)
+    assert fs.step(upd, [0], gs, ws)
     owner = upd._fused_step_owner
     # single-process runs carry no sharded flats; inject the shape the
     # multi-process zero1 path stores and check the byte accounting

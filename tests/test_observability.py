@@ -240,10 +240,10 @@ def test_steptimer_no_stream_still_returns_records():
 # ---------------------------------------------------------------------------
 # 5-step gluon training run end-to-end (the acceptance scenario)
 # ---------------------------------------------------------------------------
-def _run_gluon_steps(n_steps, batch_size=8):
+def _run_gluon_steps(n_steps, batch_size=8, optimizer="sgd"):
     net = nn.Dense(4, in_units=8)
     net.initialize()
-    trainer = gluon.Trainer(net.collect_params(), "sgd",
+    trainer = gluon.Trainer(net.collect_params(), optimizer,
                             {"learning_rate": 0.1})
     data = mx.io.NDArrayIter(
         np.random.RandomState(0).rand(n_steps * batch_size, 8)
@@ -264,8 +264,8 @@ def test_gluon_5step_jsonl_and_report(tmp_path, monkeypatch):
     # this test documents the STAGED trainer record shape (allreduce/
     # optimizer phases, kvstore bytes); the fused one-program step's
     # record (single "step.launch" phase, no kvstore hop) is covered in
-    # tests/test_fused_step.py
-    monkeypatch.setenv("MXTPU_FUSED_STEP", "0")
+    # tests/test_fused_step.py. RMSProp is outside the one-program
+    # step's optimizers, so `step()` itself stages
     # consume the once-per-process cold-start marker BEFORE the stream
     # opens: run solo, the first trainer step would otherwise publish
     # its source="compile" record into this strict 5-line assertion
@@ -276,7 +276,7 @@ def test_gluon_5step_jsonl_and_report(tmp_path, monkeypatch):
     from mxnet_tpu.observability import memory
     memory.release("trainer")
     monkeypatch.setenv("MXTPU_TELEMETRY", str(out))
-    _run_gluon_steps(5)
+    _run_gluon_steps(5, optimizer="rmsprop")
     close_stream()
     raw = [json.loads(l) for l in out.read_text().splitlines()]
     # the HBM ledger publishes ONE source="memory" timeline record when

@@ -510,7 +510,11 @@ def test_training_identical_loss_under_chaos(monkeypatch):
              chaos.trip_count("io.read"))
     assert trips > 0, "chaos must actually have fired for this to mean anything"
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(chaotic))
-    assert clean[-1] < clean[0]       # and training actually trains
+    # and training actually trains: an epoch is the same three batches,
+    # so epochs compare like with like (the last batch's loss against
+    # the first batch's hung on what the earlier tests drew from the
+    # global RNG before this net was initialized)
+    assert np.mean(clean[-3:]) < np.mean(clean[:3])
 
 
 # -- chaos_run harness -----------------------------------------------------

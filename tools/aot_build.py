@@ -87,44 +87,29 @@ def build_train(store, args):
     """Capture the training-step programs: run a few optimizer steps
     with the export env armed, so every program signature that fires
     compiles ahead of time into the store (the same mechanism a real
-    training job uses via MXTPU_AOT_STORE + MXTPU_AOT_EXPORT=1). With
-    the fused step default (docs/performance.md "Fused train step &
-    ZeRO-1") each step is ONE fused_step/ exchange+update program; a
-    second pass under MXTPU_FUSED_STEP=0 harvests the staged fused/
-    per-group kernels too, so a rollout can warm either path."""
-    import os
+    training job uses via MXTPU_AOT_STORE + MXTPU_AOT_EXPORT=1). What
+    is captured is what the trainer built here runs
+    (docs/performance.md "Fused train step & ZeRO-1"): ONE fused_step/
+    exchange+update program for SGD and Adam, the staged fused/
+    per-group kernels for `--optimizer rmsprop` or `adagrad`."""
     import numpy as np
     import mxnet_tpu as mx
     from mxnet_tpu import autograd, gluon
     from mxnet_tpu.gluon import nn
 
-    def loop():
-        net = nn.Dense(args.hidden, in_units=args.features)
-        net.initialize(mx.init.Xavier())
-        trainer = gluon.Trainer(net.collect_params(), args.optimizer,
-                                {"learning_rate": 0.01})
-        loss_fn = gluon.loss.L2Loss()
-        rng = np.random.RandomState(0)
-        for _ in range(2):
-            x = mx.nd.array(rng.rand(8, args.features)
-                            .astype(np.float32))
-            y = mx.nd.array(rng.rand(8, args.hidden)
-                            .astype(np.float32))
-            with autograd.record():
-                loss = loss_fn(net(x), y)
-            loss.backward()
-            trainer.step(8)
-
-    loop()                                        # fused_step/ programs
-    saved = os.environ.get("MXTPU_FUSED_STEP")
-    os.environ["MXTPU_FUSED_STEP"] = "0"
-    try:
-        loop()                                    # staged fused/ kernels
-    finally:
-        if saved is None:
-            os.environ.pop("MXTPU_FUSED_STEP", None)
-        else:
-            os.environ["MXTPU_FUSED_STEP"] = saved
+    net = nn.Dense(args.hidden, in_units=args.features)
+    net.initialize(mx.init.Xavier())
+    trainer = gluon.Trainer(net.collect_params(), args.optimizer,
+                            {"learning_rate": 0.01})
+    loss_fn = gluon.loss.L2Loss()
+    rng = np.random.RandomState(0)
+    for _ in range(2):
+        x = mx.nd.array(rng.rand(8, args.features).astype(np.float32))
+        y = mx.nd.array(rng.rand(8, args.hidden).astype(np.float32))
+        with autograd.record():
+            loss = loss_fn(net(x), y)
+        loss.backward()
+        trainer.step(8)
     return {"model": "train_capture", "optimizer": args.optimizer}
 
 

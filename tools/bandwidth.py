@@ -196,14 +196,14 @@ def _run_sweep_worker(args):
         fupdater = mxopt.get_updater(
             mxopt.create("sgd", learning_rate=0.01, momentum=0.9))
         from mxnet_tpu.parallel import fused_step as _fstep
-        ran = _fstep.try_step(fupdater, idxs, grads, weights,
-                              kvstore=kv)      # warmup + compile
+        ran = _fstep.step(fupdater, idxs, grads, weights,
+                          kvstore=kv)          # warmup + compile
         if not ran:       # not inside assert: python -O must still warm
             raise RuntimeError("fused step refused the sweep set")
         jax.block_until_ready([w._data for w in weights])
         f0 = time.perf_counter()
         for _ in range(args.iters):
-            _fstep.try_step(fupdater, idxs, grads, weights, kvstore=kv)
+            _fstep.step(fupdater, idxs, grads, weights, kvstore=kv)
         jax.block_until_ready([w._data for w in weights])
         ft = (time.perf_counter() - f0) / args.iters
         if rank == 0:
