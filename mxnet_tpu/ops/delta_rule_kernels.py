@@ -1,0 +1,373 @@
+"""Pallas TPU kernels for the chunked gated delta rule (Gated DeltaNet).
+
+`ops/linear_attention.py` states the recurrence and its chunked form.
+Here a chunk's whole work happens on tiles in VMEM, and the matrix state
+of a head stays in VMEM scratch across the sequential axis of the grid:
+between the op's inputs and its output nothing of size C x C or T x D
+goes to HBM except what the backward is handed on purpose, the state and
+the inverses each grid step starts from.
+
+A grid step takes a *tile* of L = 128 tokens (two chunks of 64; one chunk
+where C does not divide 128) of the `Hv // Hk` value heads of one key
+head, so q and k are read once where they lie, (B, T, Hk*Dk), and never
+repeated. What does not depend on the state (k k^T, q k^T, the decays,
+A, its inverse, U and W) is made for the tile's chunks at once, as
+L x L matrices masked to the chunks' diagonal blocks: a product of two
+128 x 128 matrices costs the MXU what one of two 64 x 64 does. The
+chunks' states then follow one another inside the step.
+
+- `gated_delta_rule_fwd` walks the tiles forward with S in scratch.
+- `gated_delta_rule_bwd` walks them backward with dS in scratch; a
+  tile's backward is `jax.vjp` of the one tile function (`_tile`) traced
+  on the loaded tiles, so the mathematics is stated once. It is handed
+  the inverses the forward made, and applies d(T^-1) = -T^-1 dT T^-1.
+
+(I + A)^-1 for the unit lower triangular I + A of a chunk is made from
+float32 products by merging diagonal blocks pairwise, from blocks of two
+(whose inverse is I - A) up to the chunk: inv = D - D L D for the
+block-diagonal inverse D so far and the part L of A between the blocks of
+a pair. Ten products for C = 64, every intermediate as small as the
+inverse itself. (The finite Neumann product (I - A)(I + A^2)(I + A^4)...
+costs the same ten and cancels terms of size C(n - 1, k) over a block of
+n: with keys that all but coincide it is wrong by 2e-4 over blocks of
+16, and this by 2e-7.)
+
+Precision is the plain path's: products take their operands in v's dtype
+and add up in float32; decays, inverse and state are float32, and a
+product of two float32 matrices is `HIGHEST` whatever v's dtype (the
+inverse, U and W, and their transposes in the backward).
+Off the chip the kernels run interpreted.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["delta_rule", "tiles"]
+
+_NT = (((1,), (1,)), ((), ()))          # a @ b.T
+_NN = (((1,), (0,)), ((), ()))          # a @ b
+_TN = (((0,), (0,)), ((), ()))          # a.T @ b
+_F32 = jnp.float32
+_HI = lax.Precision.HIGHEST
+_LANES = 128
+
+
+def _interpret():
+    return jax.default_backend() != "tpu"
+
+
+def tiles(T, Dk, Dv, chunk, dtype):
+    """Whether the kernels take this shape: heads of whole 128-lane
+    tiles, whole chunks, and a chunk of whole sublane tiles of `dtype`
+    (8 rows of float32, 16 of bfloat16)."""
+    C = min(int(chunk), T)
+    rows = 8 * (4 // jnp.dtype(dtype).itemsize)
+    return Dk % 128 == 0 and Dv % 128 == 0 and T % C == 0 and C % rows == 0
+
+
+def _chunks_a_tile(C, N):
+    """Chunks a grid step takes: as many as fill 128 lanes, where C
+    divides 128 and that many divide the sequence's N chunks."""
+    m = _LANES // C if _LANES % C == 0 else 1
+    return m if N % m == 0 else 1
+
+
+def _dot(a, b, dims, prec=_HI):
+    return lax.dot_general(a, b, dims, precision=prec,
+                           preferred_element_type=_F32)
+
+
+def _iotas(L):
+    return (lax.broadcasted_iota(jnp.int32, (L, L), 0),
+            lax.broadcasted_iota(jnp.int32, (L, L), 1))
+
+
+def _same_block(t, s, side):
+    shift = side.bit_length() - 1       # side is a power of two
+    return (t >> shift) == (s >> shift)
+
+
+def _unit_lower_inverse(A, C):
+    """(I + A)^-1 for a float32 A (L, L) that is strictly lower
+    triangular inside diagonal blocks of C and zero outside them."""
+    t, s = _iotas(A.shape[0])
+    # a unit lower block of two has the inverse I - A
+    inv = jnp.where(t == s, 1.0, 0.0) - jnp.where(_same_block(t, s, 2), A, 0.0)
+    side = 2
+    while side < C:                     # merge the blocks pairwise
+        between = jnp.logical_and(_same_block(t, s, 2 * side),
+                                  jnp.logical_not(_same_block(t, s, side)))
+        DL = _dot(inv, jnp.where(between, A, 0.0), _NN)
+        inv = inv - _dot(DL, inv, _NN)
+        side *= 2
+    return inv
+
+
+def _inverse_bwd(inv, g):
+    """d(T^-1) = -T^-1 dT T^-1: A's cotangent from its inverse's. What
+    falls outside A's pattern is dropped by the mask that made A."""
+    return -_dot(_dot(inv, g, _TN), inv, _NT)
+
+
+def _column(row, pick):
+    """(1, L) -> (L, 1) without a transpose: row[s] where pick[t, s],
+    summed along the lanes."""
+    return jnp.sum(jnp.where(pick, row, 0.0), axis=1, keepdims=True)
+
+
+def _masks(L, C):
+    """t == s, and s <= t and s < t inside a chunk."""
+    t, s = _iotas(L)
+    if C == L:
+        return t == s, s <= t, s < t
+    chunk = _same_block(t, s, C)
+    return (t == s, jnp.logical_and(chunk, s <= t),
+            jnp.logical_and(chunk, s < t))
+
+
+def _decays(c_row, b_row, masks):
+    """The columns of c and beta and G[t, s] = exp(c_t - c_s) for s <= t
+    inside a chunk; the masked part would overflow."""
+    eye, lower, _ = masks
+    c_col, b_col = _column(c_row, eye), _column(b_row, eye)
+    return c_col, b_col, jnp.exp(jnp.where(lower, c_col - c_row, -jnp.inf))
+
+
+def _systems(k, cs, bs, cd, C):
+    """A of every head: beta_t G[t, s] k_t.k_s for s < t inside a chunk."""
+    masks = _masks(k.shape[0], C)
+    kc = k.astype(cd)
+    kk = _dot(kc, kc, _NT, _HI if cd == _F32 else None)
+    As = []
+    for c_row, b_row in zip(cs, bs):
+        _, b_col, G = _decays(c_row, b_row, masks)
+        As.append(jnp.where(masks[2], b_col * G * kk, 0.0))
+    return As
+
+
+def _tile(q, k, vs, cs, bs, Ss, invs, cd, C, carry):
+    """One tile of L tokens (L // C chunks) of the `len(vs)` value heads
+    of one key head. q, k: (L, Dk) float32 holding values of `cd`; vs:
+    (L, Dv) each, in `cd`; cs, bs: (1, L) float32 rows, the running sum
+    of g inside each chunk and beta; Ss: (Dk, Dv) float32 states the tile
+    starts from; invs: (I + A)^-1 of every head, (L, L) float32. Returns
+    the float32 outputs (L, Dv) and the states the tile ends with: the
+    docstring of `ops/linear_attention.py`, statement for statement."""
+    L, Dv = q.shape[0], vs[0].shape[1]
+    prec = _HI if cd == _F32 else None
+    masks = _masks(L, C)
+    lane = lax.broadcasted_iota(jnp.int32, (1, L), 1)
+    token = lax.broadcasted_iota(jnp.int32, (L, 1), 0)
+    qk = _dot(q.astype(cd), k.astype(cd), _NT, prec)
+    cat = (lambda xs: xs[0] if len(xs) == 1
+           else jnp.concatenate(xs, axis=0))
+    outs, states = [], []
+    for v, c_row, b_row, S, inv in zip(vs, cs, bs, Ss, invs):
+        c_col, b_col, G = _decays(c_row, b_row, masks)
+        e_col = jnp.exp(c_col)
+        # U and W of all the tile's chunks, one product
+        UW = _dot(inv, jnp.concatenate(
+            [b_col * v.astype(_F32), (b_col * e_col) * k], axis=1), _NN)
+        U, W = UW[:, :Dv], UW[:, Dv:].astype(cd)
+        P = (G * qk).astype(cd)                          # s <= t by G
+        q_in = (e_col * q).astype(cd)
+        # c at the end of each chunk, (1, 1), and of each token's chunk
+        c_ends = [jnp.sum(jnp.where(lane == j + C - 1, c_row, 0.0), axis=1,
+                          keepdims=True) for j in range(0, L, C)]
+        end_col = c_ends[-1]
+        for j in reversed(range(1, L // C)):
+            end_col = jnp.where(token < j * C, c_ends[j - 1], end_col)
+        k_out = (jnp.exp(end_col - c_col) * k).astype(cd)
+        deltas, from_state = [], []
+        for j, c_end in enumerate(c_ends):               # chunk after chunk
+            rows = slice(j * C, (j + 1) * C)
+            # W S and q S, one product
+            WqS = _dot(jnp.concatenate([W[rows], q_in[rows]], axis=0),
+                       S.astype(cd), _NN, prec)
+            dc = (U[rows] - WqS[:C]).astype(cd)
+            from_state.append(WqS[C:])
+            if carry:                   # else every chunk starts from zero
+                S = jnp.exp(c_end) * S + _dot(k_out[rows], dc, _TN, prec)
+            deltas.append(dc)
+        outs.append(cat(from_state) + _dot(P, cat(deltas), _NN, prec))
+        states.append(S)
+    return outs, states
+
+
+def _head_tiles(v_ref, c_ref, b_ref, n, rep):
+    """The value tiles and the (1, L) rows of tile n, a head at a time."""
+    Dv = v_ref.shape[1] // rep
+    vs = [v_ref[:, r * Dv:(r + 1) * Dv] for r in range(rep)]
+    cs = [c_ref[r, pl.ds(n, 1), :] for r in range(rep)]
+    bs = [b_ref[r, pl.ds(n, 1), :] for r in range(rep)]
+    return vs, cs, bs
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, c_ref, b_ref, o_ref, *rest, rep, C,
+                carry, save):
+    *kept, s_ref = rest                 # the outputs where `save`; S
+    n = pl.program_id(2)
+    Dv = v_ref.shape[1] // rep
+    cd = v_ref.dtype
+
+    @pl.when(n == 0)
+    def _init():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    vs, cs, bs = _head_tiles(v_ref, c_ref, b_ref, n, rep)
+    q, k = q_ref[...].astype(_F32), k_ref[...].astype(_F32)
+    Ss = [s_ref[r] for r in range(rep)]
+    invs = [_unit_lower_inverse(A, C) for A in _systems(k, cs, bs, cd, C)]
+    if save:
+        for r in range(rep):
+            kept[0][r] = Ss[r]
+            kept[1][r] = invs[r]
+    outs, states = _tile(q, k, vs, cs, bs, Ss, invs, cd, C, carry)
+    for r in range(rep):
+        o_ref[:, r * Dv:(r + 1) * Dv] = outs[r].astype(o_ref.dtype)
+        s_ref[r] = states[r]
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, c_ref, b_ref, s0_ref, inv_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dc_ref, db_ref, ds_ref, *, rep, C,
+                carry):
+    step, N = pl.program_id(2), pl.num_programs(2)
+    n = N - 1 - step
+    Dv = v_ref.shape[1] // rep
+    cd = v_ref.dtype
+
+    @pl.when(step == 0)
+    def _init():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    vs, cs, bs = _head_tiles(v_ref, c_ref, b_ref, n, rep)
+    q, k = q_ref[...].astype(_F32), k_ref[...].astype(_F32)
+    invs = [inv_ref[r] for r in range(rep)]
+    _, pull = jax.vjp(
+        functools.partial(_tile, cd=cd, C=C, carry=carry), q, k, vs, cs, bs,
+        [s0_ref[r] for r in range(rep)], invs)
+    dos = [do_ref[:, r * Dv:(r + 1) * Dv].astype(_F32) for r in range(rep)]
+    dq, dk, dvs, dcs, dbs, dSs, dinvs = pull(
+        (dos, [ds_ref[r] for r in range(rep)]))
+    # through the inverses the forward made, to what A was made from
+    _, pull = jax.vjp(functools.partial(_systems, cd=cd, C=C), k, cs, bs)
+    dk_A, dcs_A, dbs_A = pull(
+        [_inverse_bwd(inv, g) for inv, g in zip(invs, dinvs)])
+    dq_ref[...] = dq.astype(dq_ref.dtype)
+    dk_ref[...] = (dk + dk_A).astype(dk_ref.dtype)
+    for r in range(rep):
+        dv_ref[:, r * Dv:(r + 1) * Dv] = dvs[r].astype(dv_ref.dtype)
+        dc_ref[r, pl.ds(n, 1), :] = dcs[r] + dcs_A[r]
+        db_ref[r, pl.ds(n, 1), :] = dbs[r] + dbs_A[r]
+        ds_ref[r] = dSs[r]
+
+
+def _specs(L, Dk, Dv, rep, N, tile_of):
+    """Block specs over the grid (b, key head, step): a tile of q or k,
+    of the group's value heads, the group's rows of c or beta (all N
+    tiles, fetched once a head), and a tile's states or inverses."""
+    qk = pl.BlockSpec((None, L, Dk), lambda b, h, i: (b, tile_of(i), h))
+    v = pl.BlockSpec((None, L, rep * Dv), lambda b, h, i: (b, tile_of(i), h))
+    rows = pl.BlockSpec((None, rep, N, L), lambda b, h, i: (b, h, 0, 0))
+
+    def square(rows, cols):
+        return pl.BlockSpec((None, rep, None, rows, cols),
+                            lambda b, h, i: (b, h, tile_of(i), 0, 0))
+    return qk, v, rows, square(Dk, Dv), square(L, L)
+
+
+def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
+          interpret):
+    return pl.pallas_call(
+        kernel, name=name, grid=grid, in_specs=in_specs,
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(scratch, _F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret)
+
+
+def _dims(q, v, c):
+    """Sizes, and c's and beta's rows a tile: (B, Hv, N, C) chunks as
+    (B, Hv, N // m, m * C) tiles of m chunks."""
+    B, T, Hk, Dk = q.shape
+    Hv, Dv = v.shape[2:]
+    N, C = c.shape[2:]
+    m = _chunks_a_tile(C, N)
+    return B, T, Hk, Dk, Hv, Dv, N // m, C, m * C
+
+
+# Both passes are jitted so that a model's layers share one traced
+# function each (a Pallas kernel is lowered where it is called);
+# `interpret` is an argument because it keys jit's cache.
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _fwd_on(q, k, v, c, beta, carry, save, interpret):
+    """o (B, T, Hv, Dv) in v's dtype and, where `save`, what the backward
+    is handed, float32: the state every tile starts from, (B, Hv, N, Dk,
+    Dv), and its inverses, (B, Hv, N, L, L)."""
+    B, T, Hk, Dk, Hv, Dv, N, C, L = _dims(q, v, c)
+    rep = Hv // Hk
+    qk, vspec, rows, state, inverse = _specs(L, Dk, Dv, rep, N, lambda i: i)
+    o_shape = jax.ShapeDtypeStruct((B, T, Hv * Dv), v.dtype)
+    kept = [jax.ShapeDtypeStruct((B, Hv, N, Dk, Dv), _F32),
+            jax.ShapeDtypeStruct((B, Hv, N, L, L), _F32)] if save else []
+    out = _call(
+        functools.partial(_fwd_kernel, rep=rep, C=C, carry=carry, save=save),
+        "gated_delta_rule_fwd", (B, Hk, N), [qk, qk, vspec, rows, rows],
+        [vspec] + ([state, inverse] if save else []), [o_shape] + kept,
+        (rep, Dk, Dv), interpret)(
+            q.reshape(B, T, Hk * Dk), k.reshape(B, T, Hk * Dk),
+            v.reshape(B, T, Hv * Dv), c.reshape(B, Hv, N, L),
+            beta.reshape(B, Hv, N, L))
+    return out[0].reshape(B, T, Hv, Dv), tuple(out[1:])
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9))
+def _bwd_on(q, k, v, c, beta, states, inverses, do, carry, interpret):
+    B, T, Hk, Dk, Hv, Dv, N, C, L = _dims(q, v, c)
+    rep = Hv // Hk
+    qk, vspec, rows, state, inverse = _specs(L, Dk, Dv, rep, N,
+                                             lambda i: N - 1 - i)
+    flat_qk = jax.ShapeDtypeStruct((B, T, Hk * Dk), q.dtype)
+    row_shape = jax.ShapeDtypeStruct((B, Hv, N, L), _F32)
+    dq, dk, dv, dc, db = _call(
+        functools.partial(_bwd_kernel, rep=rep, C=C, carry=carry),
+        "gated_delta_rule_bwd", (B, Hk, N),
+        [qk, qk, vspec, rows, rows, state, inverse, vspec],
+        [qk, qk, vspec, rows, rows],
+        [flat_qk, flat_qk, jax.ShapeDtypeStruct((B, T, Hv * Dv), v.dtype),
+         row_shape, row_shape], (rep, Dk, Dv), interpret)(
+            q.reshape(B, T, Hk * Dk), k.reshape(B, T, Hk * Dk),
+            v.reshape(B, T, Hv * Dv), c.reshape(B, Hv, N, L),
+            beta.reshape(B, Hv, N, L), states, inverses,
+            do.reshape(B, T, Hv * Dv))
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            dc.reshape(c.shape), db.reshape(c.shape))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def delta_rule(q, k, v, c, beta, carry_state=True):
+    """The chunked rule through the kernels. q, k: (B, T, Hk, Dk),
+    L2-normalised and scaled, in v's dtype; v: (B, T, Hv, Dv); c, beta:
+    (B, Hv, N, C) float32, c the running sum of g inside each chunk.
+    Returns o (B, T, Hv, Dv) in v's dtype. The residuals of the backward
+    are the inputs and every tile's entry state and inverses."""
+    return _fwd_on(q, k, v, c, beta, carry_state, False, _interpret())[0]
+
+
+def _vjp_fwd(q, k, v, c, beta, carry_state):
+    o, kept = _fwd_on(q, k, v, c, beta, carry_state, True, _interpret())
+    return o, (q, k, v, c, beta, *kept)
+
+
+def _vjp_bwd(carry_state, res, do):
+    return _bwd_on(*res, do, carry_state, _interpret())
+
+
+delta_rule.defvjp(_vjp_fwd, _vjp_bwd)

@@ -16,19 +16,32 @@ G[t, s] = exp(c_t - c_s):
     S_C = exp(c_C) S0 + sum_s exp(c_C - c_s) k_s d_s^T
 
 so D = U - W S0 with U = (I + A)^-1 (beta V) and W = (I + A)^-1
-(beta exp(c) K), which do not depend on the state and are solved for
-every chunk at once (a unit lower-triangular solve in float32); a
-`lax.scan` over the chunks then carries S in float32 through four
-matrix products a chunk. Its backward is JAX's transpose of the same
-program: a reverse scan over the chunks, the state's cotangent carried
-the other way. Nothing is ever T x T.
+(beta exp(c) K), which do not depend on the state; S is then carried in
+float32 through four matrix products a chunk. Nothing is ever T x T.
+
+Two paths compute this, chosen from the shape and the mesh the call is
+traced with (`_kernel_shard`; counted in `linear_attention.delta.path`).
+Where the heads are whole 128-lane tiles the Pallas kernels of
+`ops/delta_rule_kernels.py` do a chunk's whole work in VMEM with the
+state in scratch across the chunks, and their backward is a second
+kernel that walks the chunks in reverse with the state's cotangent in
+scratch and takes `jax.vjp` of the forward's own tile function.
+Everywhere else (`_plain`) U and W are solved for every chunk at once (a
+unit lower-triangular solve in float32), a `lax.scan` over the chunks
+carries S, and the backward is JAX's transpose of that program: a
+reverse scan, the state's cotangent carried the other way.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec
 
+from ..observability import registry as _obs
+from . import delta_rule_kernels
 from .registry import register
 
 __all__ = ["gated_delta_rule", "causal_conv1d", "rms_norm", "gated_rms_norm"]
@@ -47,21 +60,82 @@ def _l2norm(x):
     return xf * lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + _L2_EPS)
 
 
+DELTA_PATH = _obs.counter(
+    "linear_attention.delta.path",
+    "Times gated_delta_rule was traced into a program, by what its shape "
+    "and the mesh chose (label path: kernel = the Pallas kernels of "
+    "ops/delta_rule_kernels.py, Dk and Dv multiples of 128 and the chunk "
+    "of a sublane tile; plain = the XLA operations and a lax.scan)")
+
+
+def _kernel_shard(B, T, Dk, Dv, C, dtype):
+    """How the kernels take this call, or None where they do not: the
+    shape has to tile (`delta_rule_kernels.tiles`), and XLA cannot split
+    a custom call, so under a mesh (`parallel.use_mesh`) whose only axis
+    wider than one is `dp` they run per shard of the batch and under any
+    other mesh the plain path runs, which XLA partitions. Returns what
+    wraps the function of (q, k, v, g, beta)."""
+    from ..parallel.mesh import current_mesh, shard_map_compat
+    from .pallas_kernels import _axis_bound
+    if not delta_rule_kernels.tiles(T, Dk, Dv, C, dtype):
+        return None
+    mesh = current_mesh()
+    wide = [a for a in mesh.axis_names if mesh.shape[a] > 1] if mesh else []
+    # one device, or traced inside a shard_map over every wide axis: the
+    # arrays here are one device's already
+    if all(_axis_bound(a) for a in wide):
+        return lambda fn: fn
+    if wide != ["dp"] or B % mesh.shape["dp"]:
+        return None
+    spec = PartitionSpec("dp")
+    return lambda fn: shard_map_compat(fn, mesh, (spec,) * 5, spec)
+
+
+def _through_kernels(q, k, v, g, beta, C, carry_state):
+    """What stays outside the kernels: the normalisation of q and k and
+    the running sum of g inside each chunk, one small XLA pass each."""
+    B, T, _, Dk = q.shape
+    Hv, cd = v.shape[2], v.dtype
+
+    def rows(x):                          # (B,T,Hv) -> (B,Hv,N,C)
+        return x.astype(jnp.float32).transpose(0, 2, 1).reshape(
+            B, Hv, T // C, C)
+
+    return delta_rule_kernels.delta_rule(
+        (_l2norm(q) * (Dk ** -0.5)).astype(cd), _l2norm(k).astype(cd), v,
+        jnp.cumsum(rows(g), axis=-1), rows(beta), carry_state)
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk=64, carry_state=True):
     """q, k: (B, T, Hk, Dk); v: (B, T, Hv, Dv) with Hv a multiple of Hk
     (key head h // (Hv // Hk) serves value head h); g, beta: (B, T, Hv)
     float32. q and k are L2-normalised over the head here and q scaled
     by Dk^-1/2. Returns o (B, T, Hv, Dv) in v's dtype. Matrix products
     take their operands in v's dtype (the normalised q and k too) and add
-    up in float32; the decays, the triangular solve and the state are
-    float32. `carry_state=False`
-    starts every chunk from a zero state: the fault the tests plant."""
-    B, T, Hk, Dk = q.shape
-    Hv, Dv = v.shape[2], v.shape[3]
+    up in float32; the decays, the state and the triangular solve are
+    float32 (the kernels make (I + A)^-1 from float32 products at
+    `HIGHEST` instead of solving). `carry_state=False` starts every
+    chunk from a zero state: the fault the tests plant.
+    The path is chosen from the shape and the mesh the call is traced
+    with (`_kernel_shard`) and counted in `linear_attention.delta.path`."""
+    B, T, _, Dk = q.shape
     C = min(int(chunk), T)
     if T % C:
         raise ValueError("gated_delta_rule: %d tokens do not divide into "
                          "chunks of %d" % (T, C))
+    shard = _kernel_shard(B, T, Dk, v.shape[3], C, v.dtype)
+    DELTA_PATH.inc(path="plain" if shard is None else "kernel")
+    if shard is None:
+        return _plain(q, k, v, g, beta, C, carry_state)
+    return shard(functools.partial(_through_kernels, C=C,
+                                   carry_state=carry_state))(q, k, v, g, beta)
+
+
+def _plain(q, k, v, g, beta, C, carry_state):
+    """The chunked rule as XLA operations: every chunk's U and W from
+    one batched triangular solve, then a `lax.scan` over the chunks."""
+    B, T, Hk, Dk = q.shape
+    Hv, Dv = v.shape[2], v.shape[3]
     N = T // C
     cd = v.dtype
     prec = _precision(cd)

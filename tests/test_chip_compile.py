@@ -90,6 +90,31 @@ def test_flash_attention_gpt2_small(one_chip, for_the_chip, causal, dtype):
     assert all("constraints={bf16[96,1024,64]" in line for line in calls)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_gated_delta_rule_qwen3_next(one_chip, for_the_chip, monkeypatch,
+                                     dtype):
+    # one Gated DeltaNet layer of `qwen3next_train_b1_t8192`: B 1, T 8192,
+    # 16 key and 32 value heads of 128, chunks of 64 (two a grid step):
+    # the forward kernel, the forward that writes what the backward is
+    # handed, and the backward kernel
+    from mxnet_tpu.ops import delta_rule_kernels as dk
+    from mxnet_tpu.ops import linear_attention as la
+    monkeypatch.setattr(dk, "_interpret", lambda: False)
+    qk, v = ((1, 8192, 16, 128), dtype), ((1, 8192, 32, 128), dtype)
+    rows = ((1, 8192, 32), jnp.float32)
+    compiled = _compile(jax.value_and_grad(
+        lambda *a: la._through_kernels(*a, 64, True).astype(jnp.float32)
+        .sum(), argnums=(0, 1, 2, 3, 4)), one_chip, qk, qk, v, rows, rows)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    # no XLA array of C x C a chunk: what the backward is handed is
+    # 128 x 128 a grid step, from one kernel to the other
+    assert "64,64]" not in text
+    _compile(lambda *a: la._through_kernels(*a, 64, True), one_chip,
+             qk, qk, v, rows, rows)
+
+
 def test_layer_norm_8192x768(one_chip, for_the_chip):
     _compile(pk.pallas_layer_norm, one_chip,
              ((8192, 768), jnp.bfloat16), ((768,), jnp.float32),
