@@ -4,7 +4,9 @@ from . import model_store
 from . import vision
 from . import gpt
 from . import qwen3_next
+from . import kimi_linear
 
 from .vision import get_model
 from .gpt import GPTDecoder, get_gpt
 from .qwen3_next import Qwen3NextDecoder, get_qwen3_next
+from .kimi_linear import KimiLinearDecoder, get_kimi_linear

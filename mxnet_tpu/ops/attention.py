@@ -8,6 +8,11 @@ block's inputs and output only, and the backward pass recomputes one
 block's scores, takes their gradient and adds the block's share to the
 prefix of dK and dV. The scores, the softmax and the sums are float32;
 the two products take their operands in the inputs' dtype.
+
+The value heads may be of another size than the query and key heads
+(latent attention: a key is a head's own part with a part that all heads
+share joined on, 192 wide against values of 128); the output then has
+the values' size. Such a call is counted in `attention.latent.layers`.
 """
 from __future__ import annotations
 
@@ -18,6 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..observability import registry as _obs
 from .linear_attention import _precision
 from .registry import register
 
@@ -25,12 +31,18 @@ __all__ = ["blocked_causal_attention", "rotary_embedding"]
 
 _NEG_INF = -1e30
 
+LATENT_LAYERS = _obs.counter(
+    "attention.latent.layers",
+    "Times blocked_causal_attention was traced into a program with value "
+    "heads of another size than the query and key heads: once a latent "
+    "attention layer each time its program is traced")
+
 
 @functools.partial(jax.checkpoint, static_argnums=(3, 4))
 def _row_block(q, k, v, first_row, scale):
-    """q: (B, Q, Hkv, G, D), rows first_row..first_row+Q-1; k, v: (B, S,
-    Hkv, D), columns 0..S-1. Returns (B, Q, Hkv, G, D). `first_row` None:
-    no mask."""
+    """q: (B, Q, Hkv, G, D), rows first_row..first_row+Q-1; k: (B, S,
+    Hkv, D) and v: (B, S, Hkv, Dv), columns 0..S-1. Returns (B, Q, Hkv,
+    G, Dv). `first_row` None: no mask."""
     prec = _precision(q.dtype)
     s = jnp.einsum("bqkgd,bskd->bkgqs", q, k, precision=prec,
                    preferred_element_type=jnp.float32) * scale
@@ -44,11 +56,14 @@ def _row_block(q, k, v, first_row, scale):
 
 
 def blocked_causal_attention(q, k, v, block_q=512, scale=None, causal=True):
-    """q: (B, T, Hq, D); k, v: (B, T, Hkv, D), Hq a multiple of Hkv (query
-    head h reads key/value head h // (Hq // Hkv)). Returns (B, T, Hq, D).
+    """q: (B, T, Hq, D); k: (B, T, Hkv, D); v: (B, T, Hkv, Dv), Hq a
+    multiple of Hkv (query head h reads key/value head h // (Hq // Hkv)).
+    Returns (B, T, Hq, Dv). `scale` is D^-1/2 unless given.
     `causal=False` gives every block all the keys and no mask."""
     B, T, Hq, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[3]
+    if Dv != D:
+        LATENT_LAYERS.inc()
     if Hq % Hkv:
         raise ValueError("blocked_causal_attention: %d query heads over %d "
                          "key/value heads" % (Hq, Hkv))
@@ -64,7 +79,7 @@ def blocked_causal_attention(q, k, v, block_q=512, scale=None, causal=True):
     else:
         out = [_row_block(qg[:, i:i + bq], k, v, None, scale)
                for i in range(0, T, bq)]
-    return jnp.concatenate(out, axis=1).reshape(B, T, Hq, D)
+    return jnp.concatenate(out, axis=1).reshape(B, T, Hq, Dv)
 
 
 def rotary_embedding(x, rotary_dim, theta=10000.0):

@@ -30,6 +30,19 @@ Everywhere else (`_plain`) U and W are solved for every chunk at once (a
 unit lower-triangular solve in float32), a `lax.scan` over the chunks
 carries S, and the backward is JAX's transpose of that program: a
 reverse scan, the state's cotangent carried the other way.
+
+A decay may also be given per key channel, g (B, T, Hv, Dk): the state's
+row d then decays by exp(g_t[d]) (Kimi Delta Attention). The decay no
+longer factors out of the products of keys,
+
+    A[t, s] = beta_t sum_d k_t[d] k_s[d] exp(c_t[d] - c_s[d])   (s < t)
+
+so that call takes a third path, `_plain_channels` (counted as `plain`):
+a `lax.scan` over the chunks whose step does one chunk's whole work
+under `jax.checkpoint`, with every exponent kept at or below zero (its
+docstring says how). It holds O(T H D) arrays and the chunks' states and
+nothing of a chunk's C x C systems beyond the chunk in flight. It is
+the baseline a kernel for a per-channel decay starts from.
 """
 from __future__ import annotations
 
@@ -65,7 +78,8 @@ DELTA_PATH = _obs.counter(
     "Times gated_delta_rule was traced into a program, by what its shape "
     "and the mesh chose (label path: kernel = the Pallas kernels of "
     "ops/delta_rule_kernels.py, Dk and Dv multiples of 128 and the chunk "
-    "of a sublane tile; plain = the XLA operations and a lax.scan)")
+    "of a sublane tile; plain = the XLA operations and a lax.scan, which "
+    "is also what every decay given per key channel takes)")
 
 
 def _kernel_shard(B, T, Dk, Dv, C, dtype):
@@ -108,11 +122,14 @@ def _through_kernels(q, k, v, g, beta, C, carry_state):
 
 def gated_delta_rule(q, k, v, g, beta, chunk=64, carry_state=True):
     """q, k: (B, T, Hk, Dk); v: (B, T, Hv, Dv) with Hv a multiple of Hk
-    (key head h // (Hv // Hk) serves value head h); g, beta: (B, T, Hv)
-    float32. q and k are L2-normalised over the head here and q scaled
-    by Dk^-1/2. Returns o (B, T, Hv, Dv) in v's dtype. Matrix products
-    take their operands in v's dtype (the normalised q and k too) and add
-    up in float32; the decays, the state and the triangular solve are
+    (key head h // (Hv // Hk) serves value head h); beta: (B, T, Hv) and
+    g: (B, T, Hv), one log decay a head, or (B, T, Hv, Dk), one a key
+    channel (the state's row d decays by exp(g[d]); `_plain_channels`,
+    counted as `plain`); float32. q and k are L2-normalised over the
+    head here and q scaled by Dk^-1/2. Returns o (B, T, Hv, Dv) in v's
+    dtype. Matrix products take their operands in v's dtype (the
+    normalised q and k too) and add up in float32; the decays, the state
+    and the triangular solve are
     float32 (the kernels make (I + A)^-1 from float32 products at
     `HIGHEST` instead of solving). `carry_state=False` starts every
     chunk from a zero state: the fault the tests plant.
@@ -123,6 +140,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, carry_state=True):
     if T % C:
         raise ValueError("gated_delta_rule: %d tokens do not divide into "
                          "chunks of %d" % (T, C))
+    if g.ndim == 4:
+        DELTA_PATH.inc(path="plain")
+        return _plain_channels(q, k, v, g, beta, C, carry_state)
     shard = _kernel_shard(B, T, Dk, v.shape[3], C, v.dtype)
     DELTA_PATH.inc(path="plain" if shard is None else "kernel")
     if shard is None:
@@ -200,6 +220,111 @@ def _plain(q, k, v, g, beta, C, carry_state):
     return o.transpose(0, 2, 1, 3).astype(cd)
 
 
+_SUB = 16        # rows of a chunk that share one reference row
+
+
+def _plain_channels(q, k, v, g, beta, C, carry_state):
+    """The chunked rule with one decay a key channel, g (B, T, Hv, Dk).
+    With c_t (Dk) the running sum of g inside the chunk:
+
+        A[t, s] = beta_t sum_d k_t[d] k_s[d] exp(c_t[d] - c_s[d])   (s < t)
+        (I + A) D = beta (V - (K * exp(c)) S0)
+        o_t = (q_t * exp(c_t))^T S0 + sum_{s <= t} P[t, s] d_s,
+        P[t, s] = sum_d q_t[d] k_s[d] exp(c_t[d] - c_s[d])
+        S_C = Diag(exp(c_C)) S0 + sum_s (k_s * exp(c_C - c_s)) d_s^T
+
+    Written as (k_t exp(c_t)) . (k_s exp(-c_s)) the second factor
+    overflows float32 once a channel decays by e^88 inside a chunk. Here
+    no exponent is ever above zero: the chunk's rows are cut into blocks
+    of 16 (the published kernels' way), and for the columns BEFORE row
+    block i both factors are taken against the block's first row r,
+    (k_t exp(c_t - c_r)) . (k_s exp(c_r - c_s)), each at most one in size
+    (a matrix product, operands in v's dtype, float32 sums); INSIDE a
+    block exp(c_t - c_s) is formed for each pair and channel and summed
+    as it is (float32 elementwise, 16 x 16 x Dk a block). One `lax.scan`
+    step does one chunk, all heads at once, under `jax.checkpoint`: the
+    backward pass keeps the state each chunk started from and computes
+    the chunk again. Decays, solve and state are float32."""
+    B, T, Hk, Dk = q.shape
+    Hv, Dv = v.shape[2], v.shape[3]
+    N = T // C
+    sub = _SUB if C % _SUB == 0 else C
+    R = C // sub
+    cd = v.dtype
+    prec = _precision(cd)
+    f32 = jnp.float32
+
+    def chunks(x, h=Hv):                  # (B,T,h,...) -> (N,B,Hv,C,...)
+        x = jnp.repeat(x, Hv // h, axis=2) if h != Hv else x
+        x = x.reshape((B, N, C) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    # normalised in float32, kept in the operands' dtype
+    xs = (chunks((_l2norm(q) * (Dk ** -0.5)).astype(cd), Hk),
+          chunks(_l2norm(k).astype(cd), Hk), chunks(v),
+          chunks(g.astype(f32)), chunks(beta.astype(f32)))
+
+    t_idx = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    s_idx = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    # column s lies before row block i; rows and columns of one block
+    before = (lax.broadcasted_iota(jnp.int32, (R, C), 1)
+              < sub * lax.broadcasted_iota(jnp.int32, (R, C), 0))
+    tri = (lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+           <= lax.broadcasted_iota(jnp.int32, (sub, sub), 0))
+    same = jnp.eye(R, dtype=bool)
+
+    def mm(spec, a, b):
+        return jnp.einsum(spec, a, b, precision=prec,
+                          preferred_element_type=f32)
+
+    @jax.checkpoint
+    def chunk(S, x):
+        q_n, k_n, v_n, g_n, b_n = x       # (B,Hv,C,D), (B,Hv,C)
+        c = jnp.cumsum(g_n, axis=2)       # <= 0, falling with t
+        qf, kf = q_n.astype(f32), k_n.astype(f32)
+        blocks = lambda a: a.reshape(B, Hv, R, sub, Dk)  # noqa: E731
+        cb, qb, kb = blocks(c), blocks(qf), blocks(kf)
+        since_start = jnp.exp(c)                         # <= 1
+        ref = cb[:, :, :, :1]                            # block i's first row
+        fall = jnp.exp(cb - ref)                         # rows, <= 1
+        # columns before block i, against its first row: <= 1, else 0
+        k_cols = (kf[:, :, None] * jnp.exp(jnp.where(
+            before[:, :, None], ref - c[:, :, None], -jnp.inf))).astype(cd)
+        spec = "bhrtd,bhrsd->bhrts"
+        kk = mm(spec, (kb * fall).astype(cd), k_cols).reshape(B, Hv, C, C)
+        qk = mm(spec, (qb * fall).astype(cd), k_cols).reshape(B, Hv, C, C)
+        # inside a block, pair by pair
+        E = jnp.exp(jnp.where(tri[:, :, None], cb[:, :, :, :, None]
+                              - cb[:, :, :, None, :], -jnp.inf))
+        kE = kb[:, :, :, None, :] * E                    # (B,Hv,R,sub,sub,Dk)
+
+        def on_diagonal(rows):
+            inner = jnp.sum(rows[:, :, :, :, None] * kE, axis=-1)
+            return jnp.where(same[:, None, :, None], inner[:, :, :, :, None],
+                             0.0).reshape(B, Hv, C, C)
+
+        kk, qk = kk + on_diagonal(kb), qk + on_diagonal(qb)
+        A = jnp.where(s_idx < t_idx, b_n[..., None] * kk, 0.0)
+        P = jnp.where(s_idx <= t_idx, qk, 0.0).astype(cd)
+        Sc = S.astype(cd)
+        rhs = b_n[..., None] * (v_n.astype(f32) - mm(
+            "bhtk,bhkv->bhtv", (kf * since_start).astype(cd), Sc))
+        delta = lax.linalg.triangular_solve(
+            A + jnp.eye(C, dtype=f32), rhs, left_side=True, lower=True,
+            unit_diagonal=True)
+        dc = delta.astype(cd)
+        o = (mm("bhtk,bhkv->bhtv", (qf * since_start).astype(cd), Sc)
+             + mm("bhts,bhsv->bhtv", P, dc))
+        c_end = c[:, :, -1:]                             # (B,Hv,1,Dk)
+        S_new = jnp.swapaxes(jnp.exp(c_end), 2, 3) * S + mm(
+            "bhtk,bhtv->bhkv", (kf * jnp.exp(c_end - c)).astype(cd), dc)
+        return (S_new if carry_state else S), o.astype(cd)
+
+    _, o = lax.scan(chunk, jnp.zeros((B, Hv, Dk, Dv), f32), xs)
+    # (N,B,Hv,C,Dv) -> (B,T,Hv,Dv)
+    return jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(B, T, Hv, Dv)
+
+
 def _taps(xp, w, T):
     """sum_j w[:, j] * xp[:, j:j + T], float32."""
     return sum(xp[:, j:j + T, :].astype(jnp.float32)
@@ -255,11 +380,17 @@ def rms_norm(x, w, eps=1e-6, offset=0.0):
     return (y * (offset + w.astype(jnp.float32))).astype(x.dtype)
 
 
-def gated_rms_norm(x, z, w, eps=1e-6):
-    """w * x / sqrt(mean(x^2) + eps) * silu(z) over the last axis."""
+_GATES = {"silu": jax.nn.silu, "sigmoid": jax.nn.sigmoid}
+
+
+def gated_rms_norm(x, z, w, eps=1e-6, activation="silu"):
+    """w * x / sqrt(mean(x^2) + eps) * act(z) over the last axis, act
+    SiLU or the sigmoid."""
+    if activation not in _GATES:
+        raise ValueError("gated_rms_norm: unknown activation %r" % activation)
     xf = x.astype(jnp.float32)
     y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    y = y * w.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    y = y * w.astype(jnp.float32) * _GATES[activation](z.astype(jnp.float32))
     return y.astype(x.dtype)
 
 
@@ -269,13 +400,18 @@ def gated_rms_norm(x, z, w, eps=1e-6):
 @register("_contrib_gated_delta_rule", num_outputs=2, visible_outputs=1,
           aux_write={1: 7}, counters={7: ("linear_attention.chunks",)})
 def _gated_delta_rule_op(q, k, v, a, b, A_log, dt_bias, stats, *, chunk=64):
-    """Gated DeltaNet's mixer core. a, b: (B, T, Hv) the decay's and the
-    step's pre-activations; g = -exp(A_log) softplus(a + dt_bias) and
-    beta = sigmoid(b) in float32. `stats` (1,) is a device counter: the
-    chunks scanned in the last step."""
+    """Gated DeltaNet's mixer core. b: (B, T, Hv) the step's
+    pre-activation, a the decay's: (B, T, Hv) with dt_bias (Hv,), one
+    decay a head, or (B, T, Hv, Dk) with dt_bias (Hv * Dk,), one a key
+    channel (Kimi Delta Attention); A_log (Hv,) either way.
+    g = -exp(A_log) softplus(a + dt_bias) and beta = sigmoid(b) in
+    float32. `stats` (1,) is a device counter: the chunks scanned in the
+    last step."""
     f32 = jnp.float32
-    g = -jnp.exp(A_log.astype(f32)) * jax.nn.softplus(
-        a.astype(f32) + dt_bias.astype(f32))
+    A = jnp.exp(A_log.astype(f32))
+    if a.ndim == 4:
+        A, dt_bias = A[:, None], dt_bias.reshape(a.shape[2:])
+    g = -A * jax.nn.softplus(a.astype(f32) + dt_bias.astype(f32))
     beta = jax.nn.sigmoid(b.astype(f32))
     o = gated_delta_rule(q, k, v, g, beta, chunk=chunk)
     chunks = q.shape[0] * (q.shape[1] // min(int(chunk), q.shape[1]))
@@ -293,5 +429,5 @@ def _rms_norm_op(x, weight, *, eps=1e-6, offset=0.0):
 
 
 @register("_contrib_gated_rms_norm")
-def _gated_rms_norm_op(x, z, weight, *, eps=1e-6):
-    return gated_rms_norm(x, z, weight, eps)
+def _gated_rms_norm_op(x, z, weight, *, eps=1e-6, activation="silu"):
+    return gated_rms_norm(x, z, weight, eps, activation)

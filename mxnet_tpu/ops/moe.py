@@ -1,9 +1,12 @@
 """A dropless expert layer that is told which experts it holds.
 
 `moe_held_ffn` routes every token over ALL the experts of the layer in
-float32 (softmax, top-k, the k weights renormalised to sum to one) and
-computes, for the experts `held_start .. held_start + E - 1` whose
-weights it is given, their part of the result:
+float32 and computes, for the experts `held_start .. held_start + E - 1`
+whose weights it is given, their part of the result. The router's rule
+is an argument (`route_top_k`): the scores are a softmax over the
+experts or a sigmoid of each; the k experts are chosen by the score, or
+by the score plus a bias that plays no part in the weights; the k
+weights are renormalised to sum to one and multiplied by `scale`:
 
     y[n] = sum over the token's chosen experts e that are held of
            w[n, e] * W_down[e] (silu(W_gate[e] x[n]) * (W_up[e] x[n]))
@@ -73,17 +76,38 @@ def _top_k_bwd(k, res, cts):
 _top_k.defvjp(_top_k_fwd, _top_k_bwd)
 
 
-def route_top_k(x, router_w, top_k):
-    """(indices (N, k) int32, weights (N, k) float32 summing to one, and
-    each expert's count of assignments (E_all,) float32). The logits, the
-    softmax and the weights are float32 whatever x's dtype."""
+_SCORES = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
+           "sigmoid": jax.nn.sigmoid}
+
+
+def route_top_k(x, router_w, top_k, score="softmax", bias=None, scale=1.0):
+    """(indices (N, k) int32, weights (N, k) float32 summing to `scale`,
+    and each expert's count of assignments (E_all,) float32). The scores
+    s are `score` of the logits, a softmax over the experts or a sigmoid
+    of each; the k experts are those with the largest s, or with the
+    largest s + bias where a `bias` (E_all,) is given, and a weight is
+    the chosen expert's s (without the bias) over the sum of the k, times
+    `scale`. The logits, the scores and the weights are float32 whatever
+    x's dtype."""
+    if score not in _SCORES:
+        raise ValueError("route_top_k: unknown score %r" % score)
     # operands as they are stored (bfloat16 values multiply exactly into
     # the float32 sum; float32 ones at full precision)
     logits = _mm(x, router_w.astype(x.dtype), (1, 1), _precision(x.dtype))
-    top_w, top_i = _top_k(jax.nn.softmax(logits, axis=-1), int(top_k))
+    s = _SCORES[score](logits)
+    if bias is None:
+        top_w, top_i = _top_k(s, int(top_k))
+    else:
+        # chosen with the bias, weighed without: the chosen columns' s by
+        # a mask (compares and sums, as the backward of `_top_k`)
+        _, top_i = _top_k(lax.stop_gradient(s + bias.astype(s.dtype)),
+                          int(top_k))
+        chosen = top_i[..., None] == jnp.arange(s.shape[-1])     # (N, k, E)
+        top_w = jnp.sum(jnp.where(chosen, s[..., None, :], 0.0), axis=-1)
     counts = jnp.sum(top_i.reshape(-1, 1) == jnp.arange(router_w.shape[0]),
                      axis=0).astype(jnp.float32)
-    return top_i, top_w / jnp.sum(top_w, axis=-1, keepdims=True), counts
+    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    return top_i, (top_w if scale == 1.0 else top_w * scale), counts
 
 
 def _tiles(top_i, counts_held, held_start, n_held, tile):
@@ -201,14 +225,16 @@ _held_products.defvjp(_held_fwd, _held_bwd)
 
 
 def moe_held_ffn(x, router_w, w_gate, w_up, w_down, top_k, held_start=0,
-                 tile=256):
+                 tile=256, score="softmax", bias=None, scale=1.0):
     """x: (N, H); router_w: (E_all, H); w_gate, w_up: (E, I, H) and
-    w_down: (E, H, I), the held experts'. Returns (y (N, H) in x's dtype,
-    rows that landed on held experts, max over mean of all experts'
-    counts)."""
+    w_down: (E, H, I), the held experts'; `score`, `bias` (E_all,) and
+    `scale` are the router's rule (`route_top_k`). Returns (y (N, H) in
+    x's dtype, rows that landed on held experts, max over mean of all
+    experts' counts)."""
     E = w_gate.shape[0]
     tile = int(tile)
-    top_i, top_w, counts = route_top_k(x, router_w, int(top_k))
+    top_i, top_w, counts = route_top_k(x, router_w, int(top_k), score, bias,
+                                       float(scale))
     counts_held = lax.dynamic_slice(counts, (int(held_start),), (E,))
     *lay, n_tiles = _tiles(top_i, counts_held, int(held_start), E, tile)
     y = _held_products(x, w_gate, w_up, w_down, top_w, tuple(lay), n_tiles,
@@ -216,14 +242,17 @@ def moe_held_ffn(x, router_w, w_gate, w_up, w_down, top_k, held_start=0,
     return y, jnp.sum(counts_held), jnp.max(counts) / jnp.mean(counts)
 
 
-def shared_expert_ffn(x, w_gate, w_up, w_down, w_sgate):
-    """sigmoid(x . w_sgate) * W_down (silu(W_gate x) * (W_up x)); the
-    matrices are (out, in); float32 sums, x's dtype between products."""
+def shared_expert_ffn(x, w_gate, w_up, w_down, w_sgate=None):
+    """sigmoid(x . w_sgate) * W_down (silu(W_gate x) * (W_up x)), or
+    without `w_sgate` the expert alone; the matrices are (out, in);
+    float32 sums, x's dtype between products."""
     prec = _precision(x.dtype)
     gate = _mm(x, w_gate, (x.ndim - 1, 1), prec)
     up = _mm(x, w_up, (x.ndim - 1, 1), prec)
     act = (jax.nn.silu(gate) * up).astype(x.dtype)
     out = _mm(act, w_down, (x.ndim - 1, 1), prec)
+    if w_sgate is None:
+        return out.astype(x.dtype)
     sg = jax.nn.sigmoid(_mm(x, w_sgate, (x.ndim - 1, 1), prec))
     return (sg * out).astype(x.dtype)
 
@@ -232,20 +261,26 @@ def shared_expert_ffn(x, w_gate, w_up, w_down, w_sgate):
           aux_write={1: 5},
           counters={5: ("moe.assignments.held", "moe.load.max_over_mean")})
 def _moe_held_ffn_op(x, router_weight, gate_weight, up_weight, down_weight,
-                     stats, *, top_k, held_start=0, tile=256):
+                     stats, *router_bias, top_k, held_start=0, tile=256,
+                     score="softmax", scale=1.0, with_bias=False):
     """The routed experts' part of a sparse layer, for the experts held
     here (see the module). x: (..., H). `stats` (2,) is a device counter:
-    the held rows of the last step and its load's max over mean."""
+    the held rows of the last step and its load's max over mean. `score`
+    and `scale` are the router's rule; `with_bias=True` adds the input
+    `router_bias` (E_all,), which is added to the scores for the choice
+    of experts alone."""
     lead = x.shape[:-1]
     y, held, load = moe_held_ffn(x.reshape(-1, x.shape[-1]), router_weight,
                                  gate_weight, up_weight, down_weight, top_k,
-                                 held_start, tile)
+                                 held_start, tile, score,
+                                 router_bias[0] if with_bias else None, scale)
     return (y.reshape(lead + (x.shape[-1],)),
             jnp.stack([held, load]).astype(stats.dtype))
 
 
 @register("_contrib_shared_expert_ffn")
 def _shared_expert_ffn_op(x, gate_weight, up_weight, down_weight,
-                          expert_gate_weight):
+                          *expert_gate_weight, gated=True):
+    """`gated=False`: no `expert_gate_weight` input, the expert alone."""
     return shared_expert_ffn(x, gate_weight, up_weight, down_weight,
-                             expert_gate_weight)
+                             expert_gate_weight[0] if gated else None)

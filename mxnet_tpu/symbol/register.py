@@ -32,6 +32,12 @@ _INPUT_SPECS = {
     "Embedding": lambda p: ["data", "weight"],
     "LeakyReLU": lambda p: (["data", "gamma"]
                             if p.get("act_type") == "prelu" else ["data"]),
+    "_contrib_moe_held_ffn": lambda p: (
+        ["x", "router_weight", "gate_weight", "up_weight", "down_weight",
+         "stats"] + (["router_bias"] if p.get("with_bias") else [])),
+    "_contrib_shared_expert_ffn": lambda p: (
+        ["x", "gate_weight", "up_weight", "down_weight"]
+        + (["expert_gate_weight"] if p.get("gated", True) else [])),
     "RNN": lambda p: (["data", "parameters", "state"]
                       + (["state_cell"] if p.get("mode", "lstm") == "lstm"
                          else [])),

@@ -115,6 +115,30 @@ def test_gated_delta_rule_qwen3_next(one_chip, for_the_chip, monkeypatch,
              qk, qk, v, rows, rows)
 
 
+def test_per_channel_delta_rule_kimi_linear(one_chip, for_the_chip):
+    # one Kimi Delta Attention layer at its published widths: B 1, T 4096,
+    # 32 heads of 128, one decay a key channel, chunks of 64, bfloat16.
+    # XLA operations alone (the kernels assume one decay a head): forward
+    # and backward compile for the chip, and the temporaries of a
+    # layer's backward stay under the 1.5 GB a 602M-parameter step has
+    # room for, with no array of every chunk's C x C systems
+    from mxnet_tpu.ops import linear_attention as la
+    qkv = ((1, 4096, 32, 128), jnp.bfloat16)
+    g, beta = ((1, 4096, 32, 128), jnp.float32), ((1, 4096, 32), jnp.float32)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (qkv, qkv, qkv, g, beta)]
+    compiled = jax.jit(jax.value_and_grad(
+        lambda *a: la.gated_delta_rule(*a, chunk=64).astype(jnp.float32)
+        .sum(), argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert "[1,32,64,64,64]" not in text and "[64,1,32,64,64]" not in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp < 1.5e9, temp
+    print("per-channel delta rule, forward and backward: %.2f GB of "
+          "temporaries" % (temp / 1e9))
+
+
 def test_layer_norm_8192x768(one_chip, for_the_chip):
     _compile(pk.pallas_layer_norm, one_chip,
              ((8192, 768), jnp.bfloat16), ((768,), jnp.float32),
