@@ -20,7 +20,8 @@ so D = U - W S0 with U = (I + A)^-1 (beta V) and W = (I + A)^-1
 float32 through four matrix products a chunk. Nothing is ever T x T.
 
 Two paths compute this, chosen from the shape and the mesh the call is
-traced with (`_kernel_shard`; counted in `linear_attention.delta.path`).
+traced with (`_kernel_shard`; counted in `linear_attention.delta.path`),
+whatever g's rank (below).
 Where the heads are whole 128-lane tiles the Pallas kernels of
 `ops/delta_rule_kernels.py` do a chunk's whole work in VMEM with the
 state in scratch across the chunks, and their backward is a second
@@ -37,12 +38,15 @@ longer factors out of the products of keys,
 
     A[t, s] = beta_t sum_d k_t[d] k_s[d] exp(c_t[d] - c_s[d])   (s < t)
 
-so that call takes a third path, `_plain_channels` (counted as `plain`):
-a `lax.scan` over the chunks whose step does one chunk's whole work
-under `jax.checkpoint`, with every exponent kept at or below zero (its
-docstring says how). It holds O(T H D) arrays and the chunks' states and
-nothing of a chunk's C x C systems beyond the chunk in flight. It is
-the baseline a kernel for a per-channel decay starts from.
+so each path has a statement of a chunk of its own for that call, chosen
+by the same rule and counted alike: the kernels
+`gated_delta_rule_channels_fwd` / `_bwd` where the shape tiles, and
+`_plain_channels` elsewhere, a `lax.scan` over the chunks whose step does
+one chunk's whole work under `jax.checkpoint`. Both keep every exponent
+at or below zero (their docstrings say how). The plain path holds
+O(T H D) arrays and the chunks' states and nothing of a chunk's C x C
+systems beyond the chunk in flight; it is the kernels' reference in the
+tests and what an odd-shaped or sharded caller gets.
 """
 from __future__ import annotations
 
@@ -77,9 +81,9 @@ DELTA_PATH = _obs.counter(
     "linear_attention.delta.path",
     "Times gated_delta_rule was traced into a program, by what its shape "
     "and the mesh chose (label path: kernel = the Pallas kernels of "
-    "ops/delta_rule_kernels.py, Dk and Dv multiples of 128 and the chunk "
-    "of a sublane tile; plain = the XLA operations and a lax.scan, which "
-    "is also what every decay given per key channel takes)")
+    "ops/delta_rule_kernels.py, for one decay a head or one a key "
+    "channel, Dk and Dv multiples of 128 and the chunk of a sublane tile; "
+    "plain = the XLA operations and a lax.scan)")
 
 
 def _kernel_shard(B, T, Dk, Dv, C, dtype):
@@ -107,7 +111,9 @@ def _kernel_shard(B, T, Dk, Dv, C, dtype):
 
 def _through_kernels(q, k, v, g, beta, C, carry_state):
     """What stays outside the kernels: the normalisation of q and k and
-    the running sum of g inside each chunk, one small XLA pass each."""
+    the running sum of g inside each chunk, one small XLA pass each. A
+    decay a key channel is summed where it lies, (B, N, C, Hv, Dk): no
+    repeat and no transpose of heads."""
     B, T, _, Dk = q.shape
     Hv, cd = v.shape[2], v.dtype
 
@@ -115,38 +121,43 @@ def _through_kernels(q, k, v, g, beta, C, carry_state):
         return x.astype(jnp.float32).transpose(0, 2, 1).reshape(
             B, Hv, T // C, C)
 
-    return delta_rule_kernels.delta_rule(
-        (_l2norm(q) * (Dk ** -0.5)).astype(cd), _l2norm(k).astype(cd), v,
-        jnp.cumsum(rows(g), axis=-1), rows(beta), carry_state)
+    qn, kn = (_l2norm(q) * (Dk ** -0.5)).astype(cd), _l2norm(k).astype(cd)
+    if g.ndim == 4:                       # a channel: where it lies
+        c = jnp.cumsum(g.astype(jnp.float32).reshape(
+            B, T // C, C, Hv, Dk), axis=2)
+    else:
+        c = jnp.cumsum(rows(g), axis=-1)
+    return delta_rule_kernels.delta_rule(qn, kn, v, c, rows(beta),
+                                         carry_state)
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk=64, carry_state=True):
     """q, k: (B, T, Hk, Dk); v: (B, T, Hv, Dv) with Hv a multiple of Hk
     (key head h // (Hv // Hk) serves value head h); beta: (B, T, Hv) and
     g: (B, T, Hv), one log decay a head, or (B, T, Hv, Dk), one a key
-    channel (the state's row d decays by exp(g[d]); `_plain_channels`,
-    counted as `plain`); float32. q and k are L2-normalised over the
-    head here and q scaled by Dk^-1/2. Returns o (B, T, Hv, Dv) in v's
-    dtype. Matrix products take their operands in v's dtype (the
-    normalised q and k too) and add up in float32; the decays, the state
-    and the triangular solve are
-    float32 (the kernels make (I + A)^-1 from float32 products at
-    `HIGHEST` instead of solving). `carry_state=False` starts every
-    chunk from a zero state: the fault the tests plant.
+    channel (the state's row d decays by exp(g[d])); float32. q and k
+    are L2-normalised over the head here and q scaled by Dk^-1/2.
+    Returns o (B, T, Hv, Dv) in v's dtype. Matrix products take their
+    operands in v's dtype (the normalised q and k too) and add up in
+    float32; the decays, the state and the triangular solve are float32
+    (the kernels make (I + A)^-1 from float32 products at `HIGHEST`
+    instead of solving, and with a decay a channel multiply the tokens
+    of one 16-row block in float32 at `HIGHEST` where the plain path
+    sums them pair by pair). `carry_state=False` starts every chunk from
+    a zero state: the fault the tests plant.
     The path is chosen from the shape and the mesh the call is traced
-    with (`_kernel_shard`) and counted in `linear_attention.delta.path`."""
+    with (`_kernel_shard`), whatever g's rank, and counted in
+    `linear_attention.delta.path`."""
     B, T, _, Dk = q.shape
     C = min(int(chunk), T)
     if T % C:
         raise ValueError("gated_delta_rule: %d tokens do not divide into "
                          "chunks of %d" % (T, C))
-    if g.ndim == 4:
-        DELTA_PATH.inc(path="plain")
-        return _plain_channels(q, k, v, g, beta, C, carry_state)
     shard = _kernel_shard(B, T, Dk, v.shape[3], C, v.dtype)
     DELTA_PATH.inc(path="plain" if shard is None else "kernel")
     if shard is None:
-        return _plain(q, k, v, g, beta, C, carry_state)
+        plain = _plain_channels if g.ndim == 4 else _plain
+        return plain(q, k, v, g, beta, C, carry_state)
     return shard(functools.partial(_through_kernels, C=C,
                                    carry_state=carry_state))(q, k, v, g, beta)
 

@@ -34,3 +34,17 @@ def _close(a, b, tol):
     scale = max(float(jnp.abs(b).max()), 1e-6)
     assert float(jnp.abs(a - b).max()) <= tol * scale, (
         float(jnp.abs(a - b).max()), scale)
+
+
+def _products(jaxpr, inside=False):
+    """Every `dot_general` inside a `pallas_call`, through the nested
+    programs."""
+    for eqn in jaxpr.eqns:
+        if inside and eqn.primitive.name == "dot_general":
+            yield eqn
+        within = inside or eqn.primitive.name == "pallas_call"
+        for v in eqn.params.values():
+            for p in (v if isinstance(v, (list, tuple)) else [v]):
+                p = getattr(p, "jaxpr", p)
+                if hasattr(p, "eqns"):
+                    yield from _products(p, within)

@@ -115,24 +115,35 @@ def test_gated_delta_rule_qwen3_next(one_chip, for_the_chip, monkeypatch,
              qk, qk, v, rows, rows)
 
 
-def test_per_channel_delta_rule_kimi_linear(one_chip, for_the_chip):
+def test_per_channel_delta_rule_kimi_linear(one_chip, for_the_chip,
+                                            monkeypatch):
     # one Kimi Delta Attention layer at its published widths: B 1, T 4096,
-    # 32 heads of 128, one decay a key channel, chunks of 64, bfloat16.
-    # XLA operations alone (the kernels assume one decay a head): forward
-    # and backward compile for the chip, and the temporaries of a
-    # layer's backward stay under the 1.5 GB a 602M-parameter step has
-    # room for, with no array of every chunk's C x C systems
+    # 32 heads of 128, one decay a key channel, chunks of 64, bfloat16:
+    # the two kernels `gated_delta_rule_channels_fwd` / `_bwd` are in the
+    # program compiled for the chip (tiling and VMEM limits of both), no
+    # `while` and no `triangular_solve` is left of the plain path, and
+    # the temporaries of a layer's backward (what the backward is handed:
+    # the tiles' states and inverses) stay under the 1.5 GB a
+    # 602M-parameter step has room for
+    from mxnet_tpu.ops import delta_rule_kernels as dk
     from mxnet_tpu.ops import linear_attention as la
+    monkeypatch.setattr(dk, "_interpret", lambda: False)
     qkv = ((1, 4096, 32, 128), jnp.bfloat16)
     g, beta = ((1, 4096, 32, 128), jnp.float32), ((1, 4096, 32), jnp.float32)
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-            for s, d in (qkv, qkv, qkv, g, beta)]
-    compiled = jax.jit(jax.value_and_grad(
+    path = la.DELTA_PATH
+    kernel0, plain0 = path.get(path="kernel"), path.get(path="plain")
+    compiled = _compile(jax.value_and_grad(
         lambda *a: la.gated_delta_rule(*a, chunk=64).astype(jnp.float32)
-        .sum(), argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+        .sum(), argnums=(0, 1, 2, 3, 4)), one_chip, qkv, qkv, qkv, g, beta)
+    assert (path.get(path="kernel"), path.get(path="plain")) \
+        == (kernel0 + 1, plain0)
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
-    assert "[1,32,64,64,64]" not in text and "[64,1,32,64,64]" not in text
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("gated_delta_rule_channels_fwd",
+                   "gated_delta_rule_channels_bwd"):
+        assert kernel in text, kernel
+    assert " while(" not in text and "triangular" not in text.lower()
+    assert "64,64]" not in text                 # no C x C array a chunk
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert 0 < temp < 1.5e9, temp
     print("per-channel delta rule, forward and backward: %.2f GB of "

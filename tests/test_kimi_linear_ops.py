@@ -113,31 +113,43 @@ def test_a_scalar_decay_broadcast_over_the_channels_is_the_scalar_path():
         _close(a, b, 5e-5)
 
 
-def test_a_per_channel_decay_takes_the_plain_path_where_a_scalar_one_takes_the_kernels():
-    """`linear_attention.delta.path` at a shape the kernels tile (D 128,
-    chunks of 64): `kernel` for one decay a head, `plain` for one a
-    channel, and the two agree."""
-    q, k, v, a, b = _randn(5, (1, 128, 1, 128), (1, 128, 1, 128),
-                           (1, 128, 1, 128), (1, 128, 1), (1, 128, 1))
-    g, beta = -0.03 * jax.nn.softplus(a), jax.nn.sigmoid(b)
-    wide = jnp.broadcast_to(g[..., None], g.shape + (128,))
+def test_a_per_channel_decay_takes_the_kernels_where_its_shape_tiles():
+    """`linear_attention.delta.path` for one decay a channel: `kernel`
+    at a shape the kernels tile (D 128, chunks of 64), alone or per
+    shard of the batch under a `dp` mesh; `plain` at D 64 and under a
+    mesh with a wide axis other than `dp`; and the two paths agree."""
+    from mxnet_tpu.parallel import make_mesh, use_mesh
     path = la.DELTA_PATH
+    counts = lambda: (path.get(path="kernel"), path.get(path="plain"))  # noqa: E731
+
+    def inputs(D):
+        q, k, v, a, b = _randn(5, (2, 128, 1, D), (2, 128, 1, D),
+                               (2, 128, 1, D), (2, 128, 1, D), (2, 128, 1))
+        return q, k, v, -0.03 * jax.nn.softplus(2.0 * a), jax.nn.sigmoid(b)
+
+    rule = lambda: jax.jit(lambda *x: gated_delta_rule(*x, chunk=64))  # noqa: E731
+    args = inputs(128)
     with HI:
-        kernel0, plain0 = path.get(path="kernel"), path.get(path="plain")
-        scalar = jax.jit(lambda *x: gated_delta_rule(*x, chunk=64))(
-            q, k, v, g, beta)
-        assert (path.get(path="kernel"), path.get(path="plain")) \
-            == (kernel0 + 1, plain0)
-        channel = jax.jit(lambda *x: gated_delta_rule(*x, chunk=64))(
-            q, k, v, wide, beta)
-        assert (path.get(path="kernel"), path.get(path="plain")) \
-            == (kernel0 + 1, plain0 + 1)
-    _close(channel, scalar, 2e-5)
+        kernel0, plain0 = counts()
+        alone = rule()(*args)
+        assert counts() == (kernel0 + 1, plain0)
+        rule()(*inputs(64))
+        assert counts() == (kernel0 + 1, plain0 + 1)
+        with use_mesh(make_mesh({"dp": 2}, jax.devices()[:2])):
+            sharded = rule()(*args)
+        assert counts() == (kernel0 + 2, plain0 + 1)
+        with use_mesh(make_mesh({"dp": 2, "tp": 2}, jax.devices()[:4])):
+            other = rule()(*args)
+        assert counts() == (kernel0 + 2, plain0 + 2)
+        plain = jax.jit(lambda *x: la._plain_channels(*x, 64, True))(*args)
+    _close(alone, plain, 2e-5)
+    _close(sharded, alone, 1e-6)
+    _close(other, plain, 1e-6)
 
 
 def test_per_channel_step_holds_no_array_of_every_chunks_squares():
-    """The lean path: outside the scan over the N chunks nothing carries
-    a chunk's C x C system or its decayed keys for every chunk at once
+    """The plain path (D 8 does not tile): outside the scan over the N
+    chunks nothing carries a chunk's C x C system or its decayed keys for every chunk at once
     (one chunk's constants may sit there), and nothing is larger than g."""
     T, C, D = 768, 32, 8
     args = _channel_inputs(T, 2, D, D, 0.03)

@@ -13,7 +13,8 @@ from mxnet_tpu.ops.attention import (blocked_causal_attention,
 from mxnet_tpu.ops.linear_attention import (causal_conv1d, gated_delta_rule,
                                             gated_rms_norm, rms_norm)
 from mxnet_tpu.ops.pallas_kernels import flash_attention, _attn_reference
-from qwen3_next_helpers import HI, _close, _randn, _value_and_grads
+from qwen3_next_helpers import (HI, _close, _products, _randn,
+                                _value_and_grads)
 
 
 def _recurrence(q, k, v, g, beta):
@@ -143,20 +144,6 @@ def test_kernel_delta_rule_in_bfloat16(B):
     for a, b in zip(g_got[3:], g_plain[3:]):
         assert a.dtype == jnp.float32
         _close(a, b, 5e-3)
-
-
-def _products(jaxpr, inside=False):
-    """Every `dot_general` inside a `pallas_call`, through the nested
-    programs."""
-    for eqn in jaxpr.eqns:
-        if inside and eqn.primitive.name == "dot_general":
-            yield eqn
-        within = inside or eqn.primitive.name == "pallas_call"
-        for v in eqn.params.values():
-            for p in (v if isinstance(v, (list, tuple)) else [v]):
-                p = getattr(p, "jaxpr", p)
-                if hasattr(p, "eqns"):
-                    yield from _products(p, within)
 
 
 def test_kernels_multiply_float32_by_float32_at_highest():

@@ -2,10 +2,13 @@
 operations and a `lax.scan`) and the Pallas kernels of
 `ops/delta_rule_kernels.py`.
 
-    chiprun -- python3 tools/delta_rule_probe.py [--shape 1,8192,16,32,128]
-        [--dtype bfloat16] [--chunk 64]
+    chiprun -- python3 tools/delta_rule_probe.py [--decay head|channel]
+        [--shape B,T,Hk,Hv,D] [--dtype bfloat16] [--chunk 64]
 
-`--shape` is B,T,Hk,Hv,D. Every time is the mean of `--iters` calls after
+`--decay head` is one log decay a head (Qwen3-Next's Gated DeltaNet,
+default shape 1,8192,16,32,128), `--decay channel` one a key channel
+(Kimi Delta Attention, g of rank 4, default shape 1,4096,32,32,128).
+Every time is the mean of `--iters` calls after
 a warm-up, fenced by `block_until_ready`, in ms. `plain` and `kernel` are
 (forward, forward + backward of a weighted sum in all five arguments) of
 `gated_delta_rule`'s two paths; `fwd_save_bwd` times the kernels one by
@@ -61,7 +64,8 @@ def _recurrence(q, k, v, g, beta):
 
     def token(S, xs):
         q_t, k_t, v_t, g_t, b_t = xs
-        S = jnp.exp(g_t)[..., None, None] * S
+        # one decay a head, or one a key channel: a row of the state
+        S = jnp.exp(g_t).reshape(g_t.shape[:2] + (-1, 1)) * S
         d = b_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", S, k_t))
         S = S + k_t[..., :, None] * d[..., None, :]
         return S, jnp.einsum("bhkv,bhk->bhv", S, q_t)
@@ -72,9 +76,13 @@ def _recurrence(q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 1)
 
 
+_SHAPES = {"head": "1,8192,16,32,128", "channel": "1,4096,32,32,128"}
+
+
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shape", default="1,8192,16,32,128")
+    ap.add_argument("--decay", choices=sorted(_SHAPES), default="head")
+    ap.add_argument("--shape", help="B,T,Hk,Hv,D; default by --decay")
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--chunk", type=int, default=64)
     ap.add_argument("--iters", type=int, default=10)
@@ -83,7 +91,9 @@ def main():
     if dev.platform != "tpu":
         raise SystemExit("delta_rule_probe: times are the chip's; found %s"
                          % dev.platform)
-    B, T, Hk, Hv, D = (int(x) for x in args.shape.split(","))
+    channel = args.decay == "channel"
+    B, T, Hk, Hv, D = (int(x) for x in
+                       (args.shape or _SHAPES[args.decay]).split(","))
     dt, C, n = jnp.dtype(args.dtype), args.chunk, args.iters
     if not dk.tiles(T, D, D, C, dt):
         raise SystemExit("delta_rule_probe: the kernels do not take this "
@@ -91,13 +101,18 @@ def main():
     keys = jax.random.split(jax.random.PRNGKey(0), 7)
     q, k = (jax.random.normal(kk, (B, T, Hk, D), dt) for kk in keys[:2])
     v, do = (jax.random.normal(kk, (B, T, Hv, D), dt) for kk in keys[2:4])
-    # the cell's decays: exp(g) 0.975-0.993 a token
-    g = -0.01 * jax.nn.softplus(jax.random.normal(keys[4], (B, T, Hv)) + 1.0)
+    if channel:     # the cell's decays: exp(g) 0.90-0.994 a token and channel
+        g = -0.05 * jax.nn.softplus(
+            1.4 * jax.random.normal(keys[4], (B, T, Hv, D)))
+    else:           # the cell's decays: exp(g) 0.975-0.993 a token
+        g = -0.01 * jax.nn.softplus(
+            jax.random.normal(keys[4], (B, T, Hv)) + 1.0)
     beta = jax.nn.sigmoid(jax.random.normal(keys[5], (B, T, Hv)))
     w = jax.random.normal(keys[6], (B, T, Hv, D), jnp.float32)
     five = (q, k, v, g, beta)
-    res = {"device": dev.device_kind, "shape": [B, T, Hk, Hv, D],
-           "dtype": args.dtype, "chunk": C, "ms": {}}
+    res = {"device": dev.device_kind, "decay": args.decay,
+           "shape": [B, T, Hk, Hv, D], "dtype": args.dtype, "chunk": C,
+           "ms": {}}
 
     def record(name, fn):
         try:
@@ -106,7 +121,8 @@ def main():
             res["ms"][name] = {"error": str(e)[:400]}
         print(name, res["ms"][name], flush=True)
 
-    plain = functools.partial(la._plain, C=C, carry_state=True)
+    plain = functools.partial(la._plain_channels if channel else la._plain,
+                              C=C, carry_state=True)
     kernel = functools.partial(la._through_kernels, C=C, carry_state=True)
     record("plain", lambda: _pair(plain, five, w, n))
     record("kernel", lambda: _pair(kernel, five, w, n))
@@ -121,7 +137,9 @@ def main():
     def parts():
         qn, kn = (la._l2norm(x).astype(dt) for x in (q, k))
         rows = lambda x: x.transpose(0, 2, 1).reshape(B, Hv, T // C, C)  # noqa: E731
-        ins = (qn, kn, v, jnp.cumsum(rows(g), -1), rows(beta))
+        c = (jnp.cumsum(g.reshape(B, T // C, C, Hv, D), 2) if channel
+             else jnp.cumsum(rows(g), -1))
+        ins = (qn, kn, v, c, rows(beta))
         fwd, save = (jax.jit(lambda *a, s=s: dk._fwd_on(
             *a, True, s, dk._interpret())) for s in (False, True))
         bwd = jax.jit(lambda *a: dk._bwd_on(*a, True, dk._interpret()))
