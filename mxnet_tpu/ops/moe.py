@@ -6,7 +6,8 @@ whose weights it is given, their part of the result. The router's rule
 is an argument (`route_top_k`): the scores are a softmax over the
 experts or a sigmoid of each; the k experts are chosen by the score, or
 by the score plus a bias that plays no part in the weights; the k
-weights are renormalised to sum to one and multiplied by `scale`:
+weights are renormalised to sum to one (over their sum plus `eps`, where
+that is given) and multiplied by `scale`:
 
     y[n] = sum over the token's chosen experts e that are held of
            w[n, e] * W_down[e] (silu(W_gate[e] x[n]) * (W_up[e] x[n]))
@@ -80,15 +81,16 @@ _SCORES = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
            "sigmoid": jax.nn.sigmoid}
 
 
-def route_top_k(x, router_w, top_k, score="softmax", bias=None, scale=1.0):
+def route_top_k(x, router_w, top_k, score="softmax", bias=None, scale=1.0,
+                eps=0.0):
     """(indices (N, k) int32, weights (N, k) float32 summing to `scale`,
     and each expert's count of assignments (E_all,) float32). The scores
     s are `score` of the logits, a softmax over the experts or a sigmoid
     of each; the k experts are those with the largest s, or with the
     largest s + bias where a `bias` (E_all,) is given, and a weight is
-    the chosen expert's s (without the bias) over the sum of the k, times
-    `scale`. The logits, the scores and the weights are float32 whatever
-    x's dtype."""
+    the chosen expert's s (without the bias) over the sum of the k (plus
+    `eps` where it is not 0: LFM2's 1e-6), times `scale`. The logits, the
+    scores and the weights are float32 whatever x's dtype."""
     if score not in _SCORES:
         raise ValueError("route_top_k: unknown score %r" % score)
     # operands as they are stored (bfloat16 values multiply exactly into
@@ -106,7 +108,8 @@ def route_top_k(x, router_w, top_k, score="softmax", bias=None, scale=1.0):
         top_w = jnp.sum(jnp.where(chosen, s[..., None, :], 0.0), axis=-1)
     counts = jnp.sum(top_i.reshape(-1, 1) == jnp.arange(router_w.shape[0]),
                      axis=0).astype(jnp.float32)
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    total = jnp.sum(top_w, axis=-1, keepdims=True)
+    top_w = top_w / (total if eps == 0.0 else total + eps)
     return top_i, (top_w if scale == 1.0 else top_w * scale), counts
 
 
@@ -225,16 +228,16 @@ _held_products.defvjp(_held_fwd, _held_bwd)
 
 
 def moe_held_ffn(x, router_w, w_gate, w_up, w_down, top_k, held_start=0,
-                 tile=256, score="softmax", bias=None, scale=1.0):
+                 tile=256, score="softmax", bias=None, scale=1.0, eps=0.0):
     """x: (N, H); router_w: (E_all, H); w_gate, w_up: (E, I, H) and
-    w_down: (E, H, I), the held experts'; `score`, `bias` (E_all,) and
-    `scale` are the router's rule (`route_top_k`). Returns (y (N, H) in
-    x's dtype, rows that landed on held experts, max over mean of all
-    experts' counts)."""
+    w_down: (E, H, I), the held experts'; `score`, `bias` (E_all,),
+    `scale` and `eps` are the router's rule (`route_top_k`). Returns
+    (y (N, H) in x's dtype, rows that landed on held experts, max over
+    mean of all experts' counts)."""
     E = w_gate.shape[0]
     tile = int(tile)
     top_i, top_w, counts = route_top_k(x, router_w, int(top_k), score, bias,
-                                       float(scale))
+                                       float(scale), float(eps))
     counts_held = lax.dynamic_slice(counts, (int(held_start),), (E,))
     *lay, n_tiles = _tiles(top_i, counts_held, int(held_start), E, tile)
     y = _held_products(x, w_gate, w_up, w_down, top_w, tuple(lay), n_tiles,
@@ -262,18 +265,19 @@ def shared_expert_ffn(x, w_gate, w_up, w_down, w_sgate=None):
           counters={5: ("moe.assignments.held", "moe.load.max_over_mean")})
 def _moe_held_ffn_op(x, router_weight, gate_weight, up_weight, down_weight,
                      stats, *router_bias, top_k, held_start=0, tile=256,
-                     score="softmax", scale=1.0, with_bias=False):
+                     score="softmax", scale=1.0, with_bias=False, eps=0.0):
     """The routed experts' part of a sparse layer, for the experts held
     here (see the module). x: (..., H). `stats` (2,) is a device counter:
-    the held rows of the last step and its load's max over mean. `score`
-    and `scale` are the router's rule; `with_bias=True` adds the input
-    `router_bias` (E_all,), which is added to the scores for the choice
-    of experts alone."""
+    the held rows of the last step and its load's max over mean. `score`,
+    `scale` and `eps` are the router's rule; `with_bias=True` adds the
+    input `router_bias` (E_all,), which is added to the scores for the
+    choice of experts alone."""
     lead = x.shape[:-1]
     y, held, load = moe_held_ffn(x.reshape(-1, x.shape[-1]), router_weight,
                                  gate_weight, up_weight, down_weight, top_k,
                                  held_start, tile, score,
-                                 router_bias[0] if with_bias else None, scale)
+                                 router_bias[0] if with_bias else None, scale,
+                                 eps)
     return (y.reshape(lead + (x.shape[-1],)),
             jnp.stack([held, load]).astype(stats.dtype))
 

@@ -1,7 +1,8 @@
 """The whole tiny Kimi Linear through the benchmark's own
-`ShardedTrainer` loops against the benchmark's plain reference, the
-counters the step publishes, and the loop that moves the net's copy of
-the weights to the host."""
+`ShardedTrainer` loop, the cell's, against the benchmark's plain
+reference, the counters the step publishes, and what that loop, which
+moves the net's copy of the weights to the host, reads beside the
+inherited loop's readings."""
 import jax
 import numpy as np
 import pytest
@@ -35,29 +36,55 @@ def test_model_against_the_plain_reference(what, _followed):
 @pytest.fixture(scope="module")
 def _followed():
     """The benchmark's own loop and reference at the tiny size: what a run
-    of the cell compares, in float32."""
+    of the cell compares, in float32, through the cell's loop
+    `sharded_trainer_net_on_host` and the follower its closing puts in
+    `reference_train.follow`'s place (put back here); beside each reading
+    that loop takes a leaf at a time, the inherited loop's reading of the
+    same trainer at the same step."""
     import harness
+    import reference_train
+    import reference_train_on_host
     import tiny
     import traffic
     from mxnet_tpu.ops import attention, linear_attention
-    cell, config, seed = tiny.cell("sharded_trainer", 2), dict(tk.CONFIG), 77
+    mod = harness.load_file("loops", "sharded_trainer_net_on_host")
+
+    class Beside(mod.Loop):
+        def first_gradient(self):
+            self.inherited = {"grad": mod._base.Loop.first_gradient(self)}
+            return super().first_gradient()
+
+        def change_norms(self):
+            self.inherited["change_norms"] = mod._base.Loop.change_norms(self)
+            return super().change_norms()
+
+    cell, config, seed = (tiny.cell("sharded_trainer_net_on_host", 2),
+                          dict(tk.CONFIG), 77)
     pool = traffic.make_pool(cell, config, seed)
     devices = jax.devices()[:1]
     path = linear_attention.DELTA_PATH
     before = (path.get(path="plain"), path.get(path="kernel"),
               attention.LATENT_LAYERS.total())
-    loop = harness.load_file("loops", "sharded_trainer").Loop(
-        cell, config, seed, devices)
+    loop = Beside(cell, config, seed, devices)
+    on_host = (all(isinstance(v, np.ndarray) for v in loop.weights.values()),
+               all(p.list_ctx()[0].device_type == "cpu"
+                   for p in loop.net.collect_params().values()))
     cell["_shapes"] = {k: tuple(v.shape) for k, v in loop.weights.items()}
     prog = harness.first_steps(loop, iter(loop.feed(traffic.cycle(pool))))
     from mxnet_tpu.observability import device_counters
     counters = device_counters.drain()
+    plain_follow = reference_train.follow
     loop.close()
+    swapped = reference_train.follow is reference_train_on_host.follow
+    try:
+        ref = harness.reference_readings(config, cell, seed, pool, devices)
+    finally:
+        reference_train.follow = plain_follow
     prog["traced"] = tuple(now - was for now, was in zip(
         (path.get(path="plain"), path.get(path="kernel"),
          attention.LATENT_LAYERS.total()), before))
-    ref = harness.reference_readings(config, cell, seed, pool, devices)
     prog["counters"] = counters
+    prog["net_on_host"] = (on_host, swapped, loop.inherited)
     return prog, ref, cell["_shapes"]
 
 
@@ -85,34 +112,18 @@ def test_the_step_program_counts_its_paths(_followed):
     assert "attention_latent_layers" in text.replace(".", "_")
 
 
-def test_net_on_host_loop_reads_what_the_plain_loop_reads(_followed,
-                                                          monkeypatch):
+def test_net_on_host_loop_reads_what_the_plain_loop_reads(_followed):
     """`sharded_trainer_net_on_host`: the net's parameters and the seed's
-    weights leave the device, every reading is the inherited loop's, and
-    closing it hands the reference's steps to the follower that keeps
-    its spare arrays on the host (undone here when the test ends)."""
-    import harness
-    import reference_train
-    import reference_train_on_host
-    import tiny
-    import traffic
-    monkeypatch.setattr(reference_train, "follow", reference_train.follow)
-    cell, config, seed = tiny.cell("sharded_trainer_net_on_host", 2), \
-        dict(tk.CONFIG), 77
-    pool = traffic.make_pool(cell, config, seed)
-    loop = harness.load_file("loops", cell["loop"]).Loop(
-        cell, config, seed, jax.devices()[:1])
-    assert all(isinstance(v, np.ndarray) for v in loop.weights.values())
-    assert all(p.list_ctx()[0].device_type == "cpu"
-               for p in loop.net.collect_params().values())
-    prog = harness.first_steps(loop, iter(loop.feed(traffic.cycle(pool))))
-    loop.close()
-    assert reference_train.follow is reference_train_on_host.follow
-    want = _followed[0]
-    assert prog["losses"] == want["losses"]
-    assert prog["change_norms"] == want["change_norms"]
-    assert all(np.array_equal(prog["grad"][k], want["grad"][k])
-               for k in want["grad"])
+    weights leave the device, every reading is the inherited loop's of the
+    same trainer, and closing it hands the reference's steps to the
+    follower that keeps its spare arrays on the host."""
+    prog = _followed[0]
+    (weights_on_host, net_on_host), swapped, inherited = prog["net_on_host"]
+    assert weights_on_host and net_on_host and swapped
+    assert prog["change_norms"] == inherited["change_norms"]
+    assert sorted(prog["grad"]) == sorted(inherited["grad"])
+    assert all(np.array_equal(prog["grad"][k], inherited["grad"][k])
+               for k in inherited["grad"])
 
 
 def test_block_refuses_lists_that_do_not_cover_the_layers():

@@ -1,7 +1,7 @@
 """Kimi Linear's mixers and router on the CPU at tiny sizes: the delta
 rule with one decay a key channel against the token-by-token
 recurrence, attention with value heads of another size than the keys',
-the sigmoid router against a loop over the experts, and the 32 shares
+the sigmoid router against a loop over the experts, and the shares
 against the uncut reference layer."""
 import jax
 import jax.numpy as jnp
@@ -167,10 +167,16 @@ def test_per_channel_step_holds_no_array_of_every_chunks_squares():
 
 def test_gated_norm_with_a_sigmoid_gate():
     x, z, w = _randn(3, (2, 6, 4, 8), (2, 6, 4, 8), (8,))
-    rms = x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5)
-    _close(gated_rms_norm(x, z, w, 1e-5, "sigmoid"),
-           rms * w * jax.nn.sigmoid(z), 1e-5)
-    _close(gated_rms_norm(x, z, w, 1e-5), rms * w * jax.nn.silu(z), 1e-5)
+
+    @jax.jit          # one program, not an op at a time
+    def both(x, z, w):
+        rms = x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5)
+        return (gated_rms_norm(x, z, w, 1e-5, "sigmoid"),
+                rms * w * jax.nn.sigmoid(z), gated_rms_norm(x, z, w, 1e-5),
+                rms * w * jax.nn.silu(z))
+    sig, sig_want, silu, silu_want = both(x, z, w)
+    _close(sig, sig_want, 1e-5)
+    _close(silu, silu_want, 1e-5)
     with pytest.raises(ValueError):
         gated_rms_norm(x, z, w, 1e-5, "tanh")
 
@@ -212,7 +218,8 @@ def test_blocked_backward_with_wider_keys_holds_no_square():
     assert "%d,%d]" % (T, T) not in text and "%d,%d]" % (block, T) in text
     assert LATENT_LAYERS.total() == latent0 + 1
     same = _randn(2, (1, T, 2, 8))[0]
-    blocked_causal_attention(same, same, same, block_q=block)
+    jax.eval_shape(lambda x: blocked_causal_attention(x, x, x, block_q=block),
+                   same)
     assert LATENT_LAYERS.total() == latent0 + 1      # one head size: not counted
 
 
@@ -245,6 +252,10 @@ def _loop_over_experts(x, rw, wg, wu, wd, b, start, held, top=TOP):
     return y
 
 
+# the plain router's choices, one compiled program (not an op at a time)
+_ROUTED = jax.jit(lambda x, rw, b: _sigmoid_router(x, rw, b, TOP)[0])
+
+
 def test_sigmoid_router_chooses_with_the_bias_and_weighs_without():
     x, rw, _, _, _, b = _expert_weights()
     with HI:
@@ -252,7 +263,8 @@ def test_sigmoid_router_chooses_with_the_bias_and_weighs_without():
             x, rw, TOP, "sigmoid", b, SCALE))(x, rw, b)
         plain_i, _, _ = jax.jit(lambda x, rw: route_top_k(
             x, rw, TOP, "sigmoid"))(x, rw)
-        want_i, want_w = _sigmoid_router(x, rw, b, TOP)
+        want_i, want_w = jax.jit(_sigmoid_router, static_argnums=3)(
+            x, rw, b, TOP)
     assert bool((jnp.sort(top_i, -1) == jnp.sort(want_i, -1)).all())
     # the bias moves the choice
     assert bool((jnp.sort(top_i, -1) != jnp.sort(plain_i, -1)).any())
@@ -274,7 +286,7 @@ def test_sigmoid_routed_layer_matches_a_loop_over_experts(start, held, tile):
         x, rw, a, b_, c, TOP, start, tile, "sigmoid", b, SCALE)
     with HI:
         y, rows, _ = jax.jit(layer)(x, rw, wg[cut], wu[cut], wd[cut])
-        top_i, _ = _sigmoid_router(x, rw, b, TOP)
+        top_i = _ROUTED(x, rw, b)
         got = _value_and_grads(lambda *a: layer(*a)[0],
                                (x, rw, wg[cut], wu[cut], wd[cut]))[1]
         ref, want = _value_and_grads(
@@ -295,8 +307,9 @@ def test_no_token_is_dropped_when_the_bias_sends_every_token_to_one_expert():
     with HI:
         y, rows, load = jax.jit(lambda *a: moe_held_ffn(
             *a, TOP, 0, 8, "sigmoid", b, SCALE))(x, rw, wg[:8], wu[:8], wd[:8])
-        top_i, _ = _sigmoid_router(x, rw, b, TOP)
-        ref = _loop_over_experts(x, rw, wg, wu, wd, b, 0, 8)
+        top_i = _ROUTED(x, rw, b)
+        ref = jax.jit(_loop_over_experts, static_argnums=(6, 7))(
+            x, rw, wg, wu, wd, b, 0, 8)
     assert bool(jnp.all(jnp.any(top_i == 2, -1))) and float(load) > 5
     assert float(rows) >= N
     _close(y, ref, 2e-5)
@@ -317,13 +330,16 @@ def test_default_router_is_the_softmax_one_bit_for_bit():
            1e-5)
 
 
-def test_the_32_shares_add_up_to_the_uncut_reference_layer():
-    """256 experts over 32 chips, 8 held on each: the parts that the
-    shares give, with the ungated shared expert counted once, are what
-    the plain reference's layer gives with every expert held."""
+def test_the_shares_add_up_to_the_uncut_reference_layer():
+    """The cell's 8 experts held a chip and 8 chosen a token, at an eighth
+    of the router's width (32 experts over 4 chips, where the cell has
+    256 over 32; each share is a program's worth of loop to compile): the
+    parts that the shares give, with the ungated shared expert counted
+    once, are what the plain reference's layer gives with every expert
+    held."""
     import qwen3_next_helpers  # noqa: F401  (the benchmark's path)
     from reference import kimi_linear_48b_a3b as ref
-    experts, held, top = 256, 8, 8
+    experts, held, top = 32, 8, 8
     x, rw, wg, wu, wd, b = _expert_weights(11, experts)
     sg, su, sd = _randn(12, (I, H), (I, H), (H, I))
     p = {"l2_moe_router_weight": rw, "l2_moe_router_bias": b,

@@ -382,6 +382,13 @@ case("_contrib_moe_held_ffn",
 case("_contrib_shared_expert_ffn",
      [_q((3, 8)), _q((4, 8)), _q((4, 8)), _q((8, 4)), _q((1, 8))],
      wrt=(0, 1, 2, 3, 4), atol=2e-2)
+# LFM2's gated short convolution (PR 39): a generator of its own, so that
+# the cases above keep their inputs; batch 2, three taps
+_S = np.random.RandomState(39)
+case("_contrib_short_conv",
+     [_S.uniform(-1, 1, shape).astype("float32")
+      for shape in ((2, 5, 4), (12, 4), (4, 3), (4, 4))],
+     wrt=(0, 1, 2, 3), atol=2e-2)
 
 EXEMPT_NONFLOAT_OUTPUT = {
     "argmax", "argmin", "argsort", "topk", "sort",  # sort: permutation —

@@ -181,17 +181,22 @@ def test_unit_lower_inverse_on_correlated_keys(C, L):
     thousandfold."""
     from mxnet_tpu.ops.delta_rule_kernels import _unit_lower_inverse
     noise, = _randn(C, (L, 128))
-    k = 1.0 + 0.05 * noise
-    k = k / jnp.linalg.norm(k, axis=1, keepdims=True)
     t, s = np.indices((L, L))
     pattern = (t // C == s // C) & (s < t)
     c = -0.005 * (np.arange(L) % C + 1.0)
-    A = jnp.where(pattern, 0.9 * jnp.exp(c[:, None] - c[None, :])
-                  * jnp.dot(k, k.T, precision="highest"), 0.0)
-    assert float(A[1, 0]) > 0.85
-    inv = jax.jit(lambda a: _unit_lower_inverse(a, C))(A)
     eye = jnp.eye(L)
-    residual = jnp.dot(eye + A, inv, precision="highest") - eye
+
+    @jax.jit          # one program, not an op at a time
+    def inverse_and_residual(noise):
+        k = 1.0 + 0.05 * noise
+        k = k / jnp.linalg.norm(k, axis=1, keepdims=True)
+        A = jnp.where(pattern, 0.9 * jnp.exp(c[:, None] - c[None, :])
+                      * jnp.dot(k, k.T, precision="highest"), 0.0)
+        inv = _unit_lower_inverse(A, C)
+        return A, inv, jnp.dot(eye + A, inv, precision="highest") - eye
+
+    A, inv, residual = inverse_and_residual(noise)
+    assert float(A[1, 0]) > 0.85
     assert float(jnp.abs(residual).max()) < 2e-6, float(jnp.abs(residual).max())
     _close(inv, jnp.linalg.inv(np.asarray(eye + A, np.float64)), 2e-6)
     assert not bool(jnp.where(t // C == s // C, 0.0, inv).any())
@@ -321,11 +326,16 @@ def test_causal_convolution_and_norms():
         for j in range(4):
             if t - 3 + j >= 0:
                 want[:, t] += wn[:, j] * xn[:, t - 3 + j]
-    _close(causal_conv1d(x, w), jnp.asarray(want), 1e-5)
-    _close(causal_conv1d(x, w, "silu"), jax.nn.silu(jnp.asarray(want)), 1e-5)
-    rms = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)
-    _close(rms_norm(x, g, offset=1.0), rms * (1 + g), 1e-5)
-    _close(gated_rms_norm(x, z, g), rms * g * jax.nn.silu(z), 1e-5)
+
+    @jax.jit          # one program, not an op at a time
+    def all_four(x, w, z, g, want):
+        rms = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)
+        return ((causal_conv1d(x, w), want),
+                (causal_conv1d(x, w, "silu"), jax.nn.silu(want)),
+                (rms_norm(x, g, offset=1.0), rms * (1 + g)),
+                (gated_rms_norm(x, z, g), rms * g * jax.nn.silu(z)))
+    for got, wanted in all_four(x, w, z, g, jnp.asarray(want)):
+        _close(got, wanted, 1e-5)
 
 
 # -- attention ---------------------------------------------------------------
@@ -366,7 +376,7 @@ def test_blocked_backward_holds_no_square():
 
 def test_rotary_turns_the_first_dimensions_only():
     (x,) = _randn(5, (1, 6, 2, 16))
-    y = rotary_embedding(x, 8, 1e4)
+    y = jax.jit(lambda x: rotary_embedding(x, 8, 1e4))(x)
     assert jnp.array_equal(y[..., 8:], x[..., 8:])
     assert jnp.array_equal(y[:, 0], x[:, 0])            # position 0: no turn
     _close(jnp.sum(y * y, -1), jnp.sum(x * x, -1), 1e-5)   # a rotation

@@ -2,7 +2,7 @@
 shapes alone: no chip, no weights, nothing runs.
 
     JAX_PLATFORMS=cpu python3 tools/fit_probe.py benchmark/configs/<name>.json
-        [--mode float32] [--topology v5e:2x2]
+        [--mode float32] [--topology v5e:2x2] [--batch 1]
 
 Compiles, with the TPU's compiler for a described chip (the
 `on-chip-measurement` guide's third rehearsal), the three programs that
@@ -58,6 +58,8 @@ def main(argv=None):
     ap.add_argument("config")
     ap.add_argument("--mode", default="float32")
     ap.add_argument("--topology", default="v5e:2x2")
+    ap.add_argument("--batch", type=int, default=1,
+                    help="sequences a step (the cell's `batch`)")
     args = ap.parse_args(argv)
     with open(args.config) as f:
         config = json.load(f)
@@ -85,8 +87,8 @@ def main(argv=None):
     spec = config["input"]
     if spec["kind"] != "tokens":
         sys.exit("fit_probe: only token inputs are described here")
-    data, label = described((1, spec["length"]), jnp.int32), \
-        described((1, spec["length"]))
+    data, label = described((args.batch, spec["length"]), jnp.int32), \
+        described((args.batch, spec["length"]))
     loss = getattr(gluon.loss, config["loss"])()(
         net(symbol.var("data")), symbol.var("label"))
     fn, arg_names, aux_names, _ = build_graph_fn(loss._entries, "train")
