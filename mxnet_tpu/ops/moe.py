@@ -14,12 +14,24 @@ that is given) and multiplied by `scale`:
 
 What the absent experts would add is left out; nothing stands in for the
 chips that hold them. No token is dropped: the assignments that land on
-the held range are sorted by expert, each expert's run is cut into tiles
-of `tile` rows, and a loop over the tiles that hold a row (its trip count
-comes from the data, every shape is static) gathers a tile's rows,
-multiplies them by that one expert's matrices and adds the weighted
-result back to the tokens' rows. The backward pass is the same
-loop with the tile's products transposed. `parallel/moe.py` is the other
+the held range are sorted by expert and cut into tiles of one expert
+each, and two paths compute them, chosen from what the op sees when it
+is traced (`_kernels_take`; counted in `moe.held.path`):
+
+- the Pallas kernels of `ops/moe_kernels.py`, on a TPU where x and the
+  matrices are bfloat16 and H and I whole 128-lane tiles with a chunk
+  of I that fits the core's VMEM: one grouped pass over the held rows a
+  direction, an expert's matrices read once for its consecutive tiles
+  and its weight gradients summed in VMEM;
+- everywhere else the plain loop: the tiles are `tile` rows, and a loop
+  over the tiles that hold a row (its trip count comes from the data,
+  every shape is static) gathers a tile's rows, multiplies them by that
+  one expert's matrices and adds the weighted result back to the
+  tokens' rows; the backward pass is the same loop with the tile's
+  products transposed.
+
+Both take their operands in x's dtype and sum in float32, with the same
+rounding points. `parallel/moe.py` is the other
 kind of layer: a fixed capacity, tokens over it dropped, a dispatch
 tensor, experts exchanged over a mesh axis.
 """
@@ -32,6 +44,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..observability import registry as _obs
+from . import moe_kernels
 from .linear_attention import _precision
 from .registry import register
 
@@ -113,6 +127,14 @@ def route_top_k(x, router_w, top_k, score="softmax", bias=None, scale=1.0,
     return top_i, (top_w if scale == 1.0 else top_w * scale), counts
 
 
+def _held_key(top_i, held_start, n_held):
+    """Each flat assignment's (token * k + choice) held expert, n_held
+    where it is not held."""
+    N, k = top_i.shape
+    local = top_i.reshape(N * k) - held_start
+    return jnp.where((local >= 0) & (local < n_held), local, n_held)
+
+
 def _tiles(top_i, counts_held, held_start, n_held, tile):
     """Lay the held assignments out in tiles of one expert each. Returns
     (order, first, tile_expert, tile_first, counts, n_tiles): the flat
@@ -124,11 +146,9 @@ def _tiles(top_i, counts_held, held_start, n_held, tile):
     expert's count goes: a contiguous slice of `order`."""
     N, k = top_i.shape
     E = n_held
-    local = top_i.reshape(N * k) - held_start
-    key = jnp.where((local >= 0) & (local < E), local, E)
     # padded by a tile: the last tile's slice may run past the end
-    order = jnp.pad(jnp.argsort(key, stable=True).astype(jnp.int32),
-                    (0, tile))
+    order = jnp.pad(jnp.argsort(_held_key(top_i, held_start, E),
+                                stable=True).astype(jnp.int32), (0, tile))
     counts = counts_held.astype(jnp.int32)
     first = jnp.cumsum(counts) - counts
     tiles_e = -(-counts // tile)
@@ -227,6 +247,75 @@ def _held_bwd(tile, res, dy):
 _held_products.defvjp(_held_fwd, _held_bwd)
 
 
+HELD_PATH = _obs.counter(
+    "moe.held.path",
+    "Times _contrib_moe_held_ffn was traced into a program, a layer each, "
+    "by what its shape chose (label path: kernel = the Pallas kernels of "
+    "ops/moe_kernels.py, on a TPU with bfloat16 x and matrices, H and I "
+    "multiples of 128, a chunk of I that fits the core's VMEM and no mesh "
+    "axis the call is not already inside; "
+    "plain = the loop over tiles of `tile` rows)")
+
+
+def _kernels_take(x, w_gate, w_up, w_down):
+    """Whether the kernels take this call: on a TPU (where the kernels
+    are not interpreted), x and the three matrices bfloat16 with H and I
+    whole 128-lane tiles whose backward fits the VMEM, and the arrays
+    one device's (no mesh, or traced inside a shard_map over every mesh
+    axis wider than one). A sharded batch keeps the plain loop, which
+    XLA partitions: the layout of the held rows spans the batch."""
+    from ..parallel.mesh import current_mesh
+    from .pallas_kernels import _axis_bound
+    I, H = w_gate.shape[1:]
+    if moe_kernels._interpret() or not (
+            moe_kernels.tiles(H, I, x.dtype)
+            and all(a.dtype == x.dtype for a in (w_gate, w_up, w_down))):
+        return False
+    mesh = current_mesh()
+    wide = [a for a in mesh.axis_names if mesh.shape[a] > 1] if mesh else []
+    return all(_axis_bound(a) for a in wide)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _held_kernels(x, wg, wu, wd, scalars, w, R):
+    """`_held_products` through the kernels: w is each row slot's routing
+    weight (`moe_kernels.layout`), whose cotangent JAX carries back to
+    top_w through the layout."""
+    return moe_kernels.held_fwd(x, wg, wu, wd, scalars, w, R,
+                                moe_kernels._interpret())
+
+
+def _kernels_fwd(x, wg, wu, wd, scalars, w, R):
+    return (_held_kernels(x, wg, wu, wd, scalars, w, R),
+            (x, wg, wu, wd, scalars, w))
+
+
+def _kernels_bwd(R, res, dy):
+    x, wg, wu, wd, scalars, w = res
+    dx, dwg, dwu, dwd, dw = moe_kernels.held_bwd(
+        x, wg, wu, wd, scalars, w, dy, R, moe_kernels._interpret())
+    return dx, dwg, dwu, dwd, None, dw.reshape(w.shape)
+
+
+_held_kernels.defvjp(_kernels_fwd, _kernels_bwd)
+
+
+def _held(x, w_gate, w_up, w_down, top_i, top_w, counts_held, held_start,
+          tile, kernels):
+    """y (N, H) of the routed assignments top_i, top_w (N, k) on the held
+    experts, counts_held (E,) theirs: through the kernels, or the loop
+    over tiles of `tile` rows."""
+    E = w_gate.shape[0]
+    if kernels:
+        R = moe_kernels.shape(x.shape[1], w_gate.shape[1], True)[0]
+        scalars, w = moe_kernels.layout(_held_key(top_i, held_start, E),
+                                        counts_held, top_w, R)
+        return _held_kernels(x, w_gate, w_up, w_down, scalars, w, R)
+    *lay, n_tiles = _tiles(top_i, counts_held, held_start, E, tile)
+    return _held_products(x, w_gate, w_up, w_down, top_w, tuple(lay),
+                          n_tiles, tile)
+
+
 def moe_held_ffn(x, router_w, w_gate, w_up, w_down, top_k, held_start=0,
                  tile=256, score="softmax", bias=None, scale=1.0, eps=0.0):
     """x: (N, H); router_w: (E_all, H); w_gate, w_up: (E, I, H) and
@@ -239,9 +328,10 @@ def moe_held_ffn(x, router_w, w_gate, w_up, w_down, top_k, held_start=0,
     top_i, top_w, counts = route_top_k(x, router_w, int(top_k), score, bias,
                                        float(scale), float(eps))
     counts_held = lax.dynamic_slice(counts, (int(held_start),), (E,))
-    *lay, n_tiles = _tiles(top_i, counts_held, int(held_start), E, tile)
-    y = _held_products(x, w_gate, w_up, w_down, top_w, tuple(lay), n_tiles,
-                       tile)
+    kernels = _kernels_take(x, w_gate, w_up, w_down)
+    HELD_PATH.inc(path="kernel" if kernels else "plain")
+    y = _held(x, w_gate, w_up, w_down, top_i, top_w, counts_held,
+              int(held_start), tile, kernels)
     return y, jnp.sum(counts_held), jnp.max(counts) / jnp.mean(counts)
 
 

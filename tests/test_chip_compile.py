@@ -150,6 +150,42 @@ def test_per_channel_delta_rule_kimi_linear(one_chip, for_the_chip,
           "temporaries" % (temp / 1e9))
 
 
+@pytest.mark.parametrize("cell", ["lfm2", "qwen3next", "lfm2_64mib"])
+def test_held_expert_layer(one_chip, for_the_chip, monkeypatch, cell):
+    # one sparse layer of `lfm2_moe_train_b2_t4096` (N 8192, H 2048,
+    # I 1792, 8 held of 32, top-4) or of `qwen3next_train_b1_t8192`
+    # (I 512, 16 held of 512, top-10), bfloat16: the op's rule takes the
+    # kernels (`moe.held.path`), and `moe_held_fwd`, `moe_held_bwd` and
+    # the row-layout copies `moe_held_rows` compile for the chip (tiling,
+    # the row DMAs, VMEM limits) with no `while` of the plain loop left;
+    # lfm2_64mib: LFM2's layer in the chunks of I and the VMEM limit of a
+    # core of 64 MiB
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.ops import moe_kernels as mk
+    monkeypatch.setattr(mk, "_interpret", lambda: False)
+    if cell == "lfm2_64mib":
+        monkeypatch.setattr(mk, "_vmem_capacity", lambda: 64 * 2 ** 20)
+    I, E_all, E, k, score = {"qwen3next": (512, 512, 16, 10, "softmax")}.get(
+        cell, (1792, 32, 8, 4, "sigmoid"))
+    bf = jnp.bfloat16
+    path = moe.HELD_PATH
+    kernel0, plain0 = path.get(path="kernel"), path.get(path="plain")
+    compiled = _compile(jax.value_and_grad(
+        lambda x, *w: moe.moe_held_ffn(x, *w, k, 0, 256, score)[0]
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)), one_chip,
+        ((8192, 2048), bf), ((E_all, 2048), jnp.float32),
+        ((E, I, 2048), bf), ((E, I, 2048), bf), ((E, 2048, I), bf))
+    assert (path.get(path="kernel"), path.get(path="plain")) \
+        == (kernel0 + 1, plain0)
+    text = compiled.as_text()
+    for kernel in ("moe_held_fwd", "moe_held_bwd", "moe_held_rows"):
+        assert kernel in text, kernel
+    assert " while(" not in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp < 0.5e9, temp
+    print("held experts, %s: %.2f GB of temporaries" % (cell, temp / 1e9))
+
+
 def test_layer_norm_8192x768(one_chip, for_the_chip):
     _compile(pk.pallas_layer_norm, one_chip,
              ((8192, 768), jnp.bfloat16), ((768,), jnp.float32),

@@ -69,10 +69,10 @@ def main(argv=None):
     import reference_train
     from mxnet_tpu import gluon, symbol
     from mxnet_tpu.graph import build_graph_fn
-    from mxnet_tpu.ops import delta_rule_kernels, pallas_kernels
+    from mxnet_tpu.ops import delta_rule_kernels, moe_kernels, pallas_kernels
     from mxnet_tpu.parallel import data_parallel
     jax.config.update("jax_enable_compilation_cache", False)
-    for kernels in (pallas_kernels, delta_rule_kernels):
+    for kernels in (pallas_kernels, delta_rule_kernels, moe_kernels):
         kernels._interpret = lambda: False         # compiled, not interpreted
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=args.topology)
