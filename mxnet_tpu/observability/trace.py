@@ -24,7 +24,13 @@ and step-scoped* tracing plane on top:
   rank 1's land in the SAME merged trace without any wire protocol;
 - `device_annotation()` wraps device dispatch in a
   ``jax.profiler.TraceAnnotation`` named by the trace id, so host
-  spans line up with the XLA profiler timeline.
+  spans line up with the XLA profiler timeline;
+- a **garbage collection** of generation 1 or 2 is a `gc` span under
+  whatever span was open on the thread it stopped (generation 0 is
+  summed into the next step root's `gc0` / `gc0_ms`), and every step
+  root carries the OS's account of its thread (`cpu_ms`, `nvcsw`,
+  `nivcsw`, `majflt`, `minflt`): what a stalled step was doing is in
+  the ring (docs/observability.md "Step spans").
 
 Env knobs (resolved once a step or request, where a root context is
 made — `step_trace_context`, `TraceContext.new`/`from_traceparent`, a
@@ -48,6 +54,7 @@ carries (and echoes) its trace id — it just writes no spans.
 from __future__ import annotations
 
 import contextlib
+import gc
 import hashlib
 import itertools
 import json
@@ -57,8 +64,14 @@ import threading
 import time
 from collections import deque
 
+try:
+    import resource as _resource
+except ImportError:             # not a Unix: steps carry no OS account
+    _resource = None
+
 from .. import profiler as _prof
 from ..base import getenv
+from .registry import counter as _counter
 
 __all__ = ["TraceContext", "trace_span", "record_span", "current",
            "capture", "attached", "detach", "device_annotation",
@@ -397,6 +410,112 @@ def trace_stats():
 _INHERIT = object()
 
 
+# -- garbage collections as spans ----------------------------------------
+# The collector calls `_on_gc` at the start and the stop of every
+# collection, on the thread whose allocation set it off, while that
+# thread may hold any lock (the ring's, the shard's, a registry's:
+# `record_span`'s json.dumps allocates). So the callback takes no lock
+# and does one thing, queue a tuple; `_drain_gc` makes the spans at the
+# next `record_span` or `StepRoot.end`. Collections never overlap, so
+# one start stamp serves every thread.
+_gc_t0 = [0.0]
+_gc_pending = deque(maxlen=4096)  # (gen, t0, t1, collected, tid, ctx)
+_gc0_pending = deque(maxlen=4096)  # seconds of each generation-0 one
+_stepping = [False]     # a step root has opened: the loop has begun
+GC_COLLECTIONS = _counter(
+    "host.gc.collections",
+    "Garbage collections while the trace plane is on (label generation)")
+GC_SECONDS = _counter(
+    "host.gc.seconds",
+    "Seconds in garbage collection while the trace plane is on (label "
+    "generation)")
+
+
+def _on_gc(phase, info):
+    if not _cfg.on:
+        return
+    if phase == "start":
+        _gc_t0[0] = time.perf_counter()
+    elif _gc_t0[0]:
+        _gc_pending.append((info["generation"], _gc_t0[0],
+                            time.perf_counter(), info["collected"],
+                            threading.get_ident(),
+                            getattr(_tls, "ctx", None)))
+        _gc_t0[0] = 0.0
+
+
+gc.callbacks.append(_on_gc)
+
+
+def _drain_gc():
+    """The queued collections: generations 1 and 2 each a `gc` span
+    under the context open on its thread when it ran (so self time and
+    idle-by-span credit it to the span it interrupted), or a root of
+    its own where none was (once a step root has opened: before, a
+    collection with no context is the imports' or the set-up's, counted
+    and not kept); generation 0 summed for the next step root. Counted
+    in `host.gc.collections` / `.seconds`."""
+    counts = {}
+    while _gc_pending:
+        try:
+            gen, t0, t1, collected, tid, ctx = _gc_pending.popleft()
+        except IndexError:          # another thread drained the last
+            break
+        n = counts.setdefault(gen, [0, 0.0])
+        n[0] += 1
+        n[1] += t1 - t0
+        if gen == 0:
+            _gc0_pending.append(t1 - t0)
+            continue
+        parent = None if ctx is None else ctx.span_id
+        if ctx is None:
+            if not _stepping[0]:
+                continue            # no loop yet: imports, set-up
+            ctx = TraceContext(_new_id(16), None, True)
+        _record("gc", ctx, t0, t1, parent, None, tid,
+                {"generation": gen, "collected": collected})
+    for gen, (n, secs) in counts.items():
+        GC_COLLECTIONS.inc(n, generation=str(gen))
+        GC_SECONDS.inc(secs, generation=str(gen))
+
+
+def _gc0_totals():
+    """(count, ms) of the generation-0 collections drained so far."""
+    n, secs = 0, 0.0
+    while _gc0_pending:
+        try:
+            secs += _gc0_pending.popleft()
+        except IndexError:
+            break
+        n += 1
+    return n, 1e3 * secs
+
+
+# -- the OS's account of a step ------------------------------------------
+_RUSAGE_WHO = (getattr(_resource, "RUSAGE_THREAD", None)
+               or getattr(_resource, "RUSAGE_SELF", None))
+
+
+def _rusage():
+    """This thread's resource usage (the process's where the OS keeps
+    none a thread), or None: one system call."""
+    return None if _resource is None else _resource.getrusage(_RUSAGE_WHO)
+
+
+def _os_account(a, b):
+    """The step root's attrs from two readings: CPU ms of the thread,
+    voluntary switches (it blocked: the runtime, a lock, the GIL),
+    involuntary ones (the OS took its CPU), major and minor faults."""
+    if a is None or b is None:
+        return {}
+    return {"cpu_ms": 1e3 * (b.ru_utime - a.ru_utime
+                             + b.ru_stime - a.ru_stime),
+            "nvcsw": b.ru_nvcsw - a.ru_nvcsw,
+            "nivcsw": b.ru_nivcsw - a.ru_nivcsw,
+            "majflt": b.ru_majflt - a.ru_majflt,
+            "minflt": b.ru_minflt - a.ru_minflt}
+
+
 def record_span(name, ctx, t0, t1, parent_id=_INHERIT, span_id=None,
                 **attrs):
     """Record one finished span (perf_counter stamps) into the ring +
@@ -409,7 +528,17 @@ def record_span(name, ctx, t0, t1, parent_id=_INHERIT, span_id=None,
     raises into the traced path. The record keeps the perf stamp
     (`t0`) beside the wall time derived from it (`ts`), so a reader
     can lay the span on any clock that ticks with `perf_counter`.
-    Reads no environment: the switches are those of the last root."""
+    Reads no environment: the switches are those of the last root.
+    First turns the collections queued since the last record into
+    spans (`_drain_gc`)."""
+    if _gc_pending:
+        _drain_gc()
+    return _record(name, ctx, t0, t1, parent_id, span_id, None, attrs)
+
+
+def _record(name, ctx, t0, t1, parent_id, span_id, tid, attrs):
+    """`record_span` without the drain; `tid` None is the caller's
+    thread."""
     cfg = _cfg
     if ctx is None or not ctx.sampled or not cfg.on:
         return None
@@ -421,7 +550,7 @@ def record_span(name, ctx, t0, t1, parent_id=_INHERIT, span_id=None,
            "t0": t0, "ts": _CLOCK_WALL + (t0 - _CLOCK_PERF),
            "step_time": t1 - t0 if t1 > t0 else 0.0,
            "rank": cfg.rank, "pid": _PID,
-           "tid": threading.get_ident() & 0xffff}
+           "tid": (threading.get_ident() if tid is None else tid) & 0xffff}
     if attrs:
         rec.update({k: v for k, v in attrs.items() if v is not None})
     with _ring_lock:
@@ -516,22 +645,30 @@ class StepRoot:
     forward and backward of a Gluon loop) records under the iteration
     it belongs to, with that step's trace id. `begin` at the entry of
     `step()`, `end` at its return; a step that raised never reached
-    `end`, and the next `begin` goes on under the same root."""
+    `end`, and the next `begin` goes on under the same root.
 
-    __slots__ = ("source", "_ctx", "_child", "_t0", "_step")
+    A recorded root carries what the thread's OS account says of the
+    iteration (`_os_account`: one `getrusage` an iteration, the reading
+    at `end` the next root's baseline) and the generation-0 collections
+    drained since the last root (`gc0`, `gc0_ms`)."""
+
+    __slots__ = ("source", "_ctx", "_child", "_t0", "_step", "_ru")
 
     def __init__(self, source):
         self.source = source
         self._ctx = self._child = None
-        self._t0 = self._step = None
+        self._t0 = self._step = self._ru = None
 
-    def _open(self, step, t0):
+    def _open(self, step, t0, ru=None):
         ctx = step_trace_context(self.source, step)
         self._ctx, self._t0, self._step = ctx, t0, step
         if ctx is not None and ctx.sampled:
             self._child = TraceContext(ctx.trace_id, _new_id(8), True)
+            self._ru = ru if ru is not None else _rusage()
+            _stepping[0] = True
         else:
             self._child = ctx       # identity without records, or None
+            self._ru = None
         _tls.ctx = self._child
 
     @property
@@ -552,12 +689,17 @@ class StepRoot:
         """Close the open root and open iteration `next_step`'s where
         this one ends."""
         now = time.perf_counter()
-        ctx = self._ctx
+        ctx, ru = self._ctx, None
         if ctx is not None and ctx.sampled:
+            ru = _rusage()
+            if _gc_pending:
+                _drain_gc()
+            gc0, gc0_ms = _gc0_totals()
             record_span("step", ctx, self._t0, now, parent_id=None,
                         span_id=self._child.span_id, step=self._step,
-                        source=self.source)
-        self._open(next_step, now)
+                        source=self.source, gc0=gc0, gc0_ms=gc0_ms,
+                        **_os_account(self._ru, ru))
+        self._open(next_step, now, ru)
 
 
 def device_annotation(ctx=None, name=None):

@@ -757,6 +757,20 @@ class ShardedTrainer:
             out_shardings=(param_sh, aux_sh, opt_sh, res_sh, rep, rep),
             donate_argnums=(0, 1, 2, 3))
 
+    def _count_leaves(self, inputs, key):
+        """`step.launch`'s attrs: the arrays the step program takes and
+        the ones it returns, counted once when it is built (the call's
+        host cost grows with the buffers it hands out)."""
+        state = [self._params, self._aux, self._opt_state]
+        extra = 2                                   # the loss and `ok`
+        if self._grad_compression is not None:
+            state.append(self._gc_residuals)
+        else:
+            extra += len(self._counter_vars)
+        n = len(jax.tree.leaves(state))
+        return {"leaves_in": n + len(jax.tree.leaves((inputs, key))),
+                "leaves_out": n + extra}
+
     def step(self, *batch_and_labels):
         """Run one fused train step; returns the scalar loss NDArray."""
         # the iteration's root and the three spans every trainer's step
@@ -778,17 +792,21 @@ class ShardedTrainer:
                 ndims[n] = arr.ndim
                 inputs[n] = jax.device_put(
                     arr, self._input_sharding(n, arr.ndim))
-            if self._step_fn is None:
+            built = self._step_fn is None
+            if built:
                 self._input_ndims = ndims
                 if self._grad_compression is not None:
                     self._build_step_compressed()
                 else:
                     self._build_step()
             key = _random.next_key() if self._needs_rng else None
+            if built:
+                self._leaves = self._count_leaves(inputs, key)
         # trace (first call) under this trainer's mesh so mesh-aware ops
         # (contrib.RingAttention / contrib.MoEFFN) pick their sp/ep paths
         from .mesh import use_mesh
-        with use_mesh(self._mesh), trace_span("step.launch"):
+        with use_mesh(self._mesh), trace_span("step.launch",
+                                              **self._leaves):
             counters = None
             if self._grad_compression is not None:
                 (self._params, self._aux, self._opt_state,

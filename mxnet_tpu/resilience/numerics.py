@@ -74,6 +74,7 @@ import numpy as np
 from ..base import MXNetError, getenv
 from ..observability import registry as _obs
 from ..observability import telemetry as _tele
+from ..observability.trace import trace_span
 
 __all__ = ["enabled", "sdc_replay_enabled", "record_flag", "drain_flags",
            "pending_flags", "reset_flags", "digest", "GradScaler",
@@ -223,6 +224,12 @@ def drain_flags():
     vector_skipped = carry["skipped"]
     bad_keys = []
     by_where = {}
+    if pending:
+        # reading the flags waits for the programs that wrote them: a
+        # wait on the chip, so a `fence` (docs/observability.md)
+        with trace_span("fence"):
+            pending = [(np.asarray(flag), keys, where)
+                       for flag, keys, where in pending]
     for flag, keys, where in pending:
         b, t = _resolve(flag)
         bad += b

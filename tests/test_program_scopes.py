@@ -265,3 +265,35 @@ def test_debugz_has_a_programs_section(runs):
     assert section["jit_sharded_step"]["scoped"] is True
     assert set(section["jit_sharded_step"]) >= {
         "builds", "cache_hits", "cache_misses", "seconds", "instructions"}
+
+
+@pytest.mark.parametrize("kind", ["sharded", "gluon"])
+def test_roots_carry_the_os_account_and_the_launch_its_leaves(runs, kind):
+    spans = runs[kind]["spans"]
+    roots = [s for s in spans
+             if s["name"] == "step" and s["parent_id"] is None]
+    for root in roots:
+        assert {"cpu_ms", "nvcsw", "nivcsw", "majflt", "minflt", "gc0",
+                "gc0_ms"} <= set(root)
+    launches = [s for s in spans if s["name"] == "step.launch"]
+    if kind == "sharded":
+        # conv, batch norm and dense: 6 parameters, 2 running stats and
+        # 6 momenta in and out; in, the batch and its labels; out, the
+        # loss and the guard's flag
+        assert [(s["leaves_in"], s["leaves_out"]) for s in launches] \
+            == [(16, 16)] * 3
+    else:
+        assert not any("leaves_in" in s for s in launches)
+
+
+def test_the_guards_read_is_a_fence_and_the_loss_fetch_the_last(runs):
+    """The harness lays the spans on a device trace where the last fence
+    ends: that has to stay the fetch of the loss, after the guard's."""
+    spans = runs["gluon"]["spans"]
+    guard = {s["span_id"] for s in spans if s["name"] == "numerics"}
+    fences = sorted((s for s in spans if s["name"] == "fence"),
+                    key=lambda s: s["t0"] + s["step_time"])
+    under_guard = [s for s in fences if s["parent_id"] in guard]
+    assert len(under_guard) == len(guard) == 3
+    assert fences[-1]["parent_id"] not in guard
+    assert fences[-1]["t0"] > under_guard[-1]["t0"]

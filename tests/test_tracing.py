@@ -47,6 +47,7 @@ def _clean_trace(monkeypatch):
     monkeypatch.delenv("MXTPU_TRACE", raising=False)
     monkeypatch.delenv("MXTPU_TRACE_DIR", raising=False)
     monkeypatch.delenv("MXTPU_TRACE_SAMPLE", raising=False)
+    trace._drain_gc()           # collections of earlier tests: not ours
     trace.reset_ring()
     trace.close_shard()
     yield
@@ -85,7 +86,8 @@ def test_span_parentage_and_nesting():
         with trace.trace_span("child") as c:
             with trace.trace_span("grandchild"):
                 pass
-    by_name = {s["name"]: s for s in trace.ring_spans()}
+    # (a collection may land among them as a `gc` span of its own)
+    by_name = {s["name"]: s for s in trace.ring_spans() if s["name"] != "gc"}
     assert by_name["root"]["parent_id"] is None
     assert by_name["child"]["parent_id"] == r.span_id
     assert by_name["grandchild"]["parent_id"] == c.span_id
