@@ -40,8 +40,8 @@ import jax
 from ..observability import registry as _obs
 from ..observability.trace import trace_span
 
-__all__ = ["scope", "install", "snapshot", "owners", "parse_owners",
-           "reset"]
+__all__ = ["scope", "note_scoped", "install", "snapshot", "owners",
+           "parse_owners", "reset"]
 
 BUILDS = _obs.counter("compile.programs",
                       "program builds by module name and outcome "
@@ -85,8 +85,15 @@ def scope(name):
     are read until one is found to carry a scope. A program traced with
     no scope (an eager op's, a user's own jit) is counted and timed but
     not read, which is most of what keeping the table would cost."""
-    _tls.scoped = True
+    note_scoped()
     return jax.named_scope(name)
+
+
+def note_scoped():
+    """Note on the tracing thread, as `scope` does, a program that
+    carries the scopes without entering one: a backward program that
+    replays what its forward's trace recorded (cached_op.py)."""
+    _tls.scoped = True
 
 
 def parse_owners(hlo_text):

@@ -111,6 +111,37 @@ REMAT_ATTR = "__remat__"        # a node's group of rematerialisation
 REMAT_KEEP = "mx.keep"
 
 
+def _group_checkpoint():
+    """How a remat group runs: it keeps what enters and leaves it and what
+    its ops name `REMAT_KEEP`. Each group gets a policy object of its own,
+    as it always has: JAX lowers the callees of groups that share one
+    together, which would change the step program's text."""
+    return functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(REMAT_KEEP))
+
+
+def _stretches(units, checkpoint):
+    """Merge each run of consecutive units that belong to no group into
+    one unit run under `checkpoint`; variables drop out. A run of
+    consecutive units of a topological order is closed: nothing outside
+    it lies on a path between two of its nodes."""
+    out, stretch = [], None
+    for unit_checkpoint, nodes in units:
+        nodes = [n for n in nodes if not n.is_variable]
+        if not nodes:
+            continue
+        if unit_checkpoint is not None:
+            out.append((unit_checkpoint, nodes))
+            stretch = None
+        elif stretch is None:
+            stretch = list(nodes)
+            out.append((checkpoint, stretch))
+        else:
+            stretch.extend(nodes)
+    return out
+
+
 def counter_vars(output_entries):
     """{aux variable name: (metric name, ...)} for the aux inputs that an
     op of this graph declares as device counters (`Op.counters`)."""
@@ -177,7 +208,7 @@ def collect_vars(output_entries):
     return args, aux
 
 
-def build_graph_fn(output_entries, mode="predict"):
+def build_graph_fn(output_entries, mode="predict", policy=None):
     """Build a pure jax function evaluating the graph.
 
     Returns (fn, arg_names, aux_names, needs_rng) where::
@@ -189,6 +220,14 @@ def build_graph_fn(output_entries, mode="predict"):
     stats) — the functional-state threading that replaces the reference's
     in-place aux mutation (src/operator/nn/batch_norm.cc writes aux_states
     in place; XLA state must be explicit).
+
+    `policy`, a `jax.checkpoint` policy, runs each stretch of nodes that
+    lies outside every remat group as one `jax.checkpoint` under it
+    (CachedOp's recorded forward, whose backward is a program of its own:
+    nothing to keep apart from the forward, so no `prevent_cse`). The
+    groups stay checkpoints of their own beside them, not inside: a
+    checkpoint inside another is differentiated under the outer one's
+    policy.
     """
     order = topo_order(output_entries)
     aux_ids = aux_var_ids(order)
@@ -243,19 +282,27 @@ def build_graph_fn(output_entries, mode="predict"):
     # nodes of one group run as one `jax.checkpoint`, which keeps the
     # group's inputs and what leaves it, and computes the inside again in
     # the backward pass. A graph with no mark runs node by node as ever.
+    # Units are [(checkpoint or None, nodes)]: None runs node by node.
     units = _remat_units(order) if train else None
+    if units is not None:
+        units = [(_group_checkpoint() if mark else None, nodes)
+                 for mark, nodes in units if not nodes[0].is_variable]
+    if policy is not None:
+        units = _stretches(units or [(None, order)], functools.partial(
+            jax.checkpoint, policy=policy, prevent_cse=False))
     used_outside = set()
     if units is not None:
-        group_of = {id(n): m for m, nodes in units for n in nodes if m}
+        group_of = {id(n): g for g, (p, nodes) in enumerate(units)
+                    if p is not None for n in nodes}
         for node in order:
             for n, i in node.inputs:
-                if group_of.get(id(n)) and group_of.get(id(n)) != \
-                        group_of.get(id(node)):
+                g = group_of.get(id(n))
+                if g is not None and g != group_of.get(id(node)):
                     used_outside.add((id(n), i))
         used_outside.update((id(n), i) for n, i in output_entries
-                            if group_of.get(id(n)))
+                            if id(n) in group_of)
 
-    def run_group(nodes, values, aux_updates, key):
+    def run_group(nodes, values, aux_updates, key, checkpoint):
         inside = {id(n) for n in nodes}
         # in the order the group first reads them: the same program in
         # every process (an order by id() would miss the compile cache)
@@ -265,9 +312,7 @@ def build_graph_fn(output_entries, mode="predict"):
         leaving = [(id(n), i) for n in nodes for i in range(n.n_raw())
                    if (id(n), i) in used_outside]
 
-        @functools.partial(
-            jax.checkpoint,
-            policy=jax.checkpoint_policies.save_only_these_names(REMAT_KEEP))
+        @checkpoint
         def group(ext_vals, key):
             vals, ups = {}, {}
             for (nid, i), v in zip(ext, ext_vals):
@@ -292,10 +337,11 @@ def build_graph_fn(output_entries, mode="predict"):
             run([n for n in order if not n.is_variable], values,
                 aux_updates, key)
         else:
-            for mark, nodes in units:
-                if mark:
-                    key = run_group(nodes, values, aux_updates, key)
-                elif not nodes[0].is_variable:
+            for checkpoint, nodes in units:
+                if checkpoint is not None:
+                    key = run_group(nodes, values, aux_updates, key,
+                                    checkpoint)
+                else:
                     key = run(nodes, values, aux_updates, key)
         outs = [values[id(n)][i] for n, i in output_entries]
         return outs, aux_updates

@@ -35,9 +35,13 @@ PROGRAMS = {
                 ("jit_sharded_step", "jvp(mx.BatchNorm."),
                 ("jit_sharded_step", "transpose(jvp(mx.FullyConnected."),
                 ("jit_sharded_step", "/mx.optimizer/")],
-    "gluon": [("jit_cachedop_fwd_", "/mx.Convolution."),
-              ("jit_cachedop_bwd_", "transpose(jvp(mx.Convolution."),
-              ("jit_cachedop_bwd_", "transpose(jvp(mx.FullyConnected."),
+    # the recorded forward runs the graph under `jax.checkpoint`, whose
+    # backward names its operations `transpose(jvp(jvp()))/checkpoint/...`
+    "gluon": [("jit_cachedop_fwd_", "jvp(mx.Convolution."),
+              ("jit_cachedop_bwd_",
+               "transpose(jvp(jvp()))/checkpoint/mx.Convolution."),
+              ("jit_cachedop_bwd_",
+               "transpose(jvp(jvp()))/checkpoint/mx.FullyConnected."),
               ("jit_fused_step_sgd", "/mx.optimizer/")],
 }
 
